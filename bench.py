@@ -1,9 +1,10 @@
 """Flagship benchmark: ResNet-50 training throughput + MFU.
 
-Prints ONE JSON line in the BENCH trajectory ``parsed`` format:
+Prints ONE JSON line:
   {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, ...}
-``vs_baseline``/``mfu`` are null when the device kind has no known peak
-(fabricating a peak would fabricate the metric — ADVICE.md r1).
+It measures the chip and nothing else: without a TPU whose
+``device_kind`` has a row in the peak tables it exits non-zero before
+compiling or timing anything (``profiling.require_tpu``).
 
 Two arms (``--mode``):
 
@@ -51,6 +52,8 @@ from distkeras_tpu import attrib as attrib_lib
 from distkeras_tpu import telemetry
 from distkeras_tpu.profiling import (
     bench_device_config,
+    device_record,
+    enable_compile_cache,
     peak_bandwidth,
     peak_flops,
     resnet50_model_flops,
@@ -78,7 +81,7 @@ def _model_and_step(cfg):
 def run_sync(cfg) -> dict:
     from distkeras_tpu.workers import TrainState
 
-    device, on_tpu = cfg["device"], cfg["on_tpu"]
+    device = cfg["device"]
     batch, image = cfg["batch"], cfg["image"]
     model, tx, step = _model_and_step(cfg)
     x = jnp.ones((batch, image, image, 3), jnp.float32)
@@ -94,24 +97,20 @@ def run_sync(cfg) -> dict:
         t_compile = time.perf_counter()
         compiled = jit_step.lower(state, batch_dict).compile()
         compile_s = time.perf_counter() - t_compile
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per device
-        cost = cost[0] if cost else {}
-    xla_flops_per_step = float(cost.get("flops", 0.0)) if cost else 0.0
+    cost = compiled.cost_analysis() or {}
+    xla_flops_per_step = float(cost.get("flops", 0.0))
 
-    with telemetry.span("bench_timed_chain", n=30 if on_tpu else 3):
-        dt, synced = time_step_chain(jit_step, state, batch_dict,
-                                     n=30 if on_tpu else 3)
+    with telemetry.span("bench_timed_chain", n=30):
+        dt, synced = time_step_chain(jit_step, state, batch_dict, n=30)
 
     images_per_sec = batch / dt
     model_flops_per_step = resnet50_model_flops(batch, image)
-    peak, peak_known = peak_flops(device)
-    bw, bw_known = peak_bandwidth(device)
+    peak, _ = peak_flops(device)
+    bw, _ = peak_bandwidth(device)
     mfu = train_mfu(images_per_sec, image, device)
     # roofline floor for THIS compiled step: XLA's flops against peak
     # compute, its bytes-accessed against peak memory bandwidth
-    bytes_accessed = (float(cost.get("bytes accessed", 0.0))
-                      if cost else 0.0)
+    bytes_accessed = float(cost.get("bytes accessed", 0.0))
     roof = attrib_lib.roofline(xla_flops_per_step, bytes_accessed,
                                peak, bw)
     mfu_roofline = attrib_lib.mfu(xla_flops_per_step,
@@ -120,12 +119,11 @@ def run_sync(cfg) -> dict:
         "metric": "resnet50_train_images_per_sec_per_chip",
         "value": round(images_per_sec, 2),
         "unit": "images/sec/chip",
-        "vs_baseline": round(mfu / 0.60, 4) if mfu is not None else None,
-        "mfu": round(mfu, 4) if mfu is not None else None,
+        "vs_baseline": round(mfu / 0.60, 4),
+        "mfu": round(mfu, 4),
         "mfu_roofline": (round(mfu_roofline, 4)
                          if mfu_roofline is not None else None),
-        "xla_mfu": (round(xla_flops_per_step / dt / peak, 4)
-                    if peak == peak else None),
+        "xla_mfu": round(xla_flops_per_step / dt / peak, 4),
         "step_time_ms": round(dt * 1e3, 2),
         "compile_s": round(compile_s, 3),
         "batch": batch,
@@ -134,8 +132,7 @@ def run_sync(cfg) -> dict:
         "mode": "sync",
         "model_flops_per_step": model_flops_per_step,
         "xla_flops_per_step": xla_flops_per_step,
-        "device": getattr(device, "device_kind", str(device)),
-        "peak_known": bool(peak_known and bw_known),
+        "device": device_record(),
         "metrics_finite": bool(np.isfinite(synced)),
     }
 
@@ -148,7 +145,7 @@ def run_ps_mesh(cfg, comm_dtype: str, comm_codec,
     from distkeras_tpu.parallel.update_rules import RULES
     from distkeras_tpu.workers import TrainState
 
-    device, on_tpu = cfg["device"], cfg["on_tpu"]
+    device = cfg["device"]
     batch, image = cfg["batch"], cfg["image"]
     W = cfg["n_devices"]
     model, tx, step = _model_and_step(cfg)
@@ -177,7 +174,7 @@ def run_ps_mesh(cfg, comm_dtype: str, comm_codec,
         commit_permutation(jax.random.key(2), W), rep)
 
     driver = ps_dataplane.MeshRoundDriver(dp, mps, mws)
-    reps = 10 if on_tpu else 3
+    reps = 10
     with telemetry.span("bench_mesh_warmup", workers=W):
         driver.dispatch(batch_dict, perm)
         driver.drain()
@@ -207,8 +204,8 @@ def run_ps_mesh(cfg, comm_dtype: str, comm_codec,
         "metric": "ps_round_images_per_sec_per_chip",
         "value": round(images_per_sec_chip, 2),
         "unit": "images/sec/chip",
-        "vs_baseline": round(mfu / 0.60, 4) if mfu is not None else None,
-        "mfu": round(mfu, 4) if mfu is not None else None,
+        "vs_baseline": round(mfu / 0.60, 4),
+        "mfu": round(mfu, 4),
         "round_ms": round(dt * 1e3, 2),
         "step_time_ms": round(dt / window * 1e3, 2),
         "batch": batch,
@@ -231,8 +228,7 @@ def run_ps_mesh(cfg, comm_dtype: str, comm_codec,
                    if seg in attrib},
         "compile_s": (round(cost0["compile_s"], 3)
                       if "compile_s" in cost0 else None),
-        "device": getattr(device, "device_kind", str(device)),
-        "peak_known": bool(cost0.get("peak_known", False)),
+        "device": device_record(),
         "metrics_finite": bool(np.isfinite(losses).all()),
     }
 
@@ -249,6 +245,7 @@ def main():
                         help="mesh arm center broadcast codec (int8)")
     args = parser.parse_args()
 
+    enable_compile_cache()
     trace_path = os.environ.get("DKT_TELEMETRY_TRACE")
     if trace_path:
         telemetry.enable()

@@ -5,12 +5,11 @@ Three small, dependency-light layers shared by ``MeshDataplane`` (the
 cost ledger), ``MeshRoundDriver`` (the sampled step-time decomposition),
 ``bench.py`` and ``scripts/perf_attrib.py``:
 
-* :func:`extract_cost` — version-tolerant read of
-  ``Compiled.cost_analysis()`` / ``memory_analysis()`` for an AOT
-  executable.  On jax 0.4.x ``cost_analysis()`` returns a list with one
-  dict per executable and ``'flops'`` counts PER-DEVICE flops of the
-  SPMD program (verified empirically for the shard_map round); absent
-  or malformed analyses degrade to ``None`` fields, never raise.
+* :func:`extract_cost` — read of ``Compiled.cost_analysis()`` /
+  ``memory_analysis()`` for an AOT executable.  ``'flops'`` counts
+  PER-DEVICE flops of the SPMD program (verified empirically for the
+  shard_map round); absent or malformed analyses degrade to ``None``
+  fields, never raise.
 * :func:`roofline` — two-term roofline: compute time against a peak
   FLOP/s and communication time against a peak byte/s, classified
   compute- vs comm-bound by arithmetic intensity.  Pure math, unit
@@ -54,16 +53,12 @@ def extract_cost(compiled: Any) -> dict:
     except Exception:
         cost = None
     if cost:
-        # jax 0.4.x: list of one dict per executable; newer jax may
-        # hand back the dict directly.
-        rec = cost[0] if isinstance(cost, (list, tuple)) else cost
-        if isinstance(rec, dict):
-            flops = rec.get("flops")
-            if flops is not None and flops >= 0:
-                out["flops"] = float(flops)
-            nbytes = rec.get("bytes accessed")
-            if nbytes is not None and nbytes >= 0:
-                out["bytes_accessed"] = float(nbytes)
+        flops = cost.get("flops")
+        if flops is not None and flops >= 0:
+            out["flops"] = float(flops)
+        nbytes = cost.get("bytes accessed")
+        if nbytes is not None and nbytes >= 0:
+            out["bytes_accessed"] = float(nbytes)
     try:
         mem = compiled.memory_analysis()
     except Exception:

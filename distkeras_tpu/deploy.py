@@ -5,10 +5,12 @@ TPU-native analogue of the reference's experimental ``job_deployment.py``
 Spark cluster).  Here a "job" is one command run as N cooperating
 ``jax.distributed`` processes:
 
-* ``launch_local`` — N processes on this host (each seeing a slice of
-  the local devices, or a forced CPU mesh): the substrate for multi-host
+* ``launch_local`` — N processes on this host, every one pinned to the
+  CPU backend (``JAX_PLATFORMS=cpu``): the substrate for multi-host
   integration tests and the direct analogue of the reference testing via
-  Spark ``local[N]``.
+  Spark ``local[N]``.  It does not divide local accelerators among the
+  children — a chip belongs to one process — so it never lets them see
+  one.
 * ``TPUPodJob`` — the command set a real TPU pod launch needs (one
   process per host via ``gcloud compute tpus tpu-vm ssh --worker=all``).
   With no network egress in this environment it only *builds* the
@@ -59,7 +61,14 @@ def free_port() -> int:
 
 def launch_local(spec: JobSpec, check: bool = True
                  ) -> list[ProcessResult]:
-    """Run ``spec.argv`` as ``num_processes`` local cooperating processes.
+    """Run ``spec.argv`` as ``num_processes`` local cooperating CPU
+    processes.
+
+    Every child gets ``JAX_PLATFORMS=cpu`` (over ``spec.env`` too): the
+    caller may already hold this host's chip, N children would each try
+    to open every chip, and there is no device slicing here — so local
+    multi-process jobs run on the CPU backend, each child sizing its own
+    virtual mesh through ``XLA_FLAGS`` in ``spec.env``.
 
     Returns per-process results (ordered by process id).  With ``check``,
     raises ``RuntimeError`` carrying every process's output if any exits
@@ -69,6 +78,7 @@ def launch_local(spec: JobSpec, check: bool = True
     procs = []
     for i in range(spec.num_processes):
         env = {**os.environ, **spec.env,
+               "JAX_PLATFORMS": "cpu",
                "JAX_COORDINATOR_ADDRESS": coord,
                "JAX_NUM_PROCESSES": str(spec.num_processes),
                "JAX_PROCESS_ID": str(i)}

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 
 from distkeras_tpu.models.core import register_model
 from distkeras_tpu.parallel.moe import expert_capacity, routing
-from distkeras_tpu.utils import axis_size
 
 AttnFn = Callable[..., jnp.ndarray]
 
@@ -406,6 +405,11 @@ class TransformerLM(nn.Module):
     #: the ``flash_attn``/``blockwise_attn`` booleans and ``attn_fn``
     #: (strongest) override this field.  Under ``scan_blocks`` /
     #: ``decode`` T=1 steps, auto resolves to dense.
+    #: The Mosaic kernels carry no partitioning rule: under a
+    #: multi-device GSPMD ``jit`` (``SyncTrainer`` on more than one
+    #: chip) JAX refuses them at lowering, so pin ``attn="blockwise"``
+    #: there; ``shard_map`` programs (the ``mesh`` PS tier, ring
+    #: attention) and single-device programs take them as they are.
     attn: str = "auto"
     #: single-device flash-style attention (JSON-able spelling of
     #: attn_fn=blockwise_attn_fn(...)): online-softmax q-chunking, the
@@ -613,7 +617,7 @@ class TransformerLM(nn.Module):
         if self.seq_axis is not None:
             from distkeras_tpu.parallel.ring_attention import ring_attn_fn
 
-            t_global = t * axis_size(self.seq_axis)
+            t_global = t * lax.axis_size(self.seq_axis)
             positions = (lax.axis_index(self.seq_axis) * t
                          + jnp.arange(t))[None, :]
             if attn_fn is None:

@@ -50,11 +50,7 @@ _DEFAULT_BLOCK_K = 1024
 _SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
 
-def _params(semantics=_SEMANTICS):
-    # newer pallas renamed TPUCompilerParams -> CompilerParams
-    cp = getattr(pltpu, "CompilerParams",
-                 getattr(pltpu, "TPUCompilerParams", None))
-    return cp(dimension_semantics=semantics)
+_PARAMS = pltpu.CompilerParams(dimension_semantics=_SEMANTICS)
 
 
 def _causal_mask(i, j, bq, bk):
@@ -273,7 +269,7 @@ def _fwd_call(q, k, v, scale, causal, bq, bk, interpret):
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=None if interpret else _params(),
+        compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
     )(q, k, v)
 
@@ -291,7 +287,7 @@ def _bwd_call(q, k, v, do, lse, dsum, scale, causal, bq, bk,
         out_specs=[_qblk(bq, d)],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=None if interpret else _params(),
+        compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse, dsum)[0]
 
@@ -312,7 +308,7 @@ def _bwd_call(q, k, v, do, lse, dsum, scale, causal, bq, bk,
                    jax.ShapeDtypeStruct((b, h, t, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=None if interpret else _params(),
+        compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse, dsum)
     return dq, dk, dv
@@ -540,16 +536,10 @@ def _hop_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 def _struct(vma, shape):
     """f32 ShapeDtypeStruct, tagged varying-over-``vma`` mesh axes
     when given (required for pallas outputs under shard_map's
-    check_vma).  Older jax has no vma type system (its ShapeDtypeStruct
-    rejects the kwarg) — the tag only exists for the checker, so it is
-    simply dropped there."""
+    check_vma)."""
     if vma is None:
         return jax.ShapeDtypeStruct(shape, jnp.float32)
-    try:
-        return jax.ShapeDtypeStruct(shape, jnp.float32,
-                                    vma=frozenset(vma))
-    except TypeError:  # old jax: no vma kwarg (and no checker)
-        return jax.ShapeDtypeStruct(shape, jnp.float32)
+    return jax.ShapeDtypeStruct(shape, jnp.float32, vma=frozenset(vma))
 
 
 def _scalar_spec():
@@ -595,7 +585,7 @@ def flash_hop_fwd(q, k, v, m, l, acc, *, q_offset, k_offset,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=None if interpret else _params(),
+        compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
     )(qo, ko, q, k, v, m, l, acc)
 
@@ -628,7 +618,7 @@ def flash_hop_bwd(q, k, v, do, lse, dsum, *, q_offset, k_offset,
         out_specs=[_qblk(bq, d)],
         out_shape=[_struct(vma, (b, h, t, d))],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=None if interpret else _params(),
+        compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
     )(qo, ko, q, k, v, do, lse, dsum)[0]
 
@@ -649,7 +639,7 @@ def flash_hop_bwd(q, k, v, do, lse, dsum, *, q_offset, k_offset,
                    _struct(vma, (b, h, tk, d))],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=None if interpret else _params(),
+        compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
     )(qo, ko, q, k, v, do, lse, dsum)
     return dq, dk, dv

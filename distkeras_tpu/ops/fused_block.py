@@ -48,12 +48,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distkeras_tpu.ops.pallas_kernels import _CompilerParams, _group_mask
+from distkeras_tpu.ops.pallas_kernels import _group_mask
 
 # Whole-sample blocks at ResNet-50 stage 1 ([3136, 256] f32
 # intermediates, several live at once in the tail backward) need more
 # than the default 16 MB scoped-VMEM budget; v5e has 128 MB.
-_VMEM_LIMIT = _CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+_VMEM_LIMIT = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 
 def _gn_stats(y, mask, count, eps):

@@ -31,14 +31,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# newer pallas renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 # The fp32 intermediates of a whole-image block exceed the default 16 MB
 # scoped-VMEM budget at the ResNet stem ([12544, 64]); v5e has 128 MB of
 # VMEM, so grant the kernels a generous slice of it.
-_VMEM_LIMIT = _CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+_VMEM_LIMIT = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 
 def _group_mask(channels: int, groups: int) -> np.ndarray:

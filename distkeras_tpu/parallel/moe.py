@@ -33,7 +33,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from distkeras_tpu.utils import axis_size
 
 
 class MoEParams(NamedTuple):
@@ -151,7 +150,7 @@ def moe_apply(params: MoEParams, x: jax.Array, *, axis_name: str,
     chosen experts).  Returns ``([T_local, d], MoEAux)``; aux values
     are means over the mesh axis.
     """
-    n_dev = axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     e_local = params.w_in.shape[0]
     num_experts = e_local * n_dev
     if not 1 <= top_k <= num_experts:

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from distkeras_tpu.utils import axis_size, pcast
 
 STAGE_AXIS = "stage"
 
@@ -56,7 +55,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array, *,
       (the last stage's results are broadcast with ``psum`` so the
       caller can compute a loss without caring about stage placement).
     """
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     for leaf in jax.tree_util.tree_leaves(stage_params):
         if leaf.shape[:1] != (1,):
@@ -81,9 +80,9 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array, *,
 
     # Device-varying zeros from tick 0 (scan's carry typing must agree
     # with the computed, varying outputs).
-    state0 = pcast(jnp.zeros_like(micro[0]), (axis_name,),
+    state0 = lax.pcast(jnp.zeros_like(micro[0]), (axis_name,),
                        to="varying")
-    out0 = pcast(jnp.zeros_like(micro), (axis_name,), to="varying")
+    out0 = lax.pcast(jnp.zeros_like(micro), (axis_name,), to="varying")
     # The tick loop: stage 0 ingests microbatch t (while t < M), every
     # stage applies its compute, results hop one stage forward, and the
     # last stage banks microbatch t - (S-1) once the pipe has filled.
@@ -218,11 +217,9 @@ def make_pp_train_step(model, loss_fn, tx, mesh: Mesh, *,
         return new_state, {"loss": loss}
 
     def step(state, batch):
-        from distkeras_tpu.utils import shard_map
-
         specs = lm_state_specs(state)
         batch_specs = {k: P(workers_axis) for k in batch}
-        return shard_map(
+        return jax.shard_map(
             per_device_step, mesh=mesh,
             in_specs=(specs, batch_specs),
             out_specs=(specs, P()))(state, batch)

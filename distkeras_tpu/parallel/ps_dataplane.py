@@ -628,7 +628,7 @@ class MeshDataplane:
             }
             return new_blocks, clock + W, new_ws, metrics
 
-        round_smap = utils.shard_map(
+        round_smap = jax.shard_map(
             round_body, mesh=self.mesh,
             in_specs=(row_blocks, seg_specs, P(), specs, P(WA), P()),
             out_specs=(row_blocks, P(), specs, P(WA)))
@@ -683,7 +683,7 @@ class MeshDataplane:
             return (new_blocks, new_clock, new_ws, metrics,
                     new_pending, jnp.asarray(True))
 
-        pipe_smap = utils.shard_map(
+        pipe_smap = jax.shard_map(
             pipe_body, mesh=self.mesh,
             in_specs=(row_blocks, seg_specs, P(), specs, P(WA), P(),
                       {n: P(WA) for n in spec.groups}, P(), P()),
@@ -714,7 +714,7 @@ class MeshDataplane:
                 blocks, {n: p[0] for n, p in pending.items()}, scale)
             return new_blocks, clock + W
 
-        flush_smap = utils.shard_map(
+        flush_smap = jax.shard_map(
             flush_body, mesh=self.mesh,
             in_specs=(row_blocks, P(),
                       {n: P(WA) for n in spec.groups}, P()),
@@ -800,6 +800,12 @@ class MeshDataplane:
         entry = (compiled, rec)
         self._programs[key] = entry
         return entry
+
+    def compiled_rounds(self) -> list:
+        """The AOT ``Compiled`` handle of every round program that ever
+        ran, in ledger order — for reading the lowered program itself
+        (``as_text()``: the collectives the header claims are there)."""
+        return [compiled for compiled, _ in self._programs.values()]
 
     def last_program_record(self) -> dict | None:
         """Ledger record of the most recently dispatched program (the

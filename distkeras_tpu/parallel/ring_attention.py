@@ -33,7 +33,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from distkeras_tpu.utils import axis_size, pcast, shard_map
 import numpy as np
 from jax import lax
 
@@ -49,7 +48,7 @@ def _ring(axis_name: str | None):
     single-chip blockwise (flash-style) attention."""
     if axis_name is None:
         return 1, 0, None
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return n, lax.axis_index(axis_name), [(i, (i - 1) % n)
                                           for i in range(n)]
 
@@ -59,7 +58,7 @@ def _vary(axis_name, trees):
     carry typing must agree with the computed, varying outputs)."""
     if axis_name is None:
         return tuple(trees)
-    return tuple(pcast(x, (axis_name,), to="varying") for x in trees)
+    return tuple(lax.pcast(x, (axis_name,), to="varying") for x in trees)
 
 
 def _block_mask(src, t_local, q_pos):
@@ -457,5 +456,5 @@ def sequence_sharded_apply(fn, mesh, seq_axis: str, *,
 
     seq_spec = P(None, seq_axis)
     in_specs = (P(),) + (seq_spec,) * num_seq_args
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=seq_spec, check_vma=False)

@@ -950,6 +950,8 @@ class DistributedTrainer(Trainer):
         # capabilities — feature gates below read flags, not strings
         self.tier = resolve_tier(fidelity)
         self.fidelity = fidelity
+        #: the ``MeshRoundDriver`` of the last ``fidelity="mesh"`` train()
+        self.mesh_driver = None
         self.transport = transport
         self.checkpoint_every_rounds = checkpoint_every_rounds
         self.max_worker_failures = int(max_worker_failures)
@@ -1413,6 +1415,11 @@ class DistributedTrainer(Trainer):
                 driver = ps_dataplane.MeshRoundDriver(
                     dp, ps_state, worker_states,
                     attrib_every=self.attrib_every)
+                # kept after train(): the sharded center/worker state
+                # (``.mps``/``.mws``) and the dataplane's cost ledger
+                # and compiled rounds (``.dp``) are what a caller
+                # inspects to see where the work actually ran
+                self.mesh_driver = driver
             elif overlap:
                 round_jit = jax.jit(
                     round_fn,

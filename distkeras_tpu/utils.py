@@ -30,62 +30,11 @@ Pytree = Any
 # ---------------------------------------------------------------------------
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` across jax versions: the public alias only
-    exists on newer jax; 0.4.x spells it
-    ``jax.experimental.shard_map.shard_map``.  Every SPMD call site in
-    the repo (ring attention, pipeline, MoE, their tests and examples)
-    routes through this one name so a jax upgrade/downgrade never
-    breaks the mesh paths again."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kwargs:  # the old spelling of the flag
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        # On new jax the call sites satisfy the vma checker with
-        # explicit pcast(..., to="varying") bookkeeping; 0.4.x has no
-        # vma types (utils.pcast is a no-op there), so its replication
-        # checker sees the raw carries and rejects them.  Computation
-        # is identical either way — disable the checker, which is the
-        # old-jax equivalent of the casts.
-        kwargs.setdefault("check_rep", False)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)
-
-
-def axis_size(axis_name) -> int:
-    """STATIC size of a named mesh axis from inside ``shard_map``,
-    across jax versions: newer jax spells it ``lax.axis_size``; 0.4.x
-    exposes it as ``jax.core.axis_frame`` (an int there).  Static
-    matters — callers fold it into shape arithmetic (e.g. the
-    sequence-parallel ``t_global`` bound check)."""
-    import jax.lax as lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    import jax.core as core
-
-    frame = core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
-
-
-def pcast(x, axis_names, *, to="varying"):
-    """``lax.pcast`` across jax versions.  On newer jax it adjusts the
-    varying-across-manual-axes type (the vma checker's bookkeeping);
-    0.4.x has no vma type system, so the cast is a no-op there —
-    semantically identical, since these casts only exist to satisfy
-    the checker (the repo runs them under ``check_rep=False``)."""
-    import jax.lax as lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_names, to=to)
-    return x
-
-
 def _host_leaf(x) -> bool:
     """True when ``x`` lives on the host as a plain numpy array (no
     tracer, no device array, no python scalar).  The host PS path runs
-    these tree ops eagerly at ResNet scale, where per-leaf jax dispatch
-    costs 100-300 ms/op on this runtime vs <1 ms in numpy (measured:
-    62-leaf tree_add 5.7 s via jnp, 32 ms via np — PERF.md §12); numpy
+    these tree ops eagerly at ResNet scale, where one eager jax
+    dispatch per leaf costs far more than the numpy op it wraps; numpy
     also keeps the server thread off the device entirely.  Everything
     else keeps the jnp path, so jitted update rules (and the legacy
     promotion semantics for scalars/int leaves) are untouched."""

@@ -53,7 +53,6 @@ def _run(args):
     from distkeras_tpu.data import datasets
     from distkeras_tpu.models import ModelSpec, model_config
     from distkeras_tpu.ops.losses import resolve_loss
-    from distkeras_tpu.utils import shard_map
 
     n_dev = len(jax.devices())
     if args.seq_len % n_dev:
@@ -102,7 +101,7 @@ def _run(args):
         return jax.lax.pmean(
             loss_fn(seq_model.apply(vs, toks), tgt), "seq")
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_loss, mesh=mesh,
         in_specs=(P(), P(None, "seq"), P(None, "seq")), out_specs=P(),
         # the Pallas interpreter requires check_vma=False (JAX

@@ -40,7 +40,6 @@ def _run(args):
 
     from distkeras_tpu.parallel import (init_moe_params, moe_apply,
                                         moe_pspecs, pipeline_apply)
-    from distkeras_tpu.utils import shard_map
 
     n_dev = len(jax.devices())
     d = args.d_model
@@ -59,7 +58,7 @@ def _run(args):
     def stage_fn(p, a):
         return jnp.tanh(a @ p["w"] + p["b"])
 
-    pipe_loss = shard_map(
+    pipe_loss = jax.shard_map(
         lambda p, x, t: jnp.mean(
             (pipeline_apply(stage_fn, p, x, axis_name="stage",
                             num_microbatches=4) - t) ** 2),
@@ -81,7 +80,7 @@ def _run(args):
         return (lax.pmean(jnp.mean((out - t) ** 2), "expert")
                 + 0.01 * aux.load_balance_loss)
 
-    moe_sharded = shard_map(
+    moe_sharded = jax.shard_map(
         moe_loss, mesh=mesh_e,
         in_specs=(moe_pspecs("expert"), P("expert"),
                   P("expert")),

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from distkeras_tpu.profiling import (
+    enable_compile_cache,
     host_sync,
     peak_flops,
     resnet50_model_flops,
@@ -46,6 +47,7 @@ def report(name, dt, batch, train=True, image=224):
 
 
 def main():
+    enable_compile_cache()
     from distkeras_tpu.models import ResNet50
     from distkeras_tpu.workers import (TrainState, make_train_step,
                                        make_window_runner,

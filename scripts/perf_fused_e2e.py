@@ -14,8 +14,8 @@ import jax.numpy as jnp
 
 import time
 
-from distkeras_tpu.profiling import (host_sync, peak_flops,
-                                     resnet50_model_flops)
+from distkeras_tpu.profiling import (enable_compile_cache, host_sync,
+                                     peak_flops, resnet50_model_flops)
 
 
 def timed_chain(step, state, batch, n):
@@ -57,6 +57,7 @@ def build(arm, batch, image, stem):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--image", type=int, default=224)

@@ -27,10 +27,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distkeras_tpu.profiling import host_sync, peak_flops
+from distkeras_tpu.profiling import (enable_compile_cache, host_sync,
+                                     peak_flops)
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--layers", type=int, default=12)
     ap.add_argument("--d-model", type=int, default=768)

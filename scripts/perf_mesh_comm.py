@@ -212,8 +212,7 @@ def run(args) -> list[dict]:
         "bytes_saved_per_round":
             results[best]["comm_bytes_saved_per_round"],
         "workers": args.workers, "dim": args.dim,
-        "device": getattr(jax.devices()[0], "device_kind",
-                          str(jax.devices()[0])),
+        "device": jax.devices()[0].device_kind,
     }
     print(json.dumps(summary), flush=True)
     if not args.smoke:

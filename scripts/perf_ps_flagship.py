@@ -475,6 +475,9 @@ def main():
         smoke(args)
         return
 
+    from distkeras_tpu.profiling import enable_compile_cache
+
+    enable_compile_cache()
     rec = measure(args, args.fidelity, args.overlap)
     print(json.dumps(rec))
     if args.out:

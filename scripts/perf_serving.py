@@ -223,6 +223,10 @@ def main():
         args.slots, args.baseline_batch = 3, 3
         args.buckets, args.prefill_align = "16,32", 4
         args.steps_per_sync, args.rate = 2, 200.0
+    else:
+        from distkeras_tpu.profiling import enable_compile_cache
+
+        enable_compile_cache()
 
     from distkeras_tpu.models import model_config, ModelSpec
     import jax

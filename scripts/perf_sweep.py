@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from distkeras_tpu.profiling import (
+    enable_compile_cache,
     peak_flops,
     resnet50_model_flops,
     time_step_chain,
@@ -56,6 +57,7 @@ def run_config(batch, norm, input_dtype, image=224, n_steps=20):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()

@@ -32,6 +32,7 @@ from __future__ import annotations
 import argparse
 import collections
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -206,10 +207,13 @@ def smoke(out_dir: str) -> None:
     server_trace = out / "trace-server.json"
     client_trace = out / "trace-client.json"
 
+    # The PS child is pinned to the CPU: it only builds a center and
+    # serves host numpy, and a chip belongs to one process — the
+    # trainer in this parent.
     child = subprocess.Popen(
         [sys.executable, __file__, "--serve", str(server_trace)],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
-        cwd=str(REPO))
+        cwd=str(REPO), env={**os.environ, "JAX_PLATFORMS": "cpu"})
     try:
         port_line = child.stdout.readline().split()
         assert port_line and port_line[0] == "PORT", port_line

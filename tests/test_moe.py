@@ -15,7 +15,6 @@ from distkeras_tpu.parallel.moe import (
     moe_apply,
     moe_pspecs,
 )
-from distkeras_tpu.utils import shard_map
 
 D, H, E = 8, 16, 8  # d_model, hidden, experts
 
@@ -45,7 +44,7 @@ def _ep_apply(mesh, params, x, capacity_factor):
                              capacity_factor=capacity_factor)
         return out, aux
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(moe_pspecs("expert"), P("expert")),
         out_specs=(P("expert"), MoEAux(P(), P()))))(params, x)
@@ -100,7 +99,7 @@ def test_moe_trains(devices):
         return (lax.pmean(local, "expert")
                 + 0.01 * aux.load_balance_loss)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         loss_fn, mesh=mesh,
         in_specs=(moe_pspecs("expert"), P("expert"),
                   P("expert")),
@@ -168,7 +167,7 @@ def test_top2_matches_dense_reference(devices):
         return moe_apply(p, x, axis_name="expert",
                          capacity_factor=float(E), top_k=2)
 
-    out, aux = jax.jit(shard_map(
+    out, aux = jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(moe_pspecs("expert"), P("expert")),
         out_specs=(P("expert"), MoEAux(P(), P()))))(params, x)
@@ -194,7 +193,7 @@ def test_top2_second_choice_drops_first(devices):
             return moe_apply(p, x, axis_name="expert",
                              capacity_factor=1.0, top_k=k)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(moe_pspecs("expert"), P("expert")),
             out_specs=(P("expert"), MoEAux(P(), P()))))(params, x)
@@ -221,7 +220,7 @@ def test_bad_top_k_raises(devices):
         return moe_apply(p, x, axis_name="expert", top_k=0)
 
     with np.testing.assert_raises(Exception):
-        jax.jit(shard_map(
+        jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(moe_pspecs("expert"), P("expert")),
             out_specs=(P("expert"), MoEAux(P(), P()))))(params, x)

@@ -10,7 +10,6 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distkeras_tpu.parallel.pipeline import pipeline_apply
-from distkeras_tpu.utils import shard_map
 
 D = 16  # homogeneous stage width
 
@@ -39,7 +38,7 @@ def _pipelined(mesh, n_micro):
         return pipeline_apply(_stage_fn, params, x, axis_name="stage",
                               num_microbatches=n_micro)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(P("stage"), P()), out_specs=P()))
 
 
@@ -95,7 +94,7 @@ def test_dp_pp_training_step_converges(devices):
                              num_microbatches=4)
         return lax.pmean(jnp.mean((out - tgt) ** 2), "workers")
 
-    sharded_loss = shard_map(
+    sharded_loss = jax.shard_map(
         loss_fn, mesh=mesh,
         in_specs=(P("stage"), P("workers"), P("workers")),
         out_specs=P())

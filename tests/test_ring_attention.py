@@ -17,7 +17,6 @@ from distkeras_tpu.parallel.ring_attention import (
     ring_attention,
     sequence_sharded_apply,
 )
-from distkeras_tpu.utils import shard_map
 
 SEQ = "seq"
 
@@ -47,7 +46,7 @@ def test_ring_matches_dense(causal, q_chunk):
     mesh = _mesh()
     q, k, v = _qkv()
     scale = q.shape[-1] ** -0.5
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name=SEQ, causal=causal,
                           q_chunk=q_chunk),
         mesh=mesh, in_specs=(P(None, SEQ), P(None, SEQ), P(None, SEQ)),
@@ -62,7 +61,7 @@ def test_ring_matches_dense(causal, q_chunk):
 def test_indivisible_q_chunk_raises():
     mesh = _mesh()
     q, k, v = _qkv()  # t_local = 8 per device
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name=SEQ, q_chunk=3),
         mesh=mesh, in_specs=(P(None, SEQ), P(None, SEQ), P(None, SEQ)),
         out_specs=P(None, SEQ))
@@ -77,7 +76,7 @@ def test_ring_gradients_match_dense(q_chunk):
     probe = jax.random.normal(jax.random.key(9), q.shape)
 
     def ring_loss(q, k, v):
-        out = shard_map(
+        out = jax.shard_map(
             functools.partial(ring_attention, axis_name=SEQ,
                               q_chunk=q_chunk),
             mesh=mesh,
@@ -144,7 +143,7 @@ def test_sequence_parallel_training_grads_match_dense():
             local = loss_fn(logits, tgt).mean()
             return jax.lax.pmean(local, SEQ)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_loss, mesh=mesh,
             in_specs=(P(), P(None, SEQ), P(None, SEQ)),
             out_specs=P())
@@ -257,12 +256,12 @@ def test_transformer_attn_q_chunk_matches_dense():
 def test_flash_impl_ring_matches_dense(causal):
     """ring_attention(impl='flash') — per-hop Pallas kernels with the
     online-softmax state carried across hops — equals dense attention.
-    The Pallas interpreter needs shard_map(check_vma=False) (JAX
+    The Pallas interpreter needs jax.shard_map(check_vma=False) (JAX
     interpreter limitation; sequence_sharded_apply already does)."""
     mesh = _mesh()
     q, k, v = _qkv()
     scale = q.shape[-1] ** -0.5
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name=SEQ, causal=causal,
                           impl="flash", block_q=8, block_k=8),
         mesh=mesh, in_specs=(P(None, SEQ),) * 3,
@@ -279,7 +278,7 @@ def test_flash_impl_ring_gradients_match_dense():
     q, k, v = _qkv()
     scale = q.shape[-1] ** -0.5
     probe = jax.random.normal(jax.random.key(21), q.shape, jnp.float32)
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name=SEQ, causal=True,
                           impl="flash", block_q=8, block_k=8),
         mesh=mesh, in_specs=(P(None, SEQ),) * 3,
