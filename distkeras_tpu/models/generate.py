@@ -65,6 +65,7 @@ def _decode_model(model) -> TransformerLM:
                        remat_blocks=False)
 
 
+@jax.named_scope("sample")
 def _select(logits, temperature, top_k, top_p, rng):
     """Next-token choice from ``[B, V]`` logits (f32).
 

@@ -170,20 +170,21 @@ class SelfAttention(nn.Module):
                 return lax.dynamic_update_slice(cache, chunk,
                                                 (0, 0, idx, 0))
 
-            if quant:
-                sshape = (b, kvh, self.cache_len, 1)
-                ks = self.variable("cache", "key_scale", jnp.zeros,
-                                   sshape, jnp.float32)
-                vs = self.variable("cache", "value_scale", jnp.zeros,
-                                   sshape, jnp.float32)
-                k_w, k_s = _quantize_kv(k)
-                v_w, v_s = _quantize_kv(v)
-                ks.value = write(ks.value, k_s)
-                vs.value = write(vs.value, v_s)
-            else:
-                k_w, v_w = k, v
-            ck.value = write(ck.value, k_w)
-            cv.value = write(cv.value, v_w)
+            with jax.named_scope("kv_write"):
+                if quant:
+                    sshape = (b, kvh, self.cache_len, 1)
+                    ks = self.variable("cache", "key_scale", jnp.zeros,
+                                       sshape, jnp.float32)
+                    vs = self.variable("cache", "value_scale",
+                                       jnp.zeros, sshape, jnp.float32)
+                    k_w, k_s = _quantize_kv(k)
+                    v_w, v_s = _quantize_kv(v)
+                    ks.value = write(ks.value, k_s)
+                    vs.value = write(vs.value, v_s)
+                else:
+                    k_w, v_w = k, v
+                ck.value = write(ck.value, k_w)
+                cv.value = write(cv.value, v_w)
             # Overflow is a traced condition (cache_index is dynamic),
             # so it cannot raise; dynamic_update_slice would silently
             # CLAMP the write and corrupt the cache.  Poison the
@@ -221,33 +222,34 @@ class SelfAttention(nn.Module):
                 # materialized dequantized copy (the round-5 measured
                 # pitfall: dequantize-then-einsum was SLOWER than the
                 # bf16 cache, PERF.md §18 addendum).
-                keys, vals = ck.value, cv.value
-                if quant:
-                    keys = keys.astype(q.dtype)
-                    vals = vals.astype(q.dtype)
-                if slot_pos is not None:
-                    q_pos = slot_pos[:, None]               # [B, 1]
-                else:
-                    q_pos = (idx + jnp.arange(t))[None, :]  # [1, t]
-                k_pos = jnp.arange(self.cache_len)
-                # [B|1, t, L]: per-row causal horizon in slot mode
-                mask = k_pos[None, None, :] <= q_pos[:, :, None]
-                qg = q.reshape(b, t, kvh, group, head_dim)
-                logits = jnp.einsum("bqhgd,bhkd->bhgqk", qg, keys) \
-                    * scale
-                if quant:
-                    # ks: [B, KVH, L, 1] -> broadcast over (g, q)
-                    logits = logits * ks.value[:, :, None, None, :, 0]
-                logits = jnp.where(mask[:, None, None], logits,
-                                   -1e30)
-                probs = nn.softmax(logits.astype(jnp.float32),
-                                   axis=-1).astype(q.dtype)
-                if quant:
-                    probs = (probs.astype(jnp.float32)
-                             * vs.value[:, :, None, None, :, 0]
-                             ).astype(q.dtype)
-                out = jnp.einsum("bhgqk,bhkd->bqhgd", probs, vals)
-                out = out.reshape(b, t, self.num_heads, head_dim)
+                with jax.named_scope("attn_decode"):
+                    keys, vals = ck.value, cv.value
+                    if quant:
+                        keys = keys.astype(q.dtype)
+                        vals = vals.astype(q.dtype)
+                    if slot_pos is not None:
+                        q_pos = slot_pos[:, None]               # [B, 1]
+                    else:
+                        q_pos = (idx + jnp.arange(t))[None, :]  # [1, t]
+                    k_pos = jnp.arange(self.cache_len)
+                    # [B|1, t, L]: per-row causal horizon in slot mode
+                    mask = k_pos[None, None, :] <= q_pos[:, :, None]
+                    qg = q.reshape(b, t, kvh, group, head_dim)
+                    logits = jnp.einsum("bqhgd,bhkd->bhgqk", qg, keys) \
+                        * scale
+                    if quant:
+                        # ks: [B, KVH, L, 1] -> broadcast over (g, q)
+                        logits = logits * ks.value[:, :, None, None, :, 0]
+                    logits = jnp.where(mask[:, None, None], logits,
+                                       -1e30)
+                    probs = nn.softmax(logits.astype(jnp.float32),
+                                       axis=-1).astype(q.dtype)
+                    if quant:
+                        probs = (probs.astype(jnp.float32)
+                                 * vs.value[:, :, None, None, :, 0]
+                                 ).astype(q.dtype)
+                    out = jnp.einsum("bhgqk,bhkd->bqhgd", probs, vals)
+                    out = out.reshape(b, t, self.num_heads, head_dim)
             if jnp.ndim(ok):          # slot mode: per-row poison only
                 ok = ok[:, None, None, None]
             out = jnp.where(ok, out, jnp.nan)
@@ -347,9 +349,11 @@ class Block(nn.Module):
                        self.expert_capacity_factor, self.expert_top_k,
                        name="moe")(y)
         else:
-            y = nn.Dense(d_model * self.mlp_ratio, dtype=self.dtype)(y)
-            y = nn.gelu(y)
-            y = nn.Dense(d_model, dtype=self.dtype)(y)
+            with jax.named_scope("mlp"):
+                y = nn.Dense(d_model * self.mlp_ratio,
+                             dtype=self.dtype)(y)
+                y = nn.gelu(y)
+                y = nn.Dense(d_model, dtype=self.dtype)(y)
         return x + y
 
 

@@ -271,6 +271,7 @@ def _fwd_call(q, k, v, scale, causal, bq, bk, interpret):
                         pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -289,6 +290,7 @@ def _bwd_call(q, k, v, do, lse, dsum, scale, causal, bq, bk,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, dsum)[0]
 
     # dK/dV: the k block is the resident operand, q blocks stream.
@@ -310,6 +312,7 @@ def _bwd_call(q, k, v, do, lse, dsum, scale, causal, bq, bk,
                         pltpu.VMEM((bk, d), jnp.float32)],
         compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse, dsum)
     return dq, dk, dv
 
@@ -587,6 +590,7 @@ def flash_hop_fwd(q, k, v, m, l, acc, *, q_offset, k_offset,
                         pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
+        name="flash_hop_fwd",
     )(qo, ko, q, k, v, m, l, acc)
 
 
@@ -620,6 +624,7 @@ def flash_hop_bwd(q, k, v, do, lse, dsum, *, q_offset, k_offset,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
+        name="flash_hop_dq",
     )(qo, ko, q, k, v, do, lse, dsum)[0]
 
     kspec = pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0),
@@ -641,6 +646,7 @@ def flash_hop_bwd(q, k, v, do, lse, dsum, *, q_offset, k_offset,
                         pltpu.VMEM((bk, d), jnp.float32)],
         compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
+        name="flash_hop_dkv",
     )(qo, ko, q, k, v, do, lse, dsum)
     return dq, dk, dv
 

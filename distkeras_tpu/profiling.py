@@ -12,8 +12,6 @@ workaround live in exactly one place.
 from __future__ import annotations
 
 import contextlib
-import glob
-import json
 import os
 import time
 from typing import Iterator
@@ -223,14 +221,15 @@ def time_step_chain(step_fn, state, batch, n: int = 20,
 
 def telemetry_overhead(n: int = 200_000) -> dict:
     """Measured per-call cost (ns) of the telemetry hot-path
-    primitives, disabled vs enabled — the number PERF.md §24 quotes
+    primitives, disabled vs enabled — the number PERF.md section 6 quotes
     and ``scripts/obs_report.py`` re-measures.  Restores the global
     telemetry state it found.
 
     The disabled arm is what every instrumented call site pays when
-    telemetry is off (the tier-1 / perf-row fast path): a registry
-    lookup returning the shared no-op metric, and the shared no-op
-    span.  The enabled arm adds the real lock + dict work.
+    telemetry is off and no profiler session runs (the tier-1 /
+    perf-row fast path): a registry lookup returning the shared no-op
+    metric, and a span that is one inert ``dkt:`` profiler annotation.
+    The enabled arm adds the real lock + dict work.
     """
     from distkeras_tpu import telemetry
 
@@ -265,46 +264,21 @@ def telemetry_overhead(n: int = 200_000) -> dict:
     return out
 
 
-#: Filename of the wall-clock anchor :func:`profiler_trace` drops next
-#: to a device capture; ``telemetry.load_device_trace`` reads it to pin
-#: the trace's relative timestamps onto the host span timeline.
-WALL_ANCHOR_FILE = "wall_anchor.json"
-
-
 @contextlib.contextmanager
 def profiler_trace(log_dir: str | None) -> Iterator[None]:
     """``jax.profiler`` trace hook: no-op when ``log_dir`` is None, so
     trainers can accept an optional ``profile_dir`` flag without
     branching at every call site.
 
-    When active, writes ``wall_anchor.json`` (the wall clock at
-    ``start_trace``) into ``log_dir`` FIRST: XLA's ``trace.json.gz``
-    timestamps are microseconds RELATIVE to the capture start, and the
-    anchor is what lets ``telemetry.load_device_trace`` /
-    ``merge_traces`` shift them onto the host tracer's monotonic
-    timeline for one unified Perfetto file.
+    While it is active every ``telemetry.span`` lands in the capture
+    as ``dkt:<name>`` on ``/host:CPU``, on the same clock as the
+    device's ``XLA Ops``.
     """
     if log_dir is None:
         yield
         return
-    os.makedirs(log_dir, exist_ok=True)
-    anchor = {"wall_s": time.time()}
-    with open(os.path.join(log_dir, WALL_ANCHOR_FILE), "w") as f:
-        json.dump(anchor, f)
     jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def find_device_traces(log_dir: str) -> list[str]:
-    """Chrome-format device traces under a :func:`profiler_trace` log
-    dir (``plugins/profile/<run>/<host>.trace.json.gz``), newest first.
-    Empty when the profiler produced nothing — callers skip cleanly.
-    """
-    pattern = os.path.join(log_dir, "**", "*.trace.json.gz")
-    hits = glob.glob(pattern, recursive=True)
-    hits += glob.glob(os.path.join(log_dir, "**", "*.trace.json"),
-                      recursive=True)
-    return sorted(set(hits), key=os.path.getmtime, reverse=True)

@@ -95,14 +95,21 @@ Observability (``distkeras_tpu.telemetry``; no-op until
 histograms (the latter feeds the watchdog's ``inter_token_p99``
 signal), token/request/finish counters,
 trace-time ``compiles_total{kind,bucket[,padded]}`` (the public face
-of ``compile_counts``), and ``prefill``/``decode_step`` spans +
-``evict`` instants on the serving thread's timeline track.  The
+of ``compile_counts``), and ``evict`` instants on the serving thread's
+timeline track.  ``step()`` is one span tree, in the ring while
+telemetry is enabled and in the profiler's trace (``dkt:<name>``)
+while a ``jax.profiler`` session runs: ``engine_step`` > ``admit`` >
+``prefill`` > ``prefill_dispatch`` / ``first_token_sync``;
+``engine_step`` > ``decode_step`` > ``decode_dispatch`` /
+``decode_fetch``; ``engine_step`` > ``emit``, ``sweep``.  The
 prefix/chunk layer adds ``serving_prefix_{hits,misses,evictions,
 invalidations}_total``, ``serving_prefill_tokens_saved_total``, the
 ``serving_prefix_hit_rate`` gauge (an SLO watchdog signal),
 ``prefix_copy``/``prefill_chunk`` spans, and a ``prefix_invalidate``
 flight-recorder event on every store invalidation.  Request timing
-stamps all read ``telemetry.now()`` — see ``_finish``.
+stamps (``t_submit``, ``t_admit``, one ``t_tokens`` entry per token,
+``t_finish``; always on) all read ``telemetry.now()`` — see
+``_finish``.
 """
 
 from __future__ import annotations
@@ -231,8 +238,8 @@ def unpack_kv_blocks(body) -> dict:
 
 class _Request:
     __slots__ = ("rid", "prompt", "max_new", "eos_id", "tokens", "meta",
-                 "submit_order", "t_submit", "t_first", "t_last_tok",
-                 "traces_seen",
+                 "submit_order", "t_submit", "t_admit", "t_tokens",
+                 "t_last_tok", "traces_seen",
                  "deadline", "prefix_path", "weights_ver", "tenant",
                  "priority", "pages", "swap", "spec_on")
 
@@ -246,7 +253,8 @@ class _Request:
         self.meta = meta
         self.submit_order = submit_order
         self.t_submit = telemetry.now()
-        self.t_first = None
+        self.t_admit = None            # when _admit took it off its queue
+        self.t_tokens: list[float] = []  # host stamp of each token
         self.t_last_tok = None         # inter-token gap anchor
         self.traces_seen = -1          # engine trace total at anchor
         # absolute telemetry.now() expiry (None: no deadline)
@@ -260,6 +268,15 @@ class _Request:
         self.swap = None               # parked: host KV / restore plan
         self.spec_on = None            # per-request speculative
         #                                override (None: engine config)
+
+    @property
+    def t_first(self) -> Optional[float]:
+        return self.t_tokens[0] if self.t_tokens else None
+
+    def times(self) -> dict:
+        """The stamps a result carries, beside its ``t_finish``."""
+        return {"t_submit": self.t_submit, "t_admit": self.t_admit,
+                "t_first": self.t_first, "t_tokens": self.t_tokens}
 
     def ledger(self, env: Optional[int] = None) -> np.ndarray:
         """The slot's ONE retained-token ledger: prompt + every
@@ -820,6 +837,12 @@ class DecodeEngine:
                     "compiles_total", kind="step", bucket=env).inc()
                 return step_core(variables, cache, state, rng)
 
+            # one name a pool, so that a profile tells the pools'
+            # decode programs apart (jit_step_impl_512, ...).  The name
+            # is also part of JAX's compile-cache key, which named
+            # scopes are not: a persistent cache filled before the
+            # scopes existed cannot hand back a program without them.
+            step_impl.__name__ = f"step_impl_{env}"
             donate = (1, 2) if self._donate else ()
             return jax.jit(step_impl, donate_argnums=donate)
 
@@ -835,6 +858,7 @@ class DecodeEngine:
             return (paging.scatter_cache(pages, cache, table), state,
                     toks, was_done)
 
+        paged_step_impl.__name__ = f"paged_step_impl_{env}"
         donate = (1, 3) if self._donate else ()
         return jax.jit(paged_step_impl, donate_argnums=donate)
 
@@ -859,7 +883,9 @@ class DecodeEngine:
 
             # the WHOLE envelope is replaced, so a dirty evicted slot
             # is clean by construction on readmission
-            cache = jax.tree_util.tree_map(merge, cache, st["cache"])
+            with jax.named_scope("prefill_install"):
+                cache = jax.tree_util.tree_map(merge, cache,
+                                               st["cache"])
             done0 = (n_left0 <= 0) | ((eos_id >= 0) & (tok0 == eos_id))
             state = {
                 "tok": state["tok"].at[slot].set(tok0),
@@ -1799,6 +1825,7 @@ class DecodeEngine:
                         if not pool.queue:
                             break
                         req = pool.queue.popleft()
+                req.t_admit = telemetry.now()
                 admit = (self._admit_segmented if self._segmented
                          else self._prefill_whole)
                 finished.extend(admit(pool, slot, req, variables))
@@ -1827,24 +1854,30 @@ class DecodeEngine:
         try:
             with telemetry.span("prefill", bucket=pool.env,
                                 slot=slot, padded=t_pad,
+                                prompt_tokens=t_p,
                                 request_id=req.rid):
-                if self._paged:
-                    self._set_table_row(pool, slot, req.pages)
-                    (self._pages, pool.state,
-                     tok0) = pool.prefill_fn(
-                        variables, self._pages, pool.table,
-                        pool.state, jnp.asarray(padded), slot,
-                        t_p - 1, n_left0,
-                        -1 if req.eos_id is None else req.eos_id,
-                        self._next_rng())
-                else:
-                    pool.cache, pool.state, tok0 = pool.prefill_fn(
-                        variables, pool.cache, pool.state,
-                        jnp.asarray(padded), slot, t_p - 1,
-                        n_left0,
-                        -1 if req.eos_id is None else req.eos_id,
-                        self._next_rng())
-                req.tokens.append(int(tok0))
+                with telemetry.span("prefill_dispatch"):
+                    if self._paged:
+                        self._set_table_row(pool, slot, req.pages)
+                        (self._pages, pool.state,
+                         tok0) = pool.prefill_fn(
+                            variables, self._pages, pool.table,
+                            pool.state, jnp.asarray(padded), slot,
+                            t_p - 1, n_left0,
+                            -1 if req.eos_id is None else req.eos_id,
+                            self._next_rng())
+                    else:
+                        (pool.cache, pool.state,
+                         tok0) = pool.prefill_fn(
+                            variables, pool.cache, pool.state,
+                            jnp.asarray(padded), slot, t_p - 1,
+                            n_left0,
+                            -1 if req.eos_id is None else req.eos_id,
+                            self._next_rng())
+                with telemetry.span("first_token_sync"):
+                    tok0 = int(tok0)
+                req.tokens.append(tok0)
+                req.t_tokens.append(telemetry.now())
         except Exception as e:
             # Per-request error isolation: a poisoned request is
             # finished with an ``error`` result — its slot stays free
@@ -1856,8 +1889,7 @@ class DecodeEngine:
             self._release_pages(req, pool, slot)
             return [self._finish_error(
                 req, f"prefill_failed: {e!r}", pool.env)]
-        req.t_first = req.t_first or telemetry.now()
-        req.t_last_tok = telemetry.now()
+        req.t_last_tok = req.t_tokens[-1]
         req.traces_seen = sum(self._traces.values())
         m.counter("serving_tokens_total", bucket=pool.env).inc()
         pool.reqs[slot] = req
@@ -1987,6 +2019,7 @@ class DecodeEngine:
                         self._next_rng())
                 if final:
                     req.tokens.append(int(tok0))
+                    req.t_tokens.append(telemetry.now())
         except Exception as e:
             # same per-request isolation contract as _prefill_whole
             pool.reqs[slot] = None
@@ -1998,8 +2031,7 @@ class DecodeEngine:
         if not final:
             return []
         del pool.prefilling[slot]
-        req.t_first = telemetry.now()
-        req.t_last_tok = req.t_first
+        req.t_last_tok = req.t_tokens[-1]
         req.traces_seen = sum(self._traces.values())
         m.counter("serving_tokens_total", bucket=pool.env).inc()
         if req.max_new == 1 or req.tokens[-1] == req.eos_id:
@@ -2151,8 +2183,14 @@ class DecodeEngine:
         are not):
 
         * ``t_submit`` — when ``submit()`` queued the request;
-        * ``t_first``  — when its first token materialized on the host
-          (prefill return), i.e. queue-to-first-token is
+        * ``t_admit``  — when ``_admit`` took it off its queue, so the
+          queue wait is ``t_admit - t_submit``;
+        * ``t_tokens`` — one stamp per generated token, taken where the
+          token reached the host: after the first-token sync of the
+          prefill, and once per pool per step right after the decode
+          fetch (tokens of one fetch share a stamp), so
+          ``len(t_tokens) == len(tokens)``;
+        * ``t_first``  — ``t_tokens[0]``, i.e. queue-to-first-token is
           ``ttft = t_first - t_submit``;
         * ``t_finish`` — when the finished request was evicted;
           completion latency is ``latency = t_finish - t_submit``.
@@ -2191,7 +2229,7 @@ class DecodeEngine:
         return {**req.meta,
                 "request_id": req.rid, "prompt": req.prompt,
                 "tokens": np.asarray(req.tokens, np.int32),
-                "t_submit": req.t_submit, "t_first": req.t_first,
+                **req.times(),
                 "t_finish": t_finish, "ttft": ttft,
                 "latency": latency}
 
@@ -2221,7 +2259,7 @@ class DecodeEngine:
                 "request_id": req.rid, "prompt": req.prompt,
                 "tokens": np.asarray(req.tokens, np.int32),
                 "error": error,
-                "t_submit": req.t_submit, "t_first": req.t_first,
+                **req.times(),
                 "t_finish": t_finish, "ttft": ttft,
                 "latency": t_finish - req.t_submit}
 
@@ -2262,8 +2300,10 @@ class DecodeEngine:
         finished)``."""
         c = 0
         fin = False
+        t_tok = telemetry.now()
         for t in cand:
             req.tokens.append(int(t))
+            req.t_tokens.append(t_tok)
             c += 1
             if (len(req.tokens) >= req.max_new
                     or req.tokens[-1] == req.eos_id):
@@ -2516,8 +2556,14 @@ class DecodeEngine:
         stalling its neighbors' slots."""
         if self._closed:
             raise RuntimeError("engine is closed; step after close()")
-        finished = self._admit()
-        m = telemetry.metrics()
+        # the root span's args are the occupancy ON ENTRY: a profiler
+        # annotation takes its args when it opens
+        with telemetry.span("engine_step", **self.load()):
+            return self._step()
+
+    def _step(self) -> list[dict]:
+        with telemetry.span("admit"):
+            finished = self._admit()
         # one weights snapshot per step: a concurrent swap_variables
         # lands atomically at the next step boundary (see _admit)
         variables = self.variables
@@ -2548,61 +2594,88 @@ class DecodeEngine:
                 # the span covers dispatch AND the host sync
                 # (np.asarray), so its duration is the true
                 # step-quantum latency
-                with telemetry.span("decode_step", bucket=pool.env,
-                                    steps=self.steps_per_sync):
-                    if self._paged:
-                        (self._pages, pool.state, toks,
-                         was_done) = pool.step_fn(
-                            variables, self._pages, pool.table,
-                            pool.state, self._next_rng())
-                    else:
-                        (pool.cache, pool.state, toks,
-                         was_done) = pool.step_fn(
-                            variables, pool.cache, pool.state,
-                            self._next_rng())
-                    toks = np.asarray(toks)
-                    was_done = np.asarray(was_done)
-                n_tok = 0
-                for slot, req in enumerate(pool.reqs):
-                    if req is None:
-                        continue
-                    got = 0
-                    fin = False
-                    for k in range(toks.shape[0]):
-                        if was_done[k, slot]:
-                            break
-                        req.tokens.append(int(toks[k, slot]))
-                        got += 1
-                        if (len(req.tokens) >= req.max_new
-                                or req.tokens[-1] == req.eos_id):
-                            fin = True
-                            break
-                    if got:
-                        self._note_inter_token(req, got)
-                        n_tok += got
-                    if fin:
-                        finished.append(self._finish(pool, slot))
-                if n_tok:
-                    m.counter("serving_tokens_total",
-                              bucket=pool.env).inc(n_tok)
-            # live requests past their deadline free the slot NOW —
-            # graceful degradation under a stuck/slow decode rather
-            # than holding capacity for an answer nobody will take
-            now = telemetry.now()
-            for slot, req in enumerate(pool.reqs):
-                if (req is not None and req.deadline is not None
-                        and now > req.deadline):
-                    pool.reqs[slot] = None
-                    pool.prefilling.pop(slot, None)
-                    self._release_pages(req, pool, slot)
-                    m.counter("serving_shed_total", reason="deadline",
-                              bucket=pool.env).inc()
-                    telemetry.instant("evict", bucket=pool.env,
-                                      slot=slot, request_id=req.rid)
-                    finished.append(self._finish_error(
-                        req, "deadline_exceeded", pool.env))
-            self._note_gauges(pool)
-        finished.extend(self._admit())
+                with telemetry.span(
+                        "decode_step", bucket=pool.env,
+                        steps=self.steps_per_sync,
+                        live=sum(r is not None for r in pool.reqs)):
+                    with telemetry.span("decode_dispatch"):
+                        if self._paged:
+                            (self._pages, pool.state, toks,
+                             was_done) = pool.step_fn(
+                                variables, self._pages, pool.table,
+                                pool.state, self._next_rng())
+                        else:
+                            (pool.cache, pool.state, toks,
+                             was_done) = pool.step_fn(
+                                variables, pool.cache, pool.state,
+                                self._next_rng())
+                    with telemetry.span("decode_fetch"):
+                        toks = np.asarray(toks)
+                        was_done = np.asarray(was_done)
+                # one stamp for every token of this fetch: where they
+                # reached the host
+                t_tok = telemetry.now()
+                with telemetry.span("emit"):
+                    finished.extend(
+                        self._emit(pool, toks, was_done, t_tok))
+            with telemetry.span("sweep"):
+                finished.extend(self._sweep_deadlines(pool))
+                self._note_gauges(pool)
+        with telemetry.span("admit"):
+            finished.extend(self._admit())
+        return finished
+
+    def _emit(self, pool: _Pool, toks, was_done,
+              t_tok: float) -> list[dict]:
+        """Hand the fetched tokens of one decode quantum to their
+        requests, each stamped ``t_tok``, and finish those that
+        ended."""
+        finished = []
+        n_tok = 0
+        for slot, req in enumerate(pool.reqs):
+            if req is None:
+                continue
+            got = 0
+            fin = False
+            for k in range(toks.shape[0]):
+                if was_done[k, slot]:
+                    break
+                req.tokens.append(int(toks[k, slot]))
+                req.t_tokens.append(t_tok)
+                got += 1
+                if (len(req.tokens) >= req.max_new
+                        or req.tokens[-1] == req.eos_id):
+                    fin = True
+                    break
+            if got:
+                self._note_inter_token(req, got)
+                n_tok += got
+            if fin:
+                finished.append(self._finish(pool, slot))
+        if n_tok:
+            telemetry.metrics().counter(
+                "serving_tokens_total", bucket=pool.env).inc(n_tok)
+        return finished
+
+    def _sweep_deadlines(self, pool: _Pool) -> list[dict]:
+        """Live requests past their deadline free the slot NOW —
+        graceful degradation under a stuck/slow decode rather than
+        holding capacity for an answer nobody will take."""
+        finished = []
+        now = telemetry.now()
+        for slot, req in enumerate(pool.reqs):
+            if (req is not None and req.deadline is not None
+                    and now > req.deadline):
+                pool.reqs[slot] = None
+                pool.prefilling.pop(slot, None)
+                self._release_pages(req, pool, slot)
+                telemetry.metrics().counter(
+                    "serving_shed_total", reason="deadline",
+                    bucket=pool.env).inc()
+                telemetry.instant("evict", bucket=pool.env,
+                                  slot=slot, request_id=req.rid)
+                finished.append(self._finish_error(
+                    req, "deadline_exceeded", pool.env))
         return finished
 
     # ---- graceful shutdown --------------------------------------------

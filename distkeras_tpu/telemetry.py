@@ -27,16 +27,29 @@ spiked?".  This module is the one place all of that lands:
   step-quanta / evictions all land on one timeline with one thread
   track each.
 
-Disabled-by-default fast path: the module-level singleton starts as a
-no-op ``Telemetry`` whose metric handles and spans are shared inert
-objects — an instrumented hot path pays one attribute lookup and one
-no-op call (measured sub-microsecond; PERF.md §24) — so tier-1 numerics
-and perf rows are untouched until ``enable()`` is called.  Trainer
-``history`` uses private always-on registries (a ``MetricsRegistry`` is
-just objects + a lock), independent of the global switch.
+A span has two sinks with one switch each.  The ring above is behind
+``enable()``.  The other is the profiler's own trace: every ``span``
+also enters a ``jax.profiler.TraceAnnotation`` named ``dkt:<name>``
+with the span's args as event stats, so that while a ``jax.profiler``
+session runs (``jax.profiler.start_trace``, ``Trainer(profile_dir=)``)
+the span lands on ``/host:CPU`` of the same ``.xplane.pb`` as the
+device's ``XLA Ops`` — one clock, no alignment step.  The profiler
+session is that sink's switch; with none open the annotation tests one
+flag in C++ and formats nothing.  (``complete`` and ``instant`` cannot
+be back-dated into the profiler and feed the ring only.)
 
-Everything here is stdlib-only by design: no prometheus_client, no
-opentelemetry — the export FORMATS are the interop point.
+Disabled-by-default fast path: the module-level singleton starts as a
+no-op ``Telemetry`` whose metric handles are shared inert objects and
+whose spans are bare annotations — an instrumented hot path pays one
+attribute lookup and one call (measured sub-microsecond; PERF.md
+section 6, PR 26) — so tier-1 numerics and perf rows are untouched
+until ``enable()`` is called.  Trainer ``history`` uses private
+always-on registries (a ``MetricsRegistry`` is just objects + a lock),
+independent of the global switch.
+
+No prometheus_client, no opentelemetry — the export FORMATS are the
+interop point; the one import beyond the stdlib is ``jax.profiler``,
+which every other module of the package has loaded already.
 
 Usage::
 
@@ -57,6 +70,8 @@ import os
 import threading
 import time
 from typing import Any, Iterator, Mapping
+
+from jax.profiler import TraceAnnotation
 
 #: THE host-side monotonic clock (satellite: serving ``t_submit`` /
 #: ``t_first`` / ``t_finish``, host-PS ``_last_seen``, and every span
@@ -607,15 +622,18 @@ class _Span:
     inside the span mark ``args["error"]`` and re-raise.  Enter pushes
     ``(trace_id, span_id)`` onto the thread's trace-context stack (for
     wire propagation); exit pops it and stamps both ids into the
-    event's args."""
+    event's args.  The profiler's annotation (``dkt:<name>``) is
+    entered and left with it."""
 
     __slots__ = ("_tracer", "name", "args", "_t0", "trace_id",
-                 "span_id")
+                 "span_id", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = TraceAnnotation(PROFILER_PREFIX + name,
+                                           **args)
 
     def __enter__(self):
         stack = getattr(_trace_ctx, "stack", None)
@@ -625,11 +643,13 @@ class _Span:
         self.span_id = sid
         self.trace_id = stack[-1][0] if stack else sid
         stack.append((self.trace_id, sid))
+        self._annotation.__enter__()
         self._t0 = now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = now()
+        self._annotation.__exit__(exc_type, exc, tb)
         _trace_ctx.stack.pop()
         args = {**self.args, "trace_id": format(self.trace_id, "x"),
                 "span_id": format(self.span_id, "x")}
@@ -639,21 +659,14 @@ class _Span:
         return False
 
 
-class _NoopSpan:
-    """Shared reusable disabled span — ``with`` costs two no-op calls.
-    Safe to share across threads and nestings: enter/exit carry no
-    state."""
+#: Prefix of every span in the profiler's trace (the span's second
+#: sink, a ``TraceAnnotation`` whose stats are the span's args): what
+#: tells the program's own spans from the runtime's on ``/host:CPU``.
+#: An annotation is recorded only while a ``jax.profiler`` session
+#: runs; one entered before a session starts or left after it stops is
+#: dropped whole.
+PROFILER_PREFIX = "dkt:"
 
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_SPAN = _NoopSpan()
 
 # Trace-track thread ids: ``threading.get_ident()`` values are REUSED
 # once a thread exits, which would merge sequential threads onto one
@@ -793,12 +806,13 @@ class Tracer:
 
 
 class NullTracer:
-    """Disabled-path tracer: spans are the shared no-op span."""
+    """Disabled-path tracer: the ring takes nothing, and a span is the
+    bare profiler annotation."""
 
     capacity = 0
 
-    def span(self, name: str, **args) -> _NoopSpan:
-        return _NOOP_SPAN
+    def span(self, name: str, **args) -> TraceAnnotation:
+        return TraceAnnotation(PROFILER_PREFIX + name, **args)
 
     def complete(self, name: str, t0: float, t1: float,
                  **args) -> None:
@@ -848,8 +862,8 @@ class _NullTelemetry:
         self.metrics = NullRegistry()
         self.tracer = NullTracer()
 
-    def span(self, name: str, **args) -> _NoopSpan:
-        return _NOOP_SPAN
+    def span(self, name: str, **args) -> TraceAnnotation:
+        return self.tracer.span(name, **args)
 
     def instant(self, name: str, **args) -> None:
         pass
@@ -881,8 +895,10 @@ def tracer() -> Any:
 
 
 def span(name: str, **args):
-    """``with telemetry.span("commit", worker=i):`` — no-op (one shared
-    inert context manager) while disabled."""
+    """``with telemetry.span("commit", worker=i):`` — into the ring
+    while enabled, and into the profiler's trace as ``dkt:commit``
+    while a ``jax.profiler`` session runs; with neither, one inert
+    annotation."""
     return _active.tracer.span(name, **args)
 
 
@@ -955,47 +971,6 @@ def merge_traces(*traces: Mapping | list) -> dict:
     merged.sort(key=lambda e: (0 if e.get("ph") == "M" else 1,
                                e.get("ts", 0.0)))
     return {"traceEvents": merged, "displayTimeUnit": "ms"}
-
-
-def load_device_trace(path: str, wall_s: float | None = None) -> dict:
-    """Load an XLA device-profiler Chrome trace (the
-    ``*.trace.json.gz`` a ``jax.profiler`` capture writes) into a
-    ``merge_traces``-compatible dict.
-
-    Device timestamps are microseconds RELATIVE to ``start_trace``, not
-    a wall or monotonic clock, so alignment needs the wall time of the
-    capture start: ``profiling.profiler_trace`` drops it as
-    ``wall_anchor.json`` next to the capture, and this loader finds it
-    by walking up from ``path`` (or takes it explicitly via
-    ``wall_s``).  The synthesized ``wallAnchor`` sets ``mono_s=0.0`` —
-    the trace's own zero — so ``merge_traces``' shift formula lands
-    device events on the host tracer's monotonic timeline.  Without an
-    anchor the trace passes through unshifted (still mergeable, just
-    not aligned)."""
-    import gzip
-
-    p = os.fspath(path)
-    opener = gzip.open if p.endswith(".gz") else open
-    with opener(p, "rt") as f:
-        raw = json.load(f)
-    events = (raw.get("traceEvents", [])
-              if isinstance(raw, Mapping) else list(raw))
-    if wall_s is None:
-        probe = os.path.dirname(os.path.abspath(p))
-        for _ in range(8):
-            cand = os.path.join(probe, "wall_anchor.json")
-            if os.path.exists(cand):
-                with open(cand) as f:
-                    wall_s = json.load(f)["wall_s"]
-                break
-            parent = os.path.dirname(probe)
-            if parent == probe:
-                break
-            probe = parent
-    out: dict = {"traceEvents": events, "displayTimeUnit": "ms"}
-    if wall_s is not None:
-        out["wallAnchor"] = {"wall_s": float(wall_s), "mono_s": 0.0}
-    return out
 
 
 def enable(ring_capacity: int = 65536,

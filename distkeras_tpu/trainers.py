@@ -454,10 +454,16 @@ class SingleTrainer(Trainer):
             t_epoch = telemetry.now()
             losses = []
             stall = [0.0]
-            for segment in _epoch_segments(dataset, self.seed + epoch,
-                                           stall):
-                stacked = _stack_batches(segment, self.batch_size,
-                                         self._columns())
+            segments = _epoch_segments(dataset, self.seed + epoch,
+                                       stall)
+            while True:
+                with telemetry.span("segment_wait", epoch=epoch):
+                    segment = next(segments, None)
+                if segment is None:
+                    break
+                with telemetry.span("stack_and_put", epoch=epoch):
+                    stacked = _stack_batches(segment, self.batch_size,
+                                             self._columns())
                 if stacked is None:
                     # a shard file smaller than one batch: dropped like
                     # any other tail remainder (never silently for the
@@ -465,17 +471,27 @@ class SingleTrainer(Trainer):
                     continue
                 n = len(next(iter(stacked.values())))
                 for lo in range(0, n, self.SCAN_CHUNK):
-                    chunk = {k: jnp.asarray(v[lo:lo + self.SCAN_CHUNK])
-                             for k, v in stacked.items()}
-                    state, metrics = run_chunk(state, chunk)
-                    losses.append(np.asarray(metrics["loss"]))
+                    steps = min(self.SCAN_CHUNK, n - lo)
+                    with telemetry.span("stack_and_put", epoch=epoch,
+                                        steps=steps):
+                        chunk = {
+                            k: jnp.asarray(v[lo:lo + self.SCAN_CHUNK])
+                            for k, v in stacked.items()}
+                    with telemetry.span("chunk_dispatch", epoch=epoch,
+                                        steps=steps):
+                        state, metrics = run_chunk(state, chunk)
+                    with telemetry.span("loss_fetch", epoch=epoch,
+                                        steps=steps):
+                        losses.append(np.asarray(metrics["loss"]))
             if not losses:
                 raise ValueError("dataset smaller than one batch")
-            epoch_loss = float(np.concatenate(losses).mean())
-            self._record(epoch_loss=epoch_loss,
-                         segment_stall_s=round(stall[0], 4))
-            self._eval_epoch(state.variables())
-            self._maybe_save(state, {"epoch": epoch + 1})
+            with telemetry.span("epoch_end", epoch=epoch,
+                                steps=sum(len(x) for x in losses)):
+                epoch_loss = float(np.concatenate(losses).mean())
+                self._record(epoch_loss=epoch_loss,
+                             segment_stall_s=round(stall[0], 4))
+                self._eval_epoch(state.variables())
+                self._maybe_save(state, {"epoch": epoch + 1})
             telemetry.complete("epoch", t_epoch, epoch=epoch,
                                trainer=type(self).__name__)
         self.trained_variables = state.variables()

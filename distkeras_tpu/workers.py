@@ -206,35 +206,41 @@ def make_train_step(model, loss, tx: optax.GradientTransformation,
             model_state_in = {k: v for k, v in state.model_state.items()
                               if k != "losses"}
             variables = {"params": params, **model_state_in}
-            logits, new_model_state = model.apply(
-                variables, x, train=True, rngs={"dropout": rng},
-                mutable=mutable_keys)
-            new_model_state = dict(new_model_state)
-            aux_sum = jnp.float32(0.0)
-            for leaf in jax.tree_util.tree_leaves(
-                    new_model_state.get("losses", {})):
-                aux_sum = aux_sum + leaf
-            if "losses" not in carried_keys:
-                # keep the carry's structure identical to the input
-                # state (scan requires it)
-                new_model_state.pop("losses", None)
-            task_loss = loss_fn(logits, y)
+            with jax.named_scope("forward_loss"):
+                logits, new_model_state = model.apply(
+                    variables, x, train=True, rngs={"dropout": rng},
+                    mutable=mutable_keys)
+                new_model_state = dict(new_model_state)
+                aux_sum = jnp.float32(0.0)
+                for leaf in jax.tree_util.tree_leaves(
+                        new_model_state.get("losses", {})):
+                    aux_sum = aux_sum + leaf
+                if "losses" not in carried_keys:
+                    # keep the carry's structure identical to the input
+                    # state (scan requires it)
+                    new_model_state.pop("losses", None)
+                task_loss = loss_fn(logits, y)
             return task_loss + aux_sum, (task_loss, aux_sum,
                                          new_model_state)
 
-        ((loss_val, (task_loss, aux_sum, new_model_state)),
-         grads) = jax.value_and_grad(
-            objective, has_aux=True)(state.params)
-        updates, new_opt_state = tx.update(grads, state.opt_state,
-                                           state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        # the transposed ops inherit the scope they are made under, so
+        # a profile reads .../backward/transpose(jvp(forward_loss))/...
+        with jax.named_scope("backward"):
+            ((loss_val, (task_loss, aux_sum, new_model_state)),
+             grads) = jax.value_and_grad(
+                objective, has_aux=True)(state.params)
+        with jax.named_scope("optimizer_update"):
+            updates, new_opt_state = tx.update(grads, state.opt_state,
+                                               state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   opt_state=new_opt_state,
                                   model_state=new_model_state)
         # "loss" stays the task loss (comparable with eval loss and
         # aux-free runs); the auxiliary sum is reported separately.
         metrics = {"loss": task_loss, "aux_loss": aux_sum,
-                   "grad_norm": optax.global_norm(grads)}
+                   "grad_norm": grad_norm}
         return new_state, metrics
 
     return step

@@ -1,0 +1,3 @@
+"""Reader of ``steady_idle_in_emit_ms``: see ``perfbench/layers_spans.py``."""
+
+from perfbench.layers_spans import idle_in_emit_ms as read  # noqa: F401

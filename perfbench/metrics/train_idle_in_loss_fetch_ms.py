@@ -1,0 +1,3 @@
+"""Reader of ``train_idle_in_loss_fetch_ms``: see ``perfbench/layers_spans.py``."""
+
+from perfbench.layers_spans import idle_in_loss_fetch_ms as read  # noqa: F401

@@ -44,7 +44,6 @@ SMOKE_SCRIPTS = {
     "perf_serving.py": ["--smoke"],
     "perf_spec.py": ["--smoke"],
     "postmortem.py": ["--smoke"],
-    "trace_merge.py": ["--smoke"],
 }
 # registered but out of tier-1: the roofline smoke sweeps many op
 # shapes and runs minutes-long on the CI CPU (run with -m slow)

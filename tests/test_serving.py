@@ -12,6 +12,7 @@ import pytest
 
 from distkeras_tpu.models import ModelSpec, generate, model_config
 from distkeras_tpu.serving import DecodeEngine, ShedError
+from profiled import Profiled
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -564,3 +565,117 @@ def test_run_under_queue_bound_delivers_error_rows_too():
                 r["tokens"],
                 _want(model, variables, prompts[r["i"]], 4))
     eng.close()
+
+
+# ---- the step's span tree and a request's times (PR 26) ---------------
+
+def _traced_step(tmp_path):
+    """One profiled ``step()`` of a warm engine: three admissions into
+    the three slots of one pool, then one decode of all three."""
+    model, variables = _model()
+    eng = DecodeEngine(model, variables, slots=3, buckets=[16],
+                       prefill_align=4, steps_per_sync=2)
+    prompts = _prompts([5, 9, 3, 7])
+    for p in prompts:  # compile outside the profiled step
+        eng.submit(p, max_new_tokens=4)
+    while eng.has_work():
+        eng.step()
+    for i, p in enumerate(prompts):
+        eng.submit(p, max_new_tokens=4, request_id=f"r{i}")
+    with Profiled(tmp_path) as prof:
+        out = eng.step()
+    while eng.has_work():
+        out += eng.step()
+    return prof, prompts, out
+
+
+def test_one_traced_step_yields_the_span_tree(tmp_path):
+    prof, prompts, _ = _traced_step(tmp_path)
+    (root,) = prof.named("engine_step")
+    # the occupancy as it stood on entry: four queued, nothing live
+    assert root["stats"] == {"live": 0, "queued": 4, "parked": 0}
+    first, second = prof.named("admit")     # both passes
+    assert prof.parent(first) is root and prof.parent(second) is root
+    prefills = prof.named("prefill")
+    assert [p["stats"] for p in prefills] == [
+        {"bucket": 16, "slot": i, "padded": -(-len(prompts[i]) // 4) * 4,
+         "prompt_tokens": len(prompts[i]), "request_id": f"r{i}"}
+        for i in range(3)]
+    for p, d, s in zip(prefills, prof.named("prefill_dispatch"),
+                       prof.named("first_token_sync")):
+        assert prof.parent(p) is first
+        assert prof.parent(d) is p and prof.parent(s) is p
+        assert d["end"] <= s["start"]
+    (decode,) = prof.named("decode_step")
+    assert prof.parent(decode) is root
+    assert decode["stats"] == {"bucket": 16, "steps": 2, "live": 3}
+    (dispatch,) = prof.named("decode_dispatch")
+    (fetch,) = prof.named("decode_fetch")
+    assert prof.parent(dispatch) is decode and prof.parent(fetch) is decode
+    (emit,) = prof.named("emit")
+    (sweep,) = prof.named("sweep")
+    assert prof.parent(emit) is root and prof.parent(sweep) is root
+    assert first["end"] <= decode["start"] <= fetch["end"] \
+        <= emit["start"] <= sweep["start"] <= second["start"]
+    # nothing of the step lies outside these names
+    assert {s["name"] for s in prof.spans} == {
+        "dkt:" + n for n in (
+            "engine_step", "admit", "prefill", "prefill_dispatch",
+            "first_token_sync", "decode_step", "decode_dispatch",
+            "decode_fetch", "emit", "sweep")}
+
+
+def _check_times(res):
+    assert len(res["t_tokens"]) == len(res["tokens"])
+    assert all(isinstance(t, float) for t in res["t_tokens"])
+    assert res["t_tokens"] == sorted(res["t_tokens"])
+    if res["t_tokens"]:
+        assert res["t_submit"] <= res["t_admit"] <= res["t_tokens"][0]
+        assert res["t_tokens"][0] == res["t_first"]
+        assert res["t_tokens"][-1] <= res["t_finish"]
+    else:
+        assert res["t_first"] is None
+
+
+@pytest.mark.parametrize("engine_kw", [
+    {"steps_per_sync": 2},
+    {"steps_per_sync": 2, "prefix_cache_bytes": 1 << 20,
+     "prefill_chunk": 4},
+    {"steps_per_sync": 2, "kv_pages": 24},
+    {"speculative": {"proposer": "ngram", "k": 2, "ngram": 2}},
+], ids=["one_shot", "chunked", "paged", "speculative"])
+def test_every_token_is_stamped_where_it_reaches_the_host(engine_kw):
+    """Every path that appends a token stamps it: a result's
+    ``t_tokens`` has one time a token, between admission and finish."""
+    model, variables = _model()
+    prompts = _prompts([5, 9, 3, 7, 6, 11])
+    eng = DecodeEngine(model, variables, slots=2, buckets=[16, 32],
+                       prefill_align=4, **engine_kw)
+    out = list(eng.run([{"prompt": p, "max_new_tokens": 5 + i % 3}
+                        for i, p in enumerate(prompts)]))
+    assert len(out) == len(prompts)
+    for res in out:
+        assert "error" not in res
+        _check_times(res)
+        np.testing.assert_array_equal(
+            res["tokens"],
+            _want(model, variables, res["prompt"], len(res["tokens"])))
+
+
+def test_error_results_carry_the_time_fields_too():
+    model, variables = _model()
+    eng = DecodeEngine(model, variables, slots=1, buckets=[16],
+                       prefill_align=4)
+    a, b = _prompts([5, 6])
+    eng.submit(a, max_new_tokens=8, request_id="live")
+    eng.submit(b, max_new_tokens=8, request_id="queued")
+    eng.step()                     # "live" holds the one slot
+    by_id = {r["request_id"]: r for r in eng.close()}
+    assert by_id["live"]["error"] == by_id["queued"]["error"] \
+        == "engine_closed"
+    _check_times(by_id["live"])
+    assert len(by_id["live"]["tokens"]) >= 1
+    # never admitted: both keys are there, and empty
+    assert by_id["queued"]["t_admit"] is None
+    assert by_id["queued"]["t_tokens"] == []
+    _check_times(by_id["queued"])

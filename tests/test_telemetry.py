@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from distkeras_tpu import telemetry
+from profiled import Profiled
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -161,11 +162,61 @@ def test_disabled_fast_path_is_inert():
     m.counter("a").inc()
     m.histogram("h").observe(1.0)
     assert m.snapshot()["counters"] == {}
-    with telemetry.span("x", k=1) as s:
-        inner = s
-    assert inner is telemetry.span("y")  # the one shared no-op span
+    with telemetry.span("x", k=1):
+        pass
     telemetry.instant("e")
     assert telemetry.tracer().events() == []
+    # no span pushes trace context while disabled (wire header stays 0 B)
+    with telemetry.span("y"):
+        assert telemetry.current_trace() is None
+
+
+# ---- the span's second sink: the profiler's own trace -----------------
+
+def test_disabled_span_lands_in_an_open_profiler_session(tmp_path):
+    """With telemetry disabled, the profiler session alone is the
+    switch: the span is in the ``.xplane.pb`` as ``dkt:x`` with its
+    args as stats, and the ring takes nothing."""
+    telemetry.disable()
+    with Profiled(tmp_path) as p:
+        with telemetry.span("x", k=1, who="w"):
+            with telemetry.span("inner"):
+                pass
+    (x,) = p.named("x")
+    assert x["stats"] == {"k": 1, "who": "w"}
+    (inner,) = p.named("inner")
+    assert p.parent(inner) is x and inner["stats"] == {}
+    assert telemetry.tracer().events() == []
+
+
+def test_span_without_session_or_enable_records_nothing(tmp_path):
+    telemetry.disable()
+    with telemetry.span("before", k=1):
+        pass
+    assert telemetry.tracer().events() == [] and len(telemetry.tracer()) == 0
+    # a span left open across the session's start, and one opened
+    # inside it and left after its stop, are dropped whole
+    early = telemetry.span("early")
+    early.__enter__()
+    with Profiled(tmp_path) as p:
+        early.__exit__(None, None, None)
+        late = telemetry.span("late")
+        late.__enter__()
+    late.__exit__(None, None, None)
+    assert p.spans == []
+
+
+def test_enabled_span_feeds_both_sinks(tmp_path, tel):
+    with Profiled(tmp_path) as p:
+        with telemetry.span("both", k=2):
+            pass
+        telemetry.instant("ring_only")
+        telemetry.complete("ring_only_too", telemetry.now())
+    assert [s["name"] for s in p.spans] == ["dkt:both"]
+    assert p.spans[0]["stats"] == {"k": 2}
+    ring = {e["name"]: e for e in tel.tracer.events()}
+    assert set(ring) == {"both", "ring_only", "ring_only_too"}
+    assert ring["both"]["args"]["k"] == 2  # the ring's name: no prefix
 
 
 # ---- tracer / Perfetto format -----------------------------------------
@@ -672,9 +723,7 @@ def test_chaos_kill_restart_traced_flight_and_postmortem(tmp_path, tel):
     * the Perfetto trace validates WITH flow-event pairing: every
       surviving commit's server ``ps_rpc`` handler span carries a
       ``link_span`` that resolves to exactly one client-side wire span
-      (chaos-eaten sends leave legal orphan flow-starts).  The genuine
-      cross-PROCESS merge of the same arrows is proven by
-      ``scripts/trace_merge.py --smoke`` (tier-1 via test_examples);
+      (chaos-eaten sends leave legal orphan flow-starts);
     * the flight recorder survives the crash with the whole story —
       commits, snapshots, chaos injections, client retries, the
       ``ps_kill`` marker, the ``ps_restart`` marker — and the max

@@ -330,3 +330,31 @@ def test_commit_overlap_validation():
     with pytest.raises(ValueError, match="resume"):
         DOWNPOUR(MLP, commit_overlap=True, **common).train(
             DATA, resume_from="/tmp/nonexistent")
+
+
+def test_single_trainer_spans_in_a_profiler_session(tmp_path):
+    """A traced ``SingleTrainer.train()`` shows where its host loop
+    spends a chunk: the five ``dkt:`` spans, with the epoch and the
+    chunk's steps as stats, inside ``dkt:train``."""
+    from profiled import Profiled
+
+    t = SingleTrainer(MLP, batch_size=64, num_epoch=2)
+    with Profiled(tmp_path) as prof:
+        t.train(DATA)
+    (root,) = prof.named("train")
+    assert root["stats"] == {"trainer": "SingleTrainer"}
+    steps = 2048 // 64
+    for name in ("segment_wait", "stack_and_put", "chunk_dispatch",
+                 "loss_fetch", "epoch_end"):
+        spans = prof.named(name)
+        assert spans, name
+        assert all(prof.parent(s) is root for s in spans), name
+        assert sorted({s["stats"]["epoch"] for s in spans}) == [0, 1], name
+    # one segment an epoch (and the pull that finds the epoch's end),
+    # one chunk of 32 steps (SCAN_CHUNK is 64)
+    assert len(prof.named("segment_wait")) == 4
+    for name in ("chunk_dispatch", "loss_fetch", "epoch_end"):
+        assert [s["stats"]["steps"] for s in prof.named(name)] == \
+            [steps, steps], name
+    d, f = prof.named("chunk_dispatch")[0], prof.named("loss_fetch")[0]
+    assert d["end"] <= f["start"]
