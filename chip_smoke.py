@@ -22,6 +22,9 @@ checked):
 * ``serve/lm``      — ``DecodeEngine`` over the trained variables,
   envelope pools and the paged pool: greedy tokens equal
   ``models.generate()`` per request, byte for byte.
+* ``serve/pools``   — a two-layer engine with heads of 128, the
+  benchmark configuration's head size: its compiled step and prefill
+  programs, handed their pool, hold no copy of a whole pool leaf.
 * ``four chips/…``  — only where ``len(jax.devices()) >= 4``:
   ``DOWNPOUR(fidelity="mesh")`` one worker per chip, then the LM
   through ``SyncTrainer(num_workers=4)`` (blockwise attention: Mosaic
@@ -53,7 +56,7 @@ import numpy as np
 
 from distkeras_tpu import attrib, native, profiling, telemetry
 from distkeras_tpu.data import datasets
-from distkeras_tpu.models import generate, model_config
+from distkeras_tpu.models import ModelSpec, generate, model_config
 from distkeras_tpu.models.transformer import dense_causal_attention
 from distkeras_tpu.ops.attention import flash_attention
 from distkeras_tpu.serving import DecodeEngine
@@ -78,6 +81,10 @@ KERNELS = dict(batch=8, seq=2048, heads=12, head_dim=64, ref_batch=2,
 # compile count stays at one prefill + one step program per bucket
 SERVE = dict(buckets=(512, 1024, 2048), align=128, slots=4, kv_pages=64,
              requests=((128, 16), (512, 32), (1024, 64)) * 3)
+# heads of 128 as in the benchmark's configuration; one prompt a bucket
+POOLS = dict(layers=2, d_model=1024, heads=8, vocab=4096, seq=1024,
+             buckets=(512, 1024), align=128, slots=4,
+             requests=((100, 4), (600, 4)))
 # four chips: batches are per chip
 MESH_PS = dict(workers=4, batch=64, window=2)
 SYNC_LM = dict(workers=4, batch=4, steps=3)
@@ -354,6 +361,43 @@ def train_lm(*, layers, d_model, heads, vocab, seq, batch, steps,
     return facts, cfg, variables
 
 
+def pools_in_place(*, layers, d_model, heads, vocab, seq, buckets, align,
+                   slots, requests) -> dict:
+    """A small engine's compiled step and prefill programs hold no copy
+    of a whole pool leaf (``DecodeEngine.pool_report``): the cache is
+    declared in the order the decode step computes in, so a program
+    that is handed a pool (donated: every engine on a chip) works on it
+    in place.  That holds in every step or in none, so this count,
+    zero, is the whole of the mechanism's counter; the pool's layout is
+    printed with it.  Heads of 128: at this script's LM's 64 the TPU
+    lays a ``[.., KVH, 64]`` leaf out another way by default and the
+    step re-lays it out, as it did before PR 27 (48 whole-leaf copies
+    in its 12-layer step program either way; PERF.md section 7)."""
+    cfg = lm_config(layers=layers, d_model=d_model, heads=heads,
+                    vocab=vocab, seq=seq)
+    model = ModelSpec.from_config(cfg).build()
+    variables = jax.jit(model.init)(jax.random.key(0),
+                                    jnp.zeros((1, align), jnp.int32))
+    engine = DecodeEngine(cfg, variables, slots=slots,
+                          buckets=list(buckets), prefill_align=align)
+    rng = np.random.default_rng(0)
+    for res in engine.run(
+            [{"prompt": rng.integers(0, vocab, (t,)).astype(np.int32),
+              "max_new_tokens": n} for t, n in requests]):
+        if "error" in res:
+            raise AssertionError(f"pools: {res['error']}")
+    report = engine.pool_report()
+    engine.close()
+    for pool in report:
+        held = {name: n["relayouts"]
+                for name, n in pool["programs"].items() if n["relayouts"]}
+        if pool["donated"] and (held or not pool["programs"]):
+            raise AssertionError(
+                f"pool {pool['bucket']} ({pool['layout']}) is copied "
+                f"whole inside its own programs: {held}")
+    return {"pools": report}
+
+
 def serve_lm(cfg: dict, variables: dict, *, buckets, align, slots,
              kv_pages, requests) -> dict:
     vocab = cfg["kwargs"]["vocab_size"]
@@ -510,6 +554,8 @@ def main() -> None:
         rec.update(facts)
     with phase("serve/lm", meter, device) as rec:
         rec.update(serve_lm(lm_cfg, lm_variables, **SERVE))
+    with phase("serve/pools", meter, device) as rec:
+        rec.update(pools_in_place(**POOLS))
     if device["count"] >= 4:
         with phase("four chips/ps-mesh", meter, device) as rec:
             rec.update(mesh_ps_on_chips(**RESNET, **MESH_PS))

@@ -74,10 +74,20 @@ class SelfAttention(nn.Module):
     """``cache_len > 0`` switches on autoregressive decode mode: K/V
     projections of every token seen so far persist in a ``"cache"``
     variable collection (``cached_key``/``cached_value`` sized
-    ``[B, KVH, cache_len, D]`` — length contiguous, the measured
-    decode-bandwidth layout — plus an insertion ``cache_index``), and
+    ``[B, cache_len, KVH, D]``, plus an insertion ``cache_index``), and
     each call appends its T tokens and attends back over the whole
-    prefix.  A multi-token call (prefill) with an ``attn_fn`` runs the
+    prefix.  Position outside heads is the order a TPU v5e computes
+    the slot-mode step in: declared ``[B, KVH, L, D]`` (as it was until
+    PR 27) the compiled step copied every leaf whole into
+    ``B, L, KVH, D`` order on the way in and back on the way out, 53 of
+    84 ms a step in the serving cells (ledger, PR 26), because the
+    per-row scatter asks for that order and both einsums read it at
+    full rate (compile for a described ``v5e:2x2`` and chip runs of
+    PR 27, PERF.md section 6).  Declared so, row-major is that order
+    and no program that is handed the cache re-lays it out (heads of
+    128; a TPU lays a leaf with heads of 64 out another way by default,
+    and copies it under either declaration: PERF.md section 7).  A
+    multi-token call (prefill) with an ``attn_fn`` runs the
     chunk through that kernel instead of the dense cache read — exact
     iff the cache was empty (poisoned loud otherwise).  No counterpart
     in the reference — it predates autoregressive serving entirely
@@ -137,12 +147,13 @@ class SelfAttention(nn.Module):
             b, t = x.shape[0], x.shape[1]
             quant = self.kv_cache_dtype == "int8"
             store = jnp.int8 if quant else k.dtype
-            # [B, KVH, L, D]: the per-step attention contracts over L,
-            # so L must be the contiguous-row axis — the round-5
-            # decode roofline measured the [B, L, KVH, D] layout's
-            # strided reads at ~1/4 effective HBM bandwidth (PERF.md
-            # §18 addendum)
-            shape = (b, kvh, self.cache_len, head_dim)
+            # [B, L, KVH, D]: the order the compiled slot-mode step
+            # works in on a v5e (the scatter asks for it, the einsums
+            # read it at full rate), so the default row-major layout is
+            # already the one every program computes in and none
+            # copies a whole leaf to change it (PR 27: the decode
+            # programs of an engine step fell from 84 to 26.5 ms)
+            shape = (b, self.cache_len, kvh, head_dim)
             ck = self.variable("cache", "cached_key", jnp.zeros, shape,
                                store)
             cv = self.variable("cache", "cached_value", jnp.zeros,
@@ -159,20 +170,18 @@ class SelfAttention(nn.Module):
             rows = jnp.arange(b)
 
             def write(cache, chunk):
-                # chunk: [B, T, ...] -> cache [B, KVH, L, ...]
-                chunk = jnp.swapaxes(chunk, 1, 2)
+                # chunk: [B, T, KVH, ...] into cache [B, L, KVH, ...]
                 if slot_pos is not None:
                     # per-row scatter: row b writes its single token at
                     # its OWN position (OOB positions drop the update;
                     # the ok-poison below keeps that loud)
-                    return cache.at[rows, :, slot_pos, :].set(
-                        chunk[:, :, 0])
+                    return cache.at[rows, slot_pos].set(chunk[:, 0])
                 return lax.dynamic_update_slice(cache, chunk,
-                                                (0, 0, idx, 0))
+                                                (0, idx, 0, 0))
 
             with jax.named_scope("kv_write"):
                 if quant:
-                    sshape = (b, kvh, self.cache_len, 1)
+                    sshape = (b, self.cache_len, kvh, 1)
                     ks = self.variable("cache", "key_scale", jnp.zeros,
                                        sshape, jnp.float32)
                     vs = self.variable("cache", "value_scale",
@@ -213,9 +222,8 @@ class SelfAttention(nn.Module):
                 # mask over the full cache (future slots are zeros AND
                 # masked).  The grouped einsum attends each query-head
                 # group to its shared K/V head without materializing a
-                # repeated cache; the cache's [B, KVH, L, D] layout
-                # keeps the L contraction contiguous.  For the int8
-                # cache the per-row scales FACTOR OUT of both
+                # repeated cache.  For the int8 cache
+                # the per-row scales FACTOR OUT of both
                 # contractions (they are constant over the contracted
                 # d axis / ride the k axis), so the quantized cache
                 # feeds the einsum through a fusable cast — never a
@@ -235,20 +243,25 @@ class SelfAttention(nn.Module):
                     # [B|1, t, L]: per-row causal horizon in slot mode
                     mask = k_pos[None, None, :] <= q_pos[:, :, None]
                     qg = q.reshape(b, t, kvh, group, head_dim)
-                    logits = jnp.einsum("bqhgd,bhkd->bhgqk", qg, keys) \
+                    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys) \
                         * scale
+
+                    def per_key(scales):
+                        # [B, L, KVH, 1] -> [B, KVH, 1, 1, L]: one
+                        # factor a cached row, over (g, q)
+                        return jnp.swapaxes(
+                            scales[..., 0], 1, 2)[:, :, None, None, :]
+
                     if quant:
-                        # ks: [B, KVH, L, 1] -> broadcast over (g, q)
-                        logits = logits * ks.value[:, :, None, None, :, 0]
+                        logits = logits * per_key(ks.value)
                     logits = jnp.where(mask[:, None, None], logits,
                                        -1e30)
                     probs = nn.softmax(logits.astype(jnp.float32),
                                        axis=-1).astype(q.dtype)
                     if quant:
                         probs = (probs.astype(jnp.float32)
-                                 * vs.value[:, :, None, None, :, 0]
-                                 ).astype(q.dtype)
-                    out = jnp.einsum("bhgqk,bhkd->bqhgd", probs, vals)
+                                 * per_key(vs.value)).astype(q.dtype)
+                    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vals)
                     out = out.reshape(b, t, self.num_heads, head_dim)
             if jnp.ndim(ok):          # slot mode: per-row poison only
                 ok = ok[:, None, None, None]

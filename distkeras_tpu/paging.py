@@ -15,7 +15,7 @@ XLA cannot index a cache through a dynamic page table inside the
 attention kernel without a custom pager, so the lowering here keeps
 the *compute* byte-identical to the envelope path instead of
 rewriting it: each compiled program GATHERS a bucket's slot pages into
-the exact envelope layout (``[slots, KVH, env, D]``), runs the
+the exact envelope layout (``[slots, env, KVH, D]``), runs the
 UNCHANGED legacy step/prefill body, and SCATTERS the envelope back
 into the pages.  Greedy parity with the envelope pool is therefore
 structural, not numerical — the attention sees the same unmasked rows
@@ -31,8 +31,8 @@ rows) and the gather never faults.  ``PageAllocator`` hands out ids
 the admission-time substrate for the engine's QoS scheduler
 (priority classes, preemption, readmission).
 
-The pool layout per 4-D cache leaf is ``[n_pages + 1, KVH,
-page_size, D]`` — envelope-free, exactly ``_PrefixStore``'s segment
+The pool layout per 4-D cache leaf is ``[n_pages + 1, page_size,
+KVH, D]`` — envelope-free, exactly ``_PrefixStore``'s segment
 shape batched over pages — so with ``page_size == prefill_align``
 prefix sharing and paging are one mechanism: a prefix-cache hit is a
 device copy into a page, donation is a page slice out.
@@ -57,7 +57,7 @@ def pages_for(tokens: int, page_size: int) -> int:
 
 
 def build_pool(cache_shapes, n_pages: int, page_size: int) -> list:
-    """Zeroed device page pool: one ``[n_pages + 1, KVH, page, D]``
+    """Zeroed device page pool: one ``[n_pages + 1, page, KVH, D]``
     leaf per 4-D cache leaf of ``cache_shapes`` (an ``eval_shape``
     cache template), in flatten order — scalar cache/pos-index leaves
     are skipped, exactly like ``_PrefixStore`` segments.  Row 0 is the
@@ -69,7 +69,7 @@ def build_pool(cache_shapes, n_pages: int, page_size: int) -> list:
         if len(leaf.shape) == 0:
             continue
         out.append(jnp.zeros(
-            (n_pages + 1, leaf.shape[1], page_size, leaf.shape[3]),
+            (n_pages + 1, page_size) + tuple(leaf.shape[2:]),
             leaf.dtype))
     return out
 
@@ -85,7 +85,7 @@ def leaf_templates(segments) -> list[dict]:
     gather-sent frame back into typed arrays without any per-leaf
     framing (``serving.pack_kv_blocks`` / ``unpack_kv_blocks``).
     Every block of one export shares these templates: blocks are
-    ``[1, KVH, page, D]`` slices of the same pool leaves."""
+    ``[1, page, KVH, D]`` slices of the same pool leaves."""
     return [{"shape": [int(d) for d in np.asarray(s).shape],
              "dtype": str(np.asarray(s).dtype)} for s in segments]
 
@@ -103,11 +103,11 @@ def gather_cache(cache_shapes, pages: list, table):
         if len(tmpl.shape) == 0:
             out.append(jnp.zeros((), tmpl.dtype))
             continue
-        p = next(segs)                     # [P+1, KVH, page, D]
-        x = p[table]                       # [S, MB, KVH, page, D]
-        x = jnp.moveaxis(x, 1, 2)          # [S, KVH, MB, page, D]
-        out.append(x.reshape(table.shape[0], p.shape[1],
-                             table.shape[1] * p.shape[2], p.shape[3]))
+        p = next(segs)                     # [P+1, page, KVH, D]
+        x = p[table]                       # [S, MB, page, KVH, D]
+        out.append(x.reshape(table.shape[0],
+                             table.shape[1] * p.shape[1],
+                             *p.shape[2:]))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -124,11 +124,9 @@ def scatter_cache(pages: list, cache, table) -> list:
         if jnp.ndim(leaf) == 0:
             continue
         p = next(segs)
-        s, kvh, env, d = leaf.shape
-        mb, page = table.shape[1], p.shape[2]
-        x = leaf.reshape(s, kvh, mb, page, d)
-        x = jnp.moveaxis(x, 2, 1).reshape(s * mb, kvh, page, d)
-        out.append(p.at[flat].set(x))
+        # [S, env, KVH, D]: a slot's pages are consecutive rows
+        out.append(p.at[flat].set(
+            leaf.reshape((flat.shape[0],) + p.shape[1:])))
     return out
 
 

@@ -13,10 +13,12 @@ spent on drained rows and oversized envelopes.
 Orca (Yu et al., OSDI '22) / vLLM (Kwon et al., SOSP '23) architecture
 adapted to XLA's static-shape world:
 
-* a persistent ``[slots, ...]`` KV-cache POOL lives on device across
-  requests, one pool per ``max_len`` BUCKET (e.g. 512/1024/2048
-  envelopes), so a short request never pays a long request's static
-  cache;
+* a persistent ``[slots, envelope, KVH, D]`` KV-cache POOL lives on
+  device across requests, one pool per ``max_len`` BUCKET (e.g.
+  512/1024/2048 envelopes), so a short request never pays a long
+  request's static cache; every program is handed the pool (donated)
+  and works on it in place: the declared order is the one the step
+  computes in, so none re-lays it out (``pool_report``);
 * one compiled STEP program per bucket advances every live slot by one
   token (``slot_pos`` per-row cache positions; per-slot eos /
   remaining-token state rides along), ``steps_per_sync`` steps per
@@ -55,7 +57,7 @@ remaining hot-path waste):
 * ``prefix_cache_bytes`` turns on a SHARED-PREFIX KV CACHE — a
   host-side longest-prefix trie over token ids at ``prefill_align``
   granularity (SGLang's RadixAttention idea, Zheng et al. 2024) whose
-  nodes hold ref-counted DEVICE segments (``[1, KVH, align, D]`` per
+  nodes hold ref-counted DEVICE segments (``[1, align, KVH, D]`` per
   cache leaf, envelope-free so one store serves every bucket).  On
   admit, the longest cached prefix is copied device-to-device into
   the slot (``dynamic_update_slice``, zero model FLOPs) and only the
@@ -76,7 +78,7 @@ keep the compiled program set bounded; with both off, the legacy
 one-shot prefill path is byte-identical to before.
 
 Disaggregated prefill/decode (ISSUE 19): because prefix-store segments
-and KV pages are the same ``[1, KVH, align, D]`` blocks, a finished
+and KV pages are the same ``[1, align, KVH, D]`` blocks, a finished
 prefill's cache is a SHIPPABLE currency.  ``export_prefix`` pulls a
 prompt's cached blocks out of the store as host arrays,
 ``import_prefix`` installs a shipped block set into another engine's
@@ -123,7 +125,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distkeras_tpu import flight_recorder, paging, telemetry
+from distkeras_tpu import flight_recorder, layouts, paging, telemetry
 from distkeras_tpu import speculative as _speculative
 from distkeras_tpu.analysis import racecheck
 from distkeras_tpu.models.generate import (_decode_model, _select,
@@ -292,7 +294,7 @@ class _Request:
 
 class _PrefixNode:
     """One ``prefill_align``-sized block of a cached prefix: the K/V
-    rows for its token block as device arrays (one ``[1, KVH, align,
+    rows for its token block as device arrays (one ``[1, align, KVH,
     D|1]`` segment per 4-D cache leaf, in flatten order — envelope-
     free, so one store serves every bucket)."""
 
@@ -756,6 +758,8 @@ class DecodeEngine:
             pool.cache = jax.tree_util.tree_map(
                 lambda sh: jnp.zeros(sh.shape, sh.dtype), shapes)
             pool.table = pool.table_np = None
+            telemetry.instant("pool_layout", bucket=pool.env,
+                              layout=layouts.describe(pool.cache))
         pool.state = {
             "tok": jnp.full((s,), self.pad_id, jnp.int32),
             "pos": jnp.zeros((s,), jnp.int32),
@@ -1171,7 +1175,7 @@ class DecodeEngine:
 
     def _make_page_extract(self):
         """Prefix donation in paged mode: slice one page out as a
-        ``[1, KVH, page, D]`` store segment (fresh buffers — the pool
+        ``[1, page, KVH, D]`` store segment (fresh buffers — the pool
         keeps its own).  One compiled program for the engine."""
         def page_extract_impl(pages, pid):
             self._traces["page_extract", self.page_size] += 1
@@ -1201,7 +1205,7 @@ class DecodeEngine:
                     out.append(leaf)
                     continue
                 out.append(jax.lax.dynamic_update_slice(
-                    leaf, next(segs), (slot, 0, start, 0)))
+                    leaf, next(segs), (slot, start, 0, 0)))
             return jax.tree_util.tree_unflatten(treedef, out)
 
         donate = (0,) if self._donate else ()
@@ -1223,8 +1227,8 @@ class DecodeEngine:
                 if jnp.ndim(leaf) == 0:
                     continue
                 out.append(jax.lax.dynamic_slice(
-                    leaf, (slot, 0, start, 0),
-                    (1, leaf.shape[1], align, leaf.shape[3])))
+                    leaf, (slot, start, 0, 0),
+                    (1, align) + leaf.shape[2:]))
             return out
 
         return jax.jit(extract_impl)
@@ -2827,3 +2831,45 @@ class DecodeEngine:
         these constant across ragged arrivals (the §23 bounded-
         program-set claim; pinned by the tier-1 compile guard)."""
         return dict(self._traces)
+
+    def pool_report(self) -> list[dict]:
+        """One record an envelope pool, for an operator or a smoke
+        check: its envelope, the layout its K/V leaves live in, and for
+        its step program and each one-shot prefill program traced so
+        far how many operations of the compiled HLO copy a whole leaf
+        of the pool: ``relayouts`` (a layout change or a second
+        instance) and ``moves`` (into faster memory and back;
+        ``layouts.whole_leaf_copies``).  Where the programs are handed
+        the pool (``donated``) a ``relayouts`` above 0 means that the
+        cache is not declared in the order that program works in.
+        Every program is compiled again for its text (from the
+        persistent cache where that holds it): seconds each at a real
+        model's size, so not for a serving loop.  The paged arm has no
+        envelope pools and reports none."""
+        report = []
+        for pool in self._pools:
+            if pool.cache is None:
+                continue
+            lowered = {}
+            if ("step", pool.env) in self._traces:
+                lowered["step"] = pool.step_fn.lower(
+                    self.variables, pool.cache, pool.state, self._key)
+            for _, _, t_pad in sorted(
+                    k for k in self._traces
+                    if k[:2] == ("prefill", pool.env)):
+                lowered[f"prefill_{t_pad}"] = pool.prefill_fn.lower(
+                    self.variables, pool.cache, pool.state,
+                    jax.ShapeDtypeStruct((1, t_pad), jnp.int32),
+                    0, 0, 0, -1, self._key)
+            programs = {}
+            for name, low in lowered.items():
+                text = low.compile().as_text()
+                programs[name] = {
+                    "relayouts": layouts.whole_leaf_copies(
+                        text, pool.cache_tmpl),
+                    "moves": layouts.whole_leaf_copies(
+                        text, pool.cache_tmpl, moves=True)}
+            report.append({"bucket": pool.env, "donated": self._donate,
+                           "layout": layouts.describe(pool.cache),
+                           "programs": programs})
+        return report

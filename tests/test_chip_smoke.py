@@ -85,6 +85,17 @@ def test_train_then_serve_lm_toy(smoke):
     assert served["envelope"]["programs"] == served["paged"]["programs"]
 
 
+def test_pools_in_place_toy(smoke):
+    """Nothing is handed over on the CPU, so nothing can fail here but
+    the structure: a record a pool, its step and its one prefill."""
+    facts = smoke.pools_in_place(
+        layers=1, d_model=32, heads=2, vocab=64, seq=32, buckets=(16, 32),
+        align=4, slots=2, requests=((4, 3), (12, 8)))
+    assert [p["bucket"] for p in facts["pools"]] == [16, 32]
+    assert all(set(p["programs"]) == {"step", f"prefill_{t}"}
+               for p, t in zip(facts["pools"], (4, 12)))
+
+
 def test_four_devices_toy(smoke, devices):
     facts = smoke.mesh_ps_on_chips(**RESNET, workers=4, batch=4, window=2)
     assert facts["center_device_sets"] == [4]
