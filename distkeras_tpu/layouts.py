@@ -37,6 +37,25 @@ _COPY = re.compile(
 _MEMORY_SPACE = re.compile(r"S\(\d+\)|,$")
 
 
+LANES = 128
+
+
+def lane_padded(width: int) -> int:
+    """The width to declare a cache leaf's last axis at, so that
+    row-major is the TPU's default layout of the leaf.
+
+    A TPU gives an array whose last axis is wider than its 128 lanes
+    and no multiple of them another default layout than row-major (a
+    ``bf16[64, 6144, 576]`` latent leaf: positions last), and since the
+    order is declared and not read from the compiler (above), every
+    program handed the pool then copies each leaf whole on the way in
+    and on the way out: 12 whole-leaf copies in a six-layer step at 576
+    wide, none at 640 (compiled for a described v5e;
+    ``tests/test_layouts.py`` holds both).  The owner of the leaf fills
+    the extra columns with zeros."""
+    return width if width <= LANES else -(-width // LANES) * LANES
+
+
 def describe(tree) -> str:
     """One line for a log or a trace: each distinct (dtype, shape,
     layout) among the non-scalar arrays of ``tree``."""

@@ -1,4 +1,5 @@
-"""Autoregressive generation for ``TransformerLM`` — the LM family's
+"""Autoregressive generation for the LM families (``TransformerLM``,
+``LatentMoELM``: any model that implements ``DECODE_CONTRACT``) — their
 serving path.
 
 The reference predates autoregressive serving entirely (SURVEY.md §0:
@@ -27,42 +28,41 @@ import jax.numpy as jnp
 from jax import lax
 
 from distkeras_tpu.models.core import ModelSpec
-from distkeras_tpu.models.transformer import TransformerLM
 
 
-def _decode_model(model) -> TransformerLM:
+#: What ``generate()`` and ``serving.DecodeEngine`` ask of a model (the
+#: decode contract).  ``TransformerLM`` and ``latent_moe.LatentMoELM``
+#: implement it:
+#:
+#: * ``decode_clone()``: the model in decode mode (same parameters; a
+#:   ``"cache"`` collection whose leaves are ``[B, L, ...]`` plus scalar
+#:   indices), or an error that says why this model cannot be served;
+#: * ``dense_prefill_clone()`` of that: a clone whose multi-token chunks
+#:   read the cache, exact at any offset (chunked prefill, verify);
+#: * fields ``max_len``, ``vocab_size`` and ``cache_envelope`` (settable
+#:   through ``clone``: the cache's length ``L``);
+#: * ``apply(variables, tokens, mutable=["cache"], slot_pos=, last_index=,
+#:   logits_all=)`` returning next-token logits ``[B, 1, V]`` (every
+#:   position's with ``logits_all``);
+#: * optionally an ``"expert_load"`` collection: one ``[E]`` count of
+#:   routed tokens an expert layer, read where it is made mutable.
+DECODE_CONTRACT = ("decode_clone", "dense_prefill_clone", "max_len",
+                   "vocab_size", "cache_envelope")
+
+
+def _decode_model(model):
     if isinstance(model, Mapping):
         model = ModelSpec.from_config(model).build()
     elif isinstance(model, ModelSpec):
         model = model.build()
-    if not isinstance(model, TransformerLM):
+    missing = [a for a in DECODE_CONTRACT if not hasattr(model, a)]
+    if missing:
         raise TypeError(
-            "generate() serves TransformerLM models; got "
-            f"{type(model).__name__}")
-    if model.scan_blocks:
-        raise ValueError(
-            "generate() cannot serve scan_blocks=True models: the "
-            "stacked param layout differs from the per-layer one the "
-            "decode path walks.  Un-stack the params (or train "
-            "without scan_blocks) to serve this model.")
-    if model.num_experts > 0:
-        raise ValueError(
-            "generate() cannot serve MoE models yet: capacity-"
-            "bucketed routing over a T=1 decode step diverges from "
-            "the full-forward routing (different tokens overflow and "
-            "drop), so cached decode would silently differ from what "
-            "the trained model predicts.  Serve via the dense "
-            "full-forward path (predictors) instead.")
-    # The attention spellings (attn="auto"/flash_attn/blockwise_attn)
-    # are KEPT: decode mode uses them as the prefill kernel, so a long
-    # prompt runs the same flash/blockwise path training uses instead
-    # of a dense O(T·max_len) read of the cache; each generated token
-    # is a cached T=1 step either way.  Custom attn_fn and ring
-    # (seq_axis) are cleared — their contracts are training-path
-    # shapes.  remat_blocks off: decode never runs a backward pass,
-    # so rematerializing every step is pure overhead (ADVICE r4).
-    return model.clone(decode=True, attn_fn=None, seq_axis=None,
-                       remat_blocks=False)
+            "generate() serves models that implement the decode "
+            "contract (TransformerLM, LatentMoELM: "
+            "models.generate.DECODE_CONTRACT); "
+            f"{type(model).__name__} lacks {missing}")
+    return model.decode_clone()
 
 
 @jax.named_scope("sample")
@@ -96,6 +96,14 @@ def _select(logits, temperature, top_k, top_p, rng):
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+def stacked_expert_load(state: Mapping):
+    """``[expert layers, E]`` int32 from the ``"expert_load"`` collection
+    a model's apply left in ``state``; ``None`` for a model that sows
+    none (every model without routed experts)."""
+    leaves = jax.tree_util.tree_leaves(state.get("expert_load", {}))
+    return jnp.stack(leaves) if leaves else None
+
+
 def decode_step(dec, params: Mapping, cache, tok, *, slot_pos=None,
                 temperature: float = 0.0, top_k: int | None = None,
                 top_p: float | None = None, rng=None):
@@ -113,14 +121,17 @@ def decode_step(dec, params: Mapping, cache, tok, *, slot_pos=None,
         (continuous batching); None = the scalar-index contract.
       rng: key for sampling (``temperature > 0``).
 
-    Returns ``(new_cache, next_tok)`` with ``next_tok`` ``[B]`` int32.
-    Jit-compatible; ``generate`` runs exactly this inside its scan.
+    Returns ``(new_cache, next_tok, load)`` with ``next_tok`` ``[B]``
+    int32 and ``load`` the step's ``stacked_expert_load`` (``None`` for
+    a model without routed experts).  Jit-compatible; ``generate`` runs
+    exactly this inside its scan.
     """
     logits, state = dec.apply({**params, "cache": cache}, tok[:, None],
-                              slot_pos=slot_pos, mutable=["cache"])
+                              slot_pos=slot_pos,
+                              mutable=["cache", "expert_load"])
     nxt = _select(logits[:, -1].astype(jnp.float32), temperature,
                   top_k, top_p, rng)
-    return state["cache"], nxt
+    return state["cache"], nxt, stacked_expert_load(state)
 
 
 def generate(model, variables: Mapping, prompt, *,
@@ -207,9 +218,9 @@ def generate(model, variables: Mapping, prompt, *,
     def step(carry, _):
         cache, tok, rng, done = carry
         rng, sub = jax.random.split(rng)
-        cache, nxt = decode_step(dec, params, cache, tok,
-                                 temperature=temperature, top_k=top_k,
-                                 top_p=top_p, rng=sub)
+        cache, nxt, _ = decode_step(dec, params, cache, tok,
+                                    temperature=temperature,
+                                    top_k=top_k, top_p=top_p, rng=sub)
         if eos_id is not None:
             nxt = jnp.where(done, pad_id, nxt)
             done = done | (nxt == eos_id)

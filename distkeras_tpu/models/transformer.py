@@ -507,6 +507,43 @@ class TransformerLM(nn.Module):
     #: the full table, so the params are unchanged).  Decode-only.
     cache_envelope: Optional[int] = None
 
+    def decode_clone(self) -> "TransformerLM":
+        """The model as ``generate()`` and ``DecodeEngine`` run it (the
+        decode contract, ``models.generate.DECODE_CONTRACT``)."""
+        if self.scan_blocks:
+            raise ValueError(
+                "generate() cannot serve scan_blocks=True models: the "
+                "stacked param layout differs from the per-layer one the "
+                "decode path walks.  Un-stack the params (or train "
+                "without scan_blocks) to serve this model.")
+        if self.num_experts > 0:
+            raise ValueError(
+                "generate() cannot serve this model's capacity-bucketed "
+                "MoEFFN: over a T=1 decode step its routing diverges "
+                "from the full-forward routing (different tokens "
+                "overflow and drop), so cached decode would silently "
+                "differ from what the trained model predicts.  Serve "
+                "it via the dense full-forward path (predictors); the "
+                "experts that ARE served are the dropless ones of "
+                "models.latent_moe.LatentMoELM.")
+        # The attention spellings (attn="auto"/flash_attn/blockwise_attn)
+        # are KEPT: decode mode uses them as the prefill kernel, so a long
+        # prompt runs the same flash/blockwise path training uses instead
+        # of a dense O(T·max_len) read of the cache; each generated token
+        # is a cached T=1 step either way.  Custom attn_fn and ring
+        # (seq_axis) are cleared — their contracts are training-path
+        # shapes.  remat_blocks off: decode never runs a backward pass,
+        # so rematerializing every step is pure overhead (ADVICE r4).
+        return self.clone(decode=True, attn_fn=None, seq_axis=None,
+                          remat_blocks=False)
+
+    def dense_prefill_clone(self) -> "TransformerLM":
+        """A clone whose multi-token chunks read the cache densely:
+        exact at any offset, where the blocked prefill kernels are
+        exact only from an empty cache."""
+        return self.clone(attn="dense", attn_fn=None, flash_attn=False,
+                          blockwise_attn=False)
+
     def _local_attn_fn(self, t: int,
                        platform: Optional[str] = None) -> Optional[AttnFn]:
         """Resolve the device-local attention spelling for sequence
@@ -581,12 +618,13 @@ class TransformerLM(nn.Module):
                     "tokens are cached T=1 steps)")
             if self.num_experts > 0:
                 raise ValueError(
-                    "decode=True cannot serve MoE models: capacity-"
-                    "bucketed routing over a short decode step "
-                    "diverges from the full-forward routing the model "
-                    "trained with (different tokens overflow and "
-                    "drop) — serve MoE via the dense full-forward "
-                    "path (predictors) instead")
+                    "decode=True cannot serve this model's capacity-"
+                    "bucketed MoEFFN: its routing over a short decode "
+                    "step diverges from the full-forward routing the "
+                    "model trained with (different tokens overflow and "
+                    "drop) — serve it via the dense full-forward path "
+                    "(predictors); dropless experts are served by "
+                    "models.latent_moe.LatentMoELM")
             cache_len = self.cache_envelope or self.max_len
             if not 0 < cache_len <= self.max_len:
                 raise ValueError(

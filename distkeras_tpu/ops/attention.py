@@ -256,19 +256,20 @@ def _rowblk(bq):
 
 def _fwd_call(q, k, v, scale, causal, bq, bk, interpret):
     b, h, t, d = q.shape
+    dv = v.shape[-1]    # values (and the output) may be narrower than q/k
     n_q, n_k = t // bq, t // bk
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                n_k=n_k)
     return pl.pallas_call(
         kernel,
         grid=(b, h, n_q, n_k),
-        in_specs=[_qblk(bq, d), _kblk(bk, d), _kblk(bk, d)],
-        out_specs=[_qblk(bq, d), _rowblk(bq)],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+        in_specs=[_qblk(bq, d), _kblk(bk, d), _kblk(bk, dv)],
+        out_specs=[_qblk(bq, dv), _rowblk(bq)],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
+                        pltpu.VMEM((bq, dv), jnp.float32)],
         compiler_params=None if interpret else _PARAMS,
         interpret=interpret,
         name="flash_fwd",
@@ -347,6 +348,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: int | None = None,
                     interpret: bool | None = None) -> jax.Array:
     """Pallas-kernel attention: ``[B, T, H, D] -> [B, T, H, D]``.
+    ``v`` may have a head size of its own (``[B, T, H, Dv]``, as the
+    expanded form of latent attention has: 192 for q/k, 128 for v); the
+    result is then ``[B, T, H, Dv]`` and comes from the forward kernel
+    alone, which computes nothing it does not need: that spelling
+    serves (prefill) and has no backward pass.
 
     Same contract as ``models.transformer.dense_causal_attention`` and
     ``parallel.ring_attention.blockwise_attention``; differentiable via
@@ -369,8 +375,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # negligible (O(T)) next to attention's O(T^2), and it gives the
     # kernels their natural (rows = time, lanes = head_dim) layout.
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    out = _flash_bhtd(qt, kt, vt, float(scale), bool(causal), bq, bk,
-                      bool(interpret))
+    call = _flash_bhtd if v.shape[-1] == q.shape[-1] else \
+        (lambda *a: _fwd_call(*a)[0])
+    out = call(qt, kt, vt, float(scale), bool(causal), bq, bk,
+               bool(interpret))
     return jnp.swapaxes(out, 1, 2)
 
 

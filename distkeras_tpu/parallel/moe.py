@@ -191,3 +191,129 @@ def moe_apply(params: MoEParams, x: jax.Array, *, axis_name: str,
     return out, MoEAux(
         lax.pmean(aux.load_balance_loss, axis_name),
         lax.pmean(aux.dropped_fraction, axis_name))
+
+
+# ---------------------------------------------------------------------
+# Dropless routing: no capacity, no dropped token.  The (token, expert)
+# pairs are sorted by expert and each projection is ONE grouped matrix
+# product over the experts held (``grouped_matmul``).  The same function
+# serves a prefill of thousands of tokens and a decode step of a few
+# dozen.  An expert-parallel deployment gives each chip a contiguous
+# range of the experts: the layer routes over ALL of them and computes
+# the part of the result that the experts it holds give; what the
+# absent experts would add is left out (their exchange is not here).
+# ---------------------------------------------------------------------
+
+_ROW_TILE = 128               # the MXU's rows: the least a group costs
+_WEIGHT_TILE_BYTES = 4 << 20  # one [tk, tn] tile of an expert, twice in VMEM
+
+
+def _gmm_tiling(k: int, n: int, itemsize: int):
+    """``(tm, tk, tn)`` for the grouped-matmul kernel, or None where the
+    shapes do not tile (toy widths).  Rows in tiles of 128, so that a
+    group of a few rows (a decode step gives an expert four) costs one
+    small tile; the whole contraction in one tile (no accumulation
+    loop) and as many columns as fit beside it.  On a v5e, against
+    XLA's own lowering of ``ragged_dot`` (256- and 512-row tiles) at
+    64 experts of 3584 x 2048 and 1024 x 3584: 1.30 against 2.16 ms and
+    0.68 against 1.10 ms for a step's 256 rows (724 and 693 GB/s of the
+    chip's 819), 2.13 against 3.97 ms and 1.25 against 2.16 ms for a
+    2048-token prefill's 8192 rows, the same numbers to the last bit
+    (PERF.md section 6, PR 28)."""
+    if k % 128 or n % 128 or k * 128 * itemsize > _WEIGHT_TILE_BYTES:
+        return None
+    tn = max(t for t in range(128, n + 1, 128)
+             if n % t == 0 and k * t * itemsize <= _WEIGHT_TILE_BYTES)
+    return _ROW_TILE, k, tn
+
+
+def _gmm(rows, weights, sizes, out_dtype, tiling, interpret=False):
+    """The megablox grouped-matmul Mosaic kernel of
+    ``jax.experimental.pallas`` over row tiles (``interpret``: for a
+    test off the TPU)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m = rows.shape[0]
+    pad = -m % _ROW_TILE
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    out = gmm(rows, weights, sizes, preferred_element_type=out_dtype,
+              tiling=tiling, interpret=interpret)
+    return out[:m] if pad else out
+
+
+def grouped_matmul(rows, weights, sizes, out_dtype):
+    """``rows[start_g : start_g + sizes[g]] @ weights[g]`` for every group
+    ``g``: ``rows`` ``[m, k]`` sorted by group, ``weights`` ``[G, k, n]``,
+    ``sizes`` ``[G]`` int32; ``[m, n]`` in ``out_dtype``.  Rows past the
+    last group hold nothing to read.
+
+    On a TPU this is the megablox kernel with the tiling above;
+    elsewhere, and at widths that do not tile, ``jax.lax.ragged_dot``
+    (which XLA lowers to a kernel of its own on a TPU, with row tiles
+    that a decode step's few rows a group fill badly)."""
+    tiling = _gmm_tiling(rows.shape[1], weights.shape[2],
+                         rows.dtype.itemsize)
+    if tiling is None or jax.devices()[0].platform != "tpu":
+        return lax.ragged_dot(rows, weights, sizes,
+                              preferred_element_type=out_dtype)
+    return _gmm(rows, weights, sizes, out_dtype, tiling)
+
+
+def sigmoid_topk(x, router, bias, top_k: int, *, normalize: bool = True,
+                 scale: float = 1.0):
+    """Bias-corrected sigmoid routing ("noaux_tc", one group).
+
+    ``x`` ``[T, d]``, ``router`` ``[d, E]`` and ``bias`` ``[E]`` are
+    taken in float32 and the product runs at full float32 precision,
+    so that a rounding of the activations cannot flip a choice.  The
+    ``top_k`` experts are chosen by ``sigmoid(x W) + bias``; a chosen
+    expert's weight is its score WITHOUT the bias, divided by the sum
+    of the chosen scores (``normalize``) and multiplied by ``scale``.
+    Returns ``(idx [T, k] int32, weights [T, k] float32)``."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+def expert_load(idx, num_experts: int):
+    """Tokens routed to each expert: ``[E]`` int32 from ``idx [T, k]``."""
+    return jnp.zeros((num_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
+
+
+def dropless_experts(x, idx, weights, w_in, w_out, *, first: int = 0):
+    """The held experts' part of a routed SwiGLU layer, every
+    assignment computed.
+
+    ``x`` ``[T, d]``; ``idx``/``weights`` ``[T, k]`` from the router
+    (over all experts); ``w_in`` ``[E_held, d, 2 h]`` (gate then up) and
+    ``w_out`` ``[E_held, h, d]`` are experts ``first .. first + E_held``.
+    Returns ``[T, d]`` in ``x.dtype``: ``sum_i w_i SwiGLU_i(x)`` over a
+    token's chosen experts that are held here."""
+    t, k = idx.shape
+    held = w_in.shape[0]
+    h = w_out.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        local = idx.reshape(-1) - first
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held)          # absent ones last
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        rows = jnp.take(x, order // k, axis=0)      # [T k, d]
+    with jax.named_scope("moe_experts"):
+        gu = grouped_matmul(rows, w_in, sizes, x.dtype)
+        act = (jax.nn.silu(gu[:, :h]) * gu[:, h:]).astype(x.dtype)
+        y = grouped_matmul(act, w_out, sizes, jnp.float32)
+    with jax.named_scope("moe_combine"):
+        # rows past the last group belong to no expert held here: what
+        # the grouped product leaves there is not read
+        w = weights.reshape(-1)[order]
+        y = jnp.where(here[order][:, None], y * w[:, None], 0.0)
+        back = jnp.argsort(order)
+        y = jnp.take(y, back, axis=0).reshape(t, k, -1).sum(axis=1)
+    return y.astype(x.dtype)

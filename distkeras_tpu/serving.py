@@ -129,7 +129,8 @@ from distkeras_tpu import flight_recorder, layouts, paging, telemetry
 from distkeras_tpu import speculative as _speculative
 from distkeras_tpu.analysis import racecheck
 from distkeras_tpu.models.generate import (_decode_model, _select,
-                                           decode_step)
+                                           decode_step,
+                                           stacked_expert_load)
 from distkeras_tpu.parallel import transport
 
 _UNSET = object()
@@ -433,10 +434,11 @@ class _Pool:
 
 
 class DecodeEngine:
-    """Slot-based continuous-batching server for ``TransformerLM``.
+    """Slot-based continuous-batching server for any model that
+    implements the decode contract (``models.generate.DECODE_CONTRACT``).
 
     Args:
-      model: a ``TransformerLM``, its ``ModelSpec``, or a config dict
+      model: such a model, its ``ModelSpec``, or a config dict
         (same contract as ``generate``; GQA / int8-cache / attention
         spellings compose — the prefill runs the model's resolved
         kernel, steps run the cached dense row).
@@ -723,6 +725,7 @@ class DecodeEngine:
         self._lock = racecheck.rlock("serving.engine")
         self._closed = False  # guarded-by: _lock
         self._traces: collections.Counter = collections.Counter()
+        self._expert_load = None  # [expert layers, E], once seen
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self._donate = bool(donate)
@@ -807,7 +810,7 @@ class DecodeEngine:
                 # done slots re-write their last row (dead data, kept
                 # in range so live rows never see the NaN poison)
                 step_pos = jnp.minimum(st["pos"], env - 1)
-                cache, nxt = decode_step(
+                cache, nxt, load = decode_step(
                     dec, params, cache, st["tok"], slot_pos=step_pos,
                     temperature=temp, top_k=top_k, top_p=top_p,
                     rng=sub)
@@ -820,14 +823,19 @@ class DecodeEngine:
                       "n_left": n_left,
                       "eos": st["eos"],
                       "done": fin | eos_hit | (n_left <= 0)}
-                return (cache, st), (nxt, fin)
+                return (cache, st), (nxt, fin, load)
 
-            (cache, state), (toks, was_done) = jax.lax.scan(
+            (cache, state), (toks, was_done, load) = jax.lax.scan(
                 body, (cache, state), jax.random.split(rng, n_sub))
             # toks[k, s] is real iff the slot was live ENTERING sub-
             # step k (was_done[k, s] False); the host replays exactly
-            # this predicate.
-            return cache, state, toks, was_done
+            # this predicate.  load: the rows routed to each expert,
+            # [expert layers, E] over the sub-steps and over every row
+            # the program computed, done slots' too (None for a model
+            # without routed experts)
+            if load is not None:
+                load = load.sum(axis=0)
+            return cache, state, toks, was_done, load
 
         if not self._paged:
             def step_impl(variables, cache, state, rng):
@@ -857,10 +865,10 @@ class DecodeEngine:
             telemetry.metrics().counter(
                 "compiles_total", kind="paged_step", bucket=env).inc()
             cache = paging.gather_cache(tmpl, pages, table)
-            cache, state, toks, was_done = step_core(
+            cache, state, toks, was_done, load = step_core(
                 variables, cache, state, rng)
             return (paging.scatter_cache(pages, cache, table), state,
-                    toks, was_done)
+                    toks, was_done, load)
 
         paged_step_impl.__name__ = f"paged_step_impl_{env}"
         donate = (1, 3) if self._donate else ()
@@ -873,7 +881,8 @@ class DecodeEngine:
         def prefill_core(variables, cache, state, prompt, slot,
                          last_idx, n_left0, eos_id, rng):
             params = {"params": variables["params"]}
-            logits, st = dec.apply(params, prompt, mutable=["cache"],
+            logits, st = dec.apply(params, prompt,
+                                   mutable=["cache", "expert_load"],
                                    last_index=last_idx)
             tok0 = _select(logits[:, -1].astype(jnp.float32), temp,
                            top_k, top_p, rng)[0]
@@ -898,7 +907,8 @@ class DecodeEngine:
                 "eos": state["eos"].at[slot].set(eos_id),
                 "done": state["done"].at[slot].set(done0),
             }
-            return cache, state, tok0
+            # padded rows are routed and computed too, and counted
+            return cache, state, tok0, stacked_expert_load(st)
 
         if not self._paged:
             def prefill_impl(variables, cache, state, prompt, slot,
@@ -925,11 +935,11 @@ class DecodeEngine:
                 "compiles_total", kind="paged_prefill", bucket=env,
                 padded=prompt.shape[1]).inc()
             cache = paging.gather_cache(tmpl, pages, table)
-            cache, state, tok0 = prefill_core(
+            cache, state, tok0, load = prefill_core(
                 variables, cache, state, prompt, slot, last_idx,
                 n_left0, eos_id, rng)
             return (paging.scatter_cache(pages, cache, table), state,
-                    tok0)
+                    tok0, load)
 
         donate = (1, 3) if self._donate else ()
         return jax.jit(paged_prefill_impl, donate_argnums=donate)
@@ -948,8 +958,7 @@ class DecodeEngine:
         interleaved decode steps may rewrite harmlessly (a slot reads
         that row only after overwriting it itself)."""
         env = pool.env
-        dense = pool.dec.clone(attn="dense", attn_fn=None,
-                               flash_attn=False, blockwise_attn=False)
+        dense = pool.dec.dense_prefill_clone()
         temp, top_k, top_p = self.temperature, self.top_k, self.top_p
         pad_id = self.pad_id
 
@@ -1050,8 +1059,7 @@ class DecodeEngine:
         widths exist per bucket (``k + 1`` and the single-token
         fallback), so the compiled program set stays bounded."""
         env = pool.env
-        dense = pool.dec.clone(attn="dense", attn_fn=None,
-                               flash_attn=False, blockwise_attn=False)
+        dense = pool.dec.dense_prefill_clone()
 
         def verify_core(variables, cache, chunk, slot, start):
             params = {"params": variables["params"]}
@@ -1205,7 +1213,8 @@ class DecodeEngine:
                     out.append(leaf)
                     continue
                 out.append(jax.lax.dynamic_update_slice(
-                    leaf, next(segs), (slot, start, 0, 0)))
+                    leaf, next(segs),
+                    (slot, start) + (0,) * (leaf.ndim - 2)))
             return jax.tree_util.tree_unflatten(treedef, out)
 
         donate = (0,) if self._donate else ()
@@ -1227,7 +1236,7 @@ class DecodeEngine:
                 if jnp.ndim(leaf) == 0:
                     continue
                 out.append(jax.lax.dynamic_slice(
-                    leaf, (slot, start, 0, 0),
+                    leaf, (slot, start) + (0,) * (leaf.ndim - 2),
                     (1, align) + leaf.shape[2:]))
             return out
 
@@ -1859,27 +1868,29 @@ class DecodeEngine:
             with telemetry.span("prefill", bucket=pool.env,
                                 slot=slot, padded=t_pad,
                                 prompt_tokens=t_p,
-                                request_id=req.rid):
+                                request_id=req.rid) as sp:
                 with telemetry.span("prefill_dispatch"):
                     if self._paged:
                         self._set_table_row(pool, slot, req.pages)
-                        (self._pages, pool.state,
-                         tok0) = pool.prefill_fn(
+                        (self._pages, pool.state, tok0,
+                         load) = pool.prefill_fn(
                             variables, self._pages, pool.table,
                             pool.state, jnp.asarray(padded), slot,
                             t_p - 1, n_left0,
                             -1 if req.eos_id is None else req.eos_id,
                             self._next_rng())
                     else:
-                        (pool.cache, pool.state,
-                         tok0) = pool.prefill_fn(
+                        (pool.cache, pool.state, tok0,
+                         load) = pool.prefill_fn(
                             variables, pool.cache, pool.state,
                             jnp.asarray(padded), slot, t_p - 1,
                             n_left0,
                             -1 if req.eos_id is None else req.eos_id,
                             self._next_rng())
                 with telemetry.span("first_token_sync"):
+                    tok0, load = jax.device_get((tok0, load))
                     tok0 = int(tok0)
+                self._note_expert_load(sp, load)
                 req.tokens.append(tok0)
                 req.t_tokens.append(telemetry.now())
         except Exception as e:
@@ -2601,21 +2612,25 @@ class DecodeEngine:
                 with telemetry.span(
                         "decode_step", bucket=pool.env,
                         steps=self.steps_per_sync,
-                        live=sum(r is not None for r in pool.reqs)):
+                        live=sum(r is not None for r in pool.reqs)
+                        ) as sp:
                     with telemetry.span("decode_dispatch"):
                         if self._paged:
                             (self._pages, pool.state, toks,
-                             was_done) = pool.step_fn(
+                             was_done, load) = pool.step_fn(
                                 variables, self._pages, pool.table,
                                 pool.state, self._next_rng())
                         else:
                             (pool.cache, pool.state, toks,
-                             was_done) = pool.step_fn(
+                             was_done, load) = pool.step_fn(
                                 variables, pool.cache, pool.state,
                                 self._next_rng())
                     with telemetry.span("decode_fetch"):
-                        toks = np.asarray(toks)
-                        was_done = np.asarray(was_done)
+                        # one transfer: the tokens and, where the
+                        # model routes over experts, their load
+                        toks, was_done, load = jax.device_get(
+                            (toks, was_done, load))
+                    self._note_expert_load(sp, load)
                 # one stamp for every token of this fetch: where they
                 # reached the host
                 t_tok = telemetry.now()
@@ -2823,6 +2838,30 @@ class DecodeEngine:
             while next_emit < len(order):
                 yield buffered.pop(order[next_emit])
                 next_emit += 1
+
+    def _note_expert_load(self, span, load) -> None:
+        """Add what one program routed to the histogram, and put its
+        summary on the program's span (``experts_touched``: distinct
+        experts that got a row, summed over the expert layers;
+        ``expert_tokens_max``: the busiest expert's rows)."""
+        if load is None:
+            return
+        if self._expert_load is None:
+            self._expert_load = np.zeros(load.shape, np.int64)
+        self._expert_load += load
+        span.set_metadata(experts_touched=int(np.count_nonzero(load)),
+                          expert_tokens_max=int(load.max()))
+
+    def expert_load(self) -> Optional[np.ndarray]:
+        """``[expert layers, experts]``: the rows that the one-shot
+        prefills and the decode steps have routed to each expert since
+        the engine was built (every row a program computed: a prompt's
+        padding and a finished slot's dead row are routed like any
+        other).  ``None`` for a model without routed experts, and until
+        the first program has run.  Chunked prefills and speculative
+        verifies are not counted."""
+        return None if self._expert_load is None \
+            else self._expert_load.copy()
 
     @property
     def compile_counts(self) -> dict:

@@ -168,9 +168,9 @@ def make_draft_propose(dec, env: int, k: int, pad_id: int,
             cache, tok, pos = carry
             step_pos = jnp.where(live, jnp.minimum(pos, env - 1),
                                  env - 1)
-            cache, nxt = decode_step(dec, params, cache, tok,
-                                     slot_pos=step_pos,
-                                     temperature=0.0)
+            cache, nxt, _ = decode_step(dec, params, cache, tok,
+                                        slot_pos=step_pos,
+                                        temperature=0.0)
             nxt = jnp.where(live, nxt, pad_id)
             return (cache, nxt, pos + 1), nxt
 

@@ -647,6 +647,14 @@ class _Span:
         self._t0 = now()
         return self
 
+    def set_metadata(self, **args) -> None:
+        """Args known only once the span's work is done (what a fetch
+        brought back): added to the ring's event and, as
+        ``TraceAnnotation.set_metadata`` does on the bare annotation of
+        the disabled path, to the profiler's."""
+        self.args = {**self.args, **args}
+        self._annotation.set_metadata(**args)
+
     def __exit__(self, exc_type, exc, tb):
         t1 = now()
         self._annotation.__exit__(exc_type, exc, tb)

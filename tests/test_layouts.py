@@ -430,3 +430,119 @@ def test_v5e_heads_outside_position_is_relaid_out_every_step(one_chip):
     text = jax.jit(step, donate_argnums=(0, 1)).lower(
         pool, pool, row, row, row, pos).compile().as_text()
     assert layouts.whole_leaf_copies(text, [pool]) == 4
+
+
+# ---- the latent cache, compiled for the described v5e -------------------
+
+@pytest.fixture(scope="module")
+def v5e_latent_pool(one_chip):
+    """One dense and one expert layer at the published widths of the
+    benchmark's latent-attention configuration (d 3584, 32 heads, latent
+    512 + 64, 64 experts of 1024), a pool of 16 slots x 1024 tokens, as
+    shapes on the described chip: ``(dec, params, cache, on_chip)``."""
+    cfg = model_config(
+        "latent_moe_lm", (1024,), input_dtype="int32", vocab_size=8192,
+        num_layers=2, d_model=3584, num_heads=32, q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, dense_width=9216, first_dense_layers=1,
+        num_experts=64, experts_per_token=4, expert_width=1024,
+        routed_scaling=2.0, rope_factor=64.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0, max_len=1024, dtype="bfloat16")
+    dec = _decode_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: SDS(x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = {"params": jax.eval_shape(
+        lambda: dec.clone(decode=False).init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))["params"]}
+    cache = jax.eval_shape(
+        lambda v: dec.apply(v, jnp.zeros((16, 1), jnp.int32),
+                            mutable=["cache"]), params)[1]["cache"]
+    return dec, on_chip(params), on_chip(cache), on_chip
+
+
+@pytest.fixture()
+def traced_for_the_chip(monkeypatch):
+    """The code that asks ``jax.devices()[0]`` where it runs (the flash
+    kernels' ``interpret``, the grouped product's kernel) sees the CPU
+    here; while a test traces a program for the described chip it is told
+    the chip."""
+    import types
+
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+
+
+def test_v5e_latent_step_copies_no_leaf(v5e_latent_pool,
+                                        traced_for_the_chip):
+    """The latent leaf is 512 + 64 wide and padded to 640: row-major is
+    then the TPU's default layout of it, and the absorbed step (a per-row
+    scatter, two contractions over the leaf as it lies) copies none."""
+    dec, params, cache, on_chip = v5e_latent_pool
+
+    def step(params, cache, tok, pos):
+        return decode_step(dec, params, cache, tok, slot_pos=pos,
+                           temperature=0.0, top_k=None, top_p=None,
+                           rng=None)
+
+    tok = on_chip(SDS((16,), jnp.int32))
+    text = jax.jit(step, donate_argnums=1).lower(
+        params, cache, tok, tok).compile().as_text()
+    assert _kv_leaves(cache)[0].shape == (16, 1024, 640)
+    assert layouts.whole_leaf_copies(text, cache) == 0
+    # the grouped products are the megablox kernel, under its scope
+    assert text.count("moe_experts/jit(gmm)/pallas_call") >= 2
+    assert "ragged-dot" not in text
+
+
+def test_v5e_latent_prefill_runs_the_flash_kernel_at_192_and_128(
+        v5e_latent_pool, traced_for_the_chip):
+    """The expanded prefill: q and k 192 wide, v 128, through the forward
+    kernel as it is (nothing padded), installed into one slot in place."""
+    dec, params, cache, on_chip = v5e_latent_pool
+
+    def prefill(params, cache, prompt, slot):
+        _, st = dec.apply(params, prompt, mutable=["cache"],
+                          last_index=3)
+        return jax.tree_util.tree_map(
+            lambda c, n: c if not n.shape else
+            jax.lax.dynamic_update_slice(
+                c, n, (slot,) + (0,) * (n.ndim - 1)),
+            cache, st["cache"])
+
+    text = jax.jit(prefill, donate_argnums=1).lower(
+        params, cache, on_chip(SDS((1, 512), jnp.int32)),
+        on_chip(SDS((), jnp.int32))).compile().as_text()
+    assert layouts.whole_leaf_copies(text, cache) == 0
+    assert "flash_fwd" in text
+
+
+def test_lane_padded_is_the_next_multiple_of_128_past_one_tile():
+    """What the latent leaf is declared at (576 -> 640); a leaf inside one
+    tile of lanes (the toys' 24) and a multiple of 128 stay as they are."""
+    assert [layouts.lane_padded(w) for w in (24, 128, 576, 640, 641)] == \
+        [24, 128, 640, 640, 768]
+
+
+def test_v5e_a_leaf_576_wide_is_relaid_out_every_step(one_chip):
+    """Why the pad: the same write and contractions on an unpadded
+    ``[B, L, 576]`` leaf.  576 is no multiple of the 128 lanes, the TPU's
+    default layout of the leaf puts positions last, and the step copies it
+    whole on the way in and on the way out."""
+    b, length, width, h = 16, 1024, 576, 32
+
+    def step(cache, q, new, pos):
+        cache = cache.at[jnp.arange(b), pos].set(new)
+        logits = jnp.einsum("bhc,blc->bhl", q, cache)
+        probs = jax.nn.softmax(logits.astype(jnp.float32),
+                               axis=-1).astype(q.dtype)
+        return cache, jnp.einsum("bhl,blc->bhc", probs, cache)
+
+    pool = SDS((b, length, width), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(step, donate_argnums=0).lower(
+        pool, SDS((b, h, width), jnp.bfloat16, sharding=one_chip),
+        SDS((b, width), jnp.bfloat16, sharding=one_chip),
+        SDS((b,), jnp.int32, sharding=one_chip)).compile().as_text()
+    assert layouts.whole_leaf_copies(text, [pool]) == 2
