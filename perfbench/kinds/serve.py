@@ -16,6 +16,7 @@ decodes in one step) share the earlier stamp, so their gap is 0.
 """
 
 import bisect
+import collections
 import contextlib
 import gc
 import resource
@@ -32,7 +33,7 @@ def warm_keys(requests, buckets, align) -> dict:
     two can tell apart, so every program of the window is warmed."""
     keys = {}
     for r in requests:
-        need = next(b for b in buckets if len(r.prompt) + r.budget <= b)
+        need = trafficgen.pool_class(buckets, len(r.prompt), r.budget)
         padded = -(-len(r.prompt) // align) * align
         keys.setdefault((padded, need), r)
     return keys
@@ -221,17 +222,32 @@ def sample_for_check(served, requests, buckets, seed, t_open, t_close,
         return []
     rng = np.random.default_rng([int(seed), 0xC4EC])
     done.sort(key=lambda r: r.index)
-    size = lambda r: len(r.prompt) + r.budget  # noqa: E731
-    picked = [max(done, key=size)]
+    picked = [max(done, key=lambda r: len(r.prompt) + r.budget)]
     for b in buckets:
         cls = [r for r in done if r not in picked and
-               next(x for x in buckets if size(r) <= x) == b]
+               trafficgen.pool_class(buckets, len(r.prompt), r.budget) == b]
         if cls:
             picked.append(cls[int(rng.integers(len(cls)))])
     rest = [r for r in done if r not in picked]
     while len(picked) < k and rest:
         picked.append(rest.pop(int(rng.integers(len(rest)))))
     return picked
+
+
+def backlog_left_share_min(served, requests, buckets, t_close) -> float:
+    """How much of the backlog the close of the window found untouched:
+    the least, over pool classes, of the share of the class's offered
+    budget tokens that belong to requests with no token delivered by
+    ``t_close``.  Near 0 the next faster program runs a queue dry and
+    idles a slot: ask for more ``requests`` before that."""
+    offered, left = collections.Counter(), collections.Counter()
+    for r in requests:
+        cls = trafficgen.pool_class(buckets, len(r.prompt), r.budget)
+        offered[cls] += r.budget
+        times = served.deliveries(r.index)      # ascending
+        if not times or times[0] > t_close:
+            left[cls] += r.budget
+    return min(left[cls] / offered[cls] for cls in offered)
 
 
 def served_gap(reference, cfg, seed, picked, served, precision="f32"):
@@ -332,6 +348,8 @@ def run(ctx) -> dict:
 
     window_reqs = [r for r in requests if r.phase == "window"]
     if traffic["arrival"] == "backlog":
+        numbers["backlog_left_share_min"] = backlog_left_share_min(
+            served, requests, sorted(buckets), t_close)
         attempted = [r for r in window_reqs
                      if any(t_open < x <= t_close
                             for x in served.deliveries(r.index))]

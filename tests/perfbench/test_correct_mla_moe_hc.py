@@ -81,6 +81,28 @@ def test_toy_moe_cell_is_correct(moe_run):
     assert rows["served_logit_worst_gap"]["tokens"] >= 200
 
 
+def test_a_backlog_run_says_how_much_of_the_backlog_is_left(moe_run):
+    ctx, out = moe_run
+    left = out["end_to_end"]["backlog_left_share_min"]
+    assert 0 < left < 1
+    # not a metric and not compared: it is in the line's ``window``
+    assert "backlog_left_share_min" not in ctx.limits
+
+
+def test_a_backlog_cut_too_short_idles_a_slot_and_is_not_correct(toy_tree):
+    """The toy with two requests more than it has slots: the queue is dry
+    at once, and the first request to finish leaves its slot idle."""
+    ctx = context(toy_tree, "tiny-moe-backlog", 2**31 + 30, 1.0)
+    slots = sum(ctx.traffic["engine"]["buckets"].values())
+    ctx.traffic = {**ctx.traffic, "requests": slots + 2}
+    out = serve.run(ctx)
+    rows = {r["name"]: r for r in out["checks"].rows}
+    assert rows["slots_idle_in_window"]["value"] > 0
+    assert not rows["slots_idle_in_window"]["ok"]
+    assert not out["checks"].correct
+    assert out["end_to_end"]["backlog_left_share_min"] == 0
+
+
 def test_moe_control_in_fp8_is_not_correct(moe_run):
     ctx, out = moe_run
     _, reference, _, _ = ctx.arch
