@@ -14,9 +14,7 @@ Two arms (``--mode``):
   ISSUE 16): one worker per visible device, async ``MeshRoundDriver``
   dispatch, optional on-chip comm compression
   (``--comm-dtype``/``--comm-codec``).  Metric
-  ``ps_round_images_per_sec_per_chip`` — the same unit the flagship
-  script reports, so its records and BENCH records compare under
-  ``perf_regress`` (which keys candidates by metric name).
+  ``ps_round_images_per_sec_per_chip``.
 * ``auto`` (default) — ``ps-mesh`` when more than one device is
   visible, else ``sync``; the bench path IS the mesh tier wherever a
   mesh exists.

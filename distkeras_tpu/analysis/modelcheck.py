@@ -59,8 +59,8 @@ shortest violating schedule.
 
 Telemetry: ``modelcheck_states_explored_total`` and
 ``modelcheck_violations_total{invariant=...}`` counters on the global
-registry (``scripts/check_protocol.py --metrics-out`` snapshots them
-for ``perf_regress --from-registry``).
+registry (``scripts/check_protocol.py --metrics-out`` snapshots
+them).
 """
 
 from __future__ import annotations

@@ -3,7 +3,7 @@ data plane (ROADMAP item 1 — "where do a round's milliseconds go?").
 
 Three small, dependency-light layers shared by ``MeshDataplane`` (the
 cost ledger), ``MeshRoundDriver`` (the sampled step-time decomposition),
-``bench.py`` and ``scripts/perf_attrib.py``:
+and ``bench.py``:
 
 * :func:`extract_cost` — read of ``Compiled.cost_analysis()`` /
   ``memory_analysis()`` for an AOT executable.  ``'flops'`` counts

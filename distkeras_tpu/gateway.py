@@ -1548,8 +1548,7 @@ class PrefillDecodeRouter:
     TOKEN latency degrades fleet-wide.  Here the flood queues at the
     prefill pool — ``max_inflight_handoffs`` bounds prefill+export
     work in flight, the back-pressure valve — while decode replicas
-    keep their step cadence (``scripts/perf_prefill_decode.py`` gates
-    decode-side p99 flood-flatness on exactly this).
+    keep their step cadence.
 
     Request lifecycle:
 

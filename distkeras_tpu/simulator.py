@@ -29,8 +29,9 @@ and a capacity model fitted from the telemetry it produces:
 * ``run_drill`` — the closed-loop acceptance scenario: the autoscaler
   must track ``required(rate_at(t))`` as the curve moves, with
   convergence seconds (``sim_drill_convergence_seconds_total``) and
-  the watchdog's ``slo_violation_seconds_total`` as the gated metrics
-  (see ``scripts/perf_capacity.py``).
+  the watchdog's ``slo_violation_seconds_total`` as its counters
+  (``tests/test_simulator.py::test_full_stack_drill_ledger`` runs it
+  over real engines).
 
 The replay loop is deliberately single-threaded — submissions, result
 polling, chaos kills, and autoscaler ticks interleave in ONE pacing
@@ -679,8 +680,7 @@ def run_drill(trace: Trace, gateway, autoscaler, model: CapacityModel,
     accrues to ``sim_drill_convergence_seconds_total`` (one
     ``drill_converged`` flight event each).  SLO-violation seconds
     accrue on the watchdog's ``slo_violation_seconds_total`` as its
-    evaluations tick.  Both are per-second-gateable via
-    ``perf_regress.from_registry``.
+    evaluations tick.
 
     Returns ``{"replay", "episodes", "converged", "samples"}`` —
     ``converged`` is True when every deficit episode closed before the

@@ -15,15 +15,15 @@ test_examples.py's scripts-coverage check; tune them with the flags):
    ``error`` result, and ``drain()`` returns every accepted request.
 3. **Replicated-PS primary kill** (ISSUE 10) — a 2-node replica group
    loses its primary mid-training: the standby self-promotes (epoch
-   2), the workers fail over, commits lost must be ZERO, and the
-   kill -> promote latency plus the run's commit throughput are gated
-   through ``perf_regress`` (the latency lower-is-better).
+   2), the workers fail over, and commits lost must be ZERO; the
+   kill -> promote latency and the run's commit rate are printed as
+   what they are, CPU readings.
 4. **Elastic reshard + receiver kill mid-move** (ISSUE 14) — an
    elastic PS group splits and live-migrates shards under a
    ``ps_elastic`` training run, then the RECEIVING server of a second
    migration is killed mid-stream: the cutover aborts cleanly, the
-   old owner un-fences, commits lost must be ZERO, and the successful
-   migration's latency is ``perf_regress``-gated.
+   old owner un-fences, and commits lost must be ZERO; the successful
+   migration's latency and the commit rate are printed, CPU readings.
 
 The report prints, per layer: injected fault counts, client retries and
 backoff spent, commit/dedupe/snapshot counters, shed/error counts,
@@ -40,10 +40,6 @@ import sys
 REPO = pathlib.Path(__file__).resolve().parent.parent
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
-if str(REPO / "scripts") not in sys.path:
-    sys.path.insert(0, str(REPO / "scripts"))
-
-import perf_regress  # noqa: E402  (sibling script, path set above)
 
 
 def chaos_training_round(seed: int, rows: int) -> dict:
@@ -90,8 +86,8 @@ def failover_round(rows: int, out_dir: str) -> dict:
     the replicated dedupe table keeps retried commits exactly-once
     across the failover).  Promotion latency is measured from the
     fsynced ``ps_kill`` flight event to the successor's ``ps_promote``
-    and gated through ``perf_regress``."""
-    import json
+    and reported beside the run's commit rate: CPU readings, gated by
+    nothing."""
     import threading
     import time
 
@@ -99,7 +95,7 @@ def failover_round(rows: int, out_dir: str) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from distkeras_tpu import flight_recorder, telemetry
+    from distkeras_tpu import flight_recorder
     from distkeras_tpu.data import datasets
     from distkeras_tpu.models import ModelSpec, model_config
     from distkeras_tpu.parallel.replicated_ps import make_replica_group
@@ -160,35 +156,12 @@ def failover_round(rows: int, out_dir: str) -> dict:
         t.history.get("ps_epoch"), epoch)
     assert t.history["ps_failovers"][-1] >= 1, t.history
 
-    # ---- the perf_regress hookup: gate the recovery cost both ways —
-    # commit throughput (from the live registry) must not collapse,
-    # kill -> promote latency must not balloon (lower is better)
-    snap_path = out / "registry.json"
-    snap_path.write_text(json.dumps(telemetry.metrics().snapshot(),
-                                    default=repr))
-    cands = perf_regress.from_registry(
-        str(snap_path), "failover_commits_per_sec",
-        "ps_commits_total", seconds)
-    latency_cand = [{"metric": "failover_promotion_latency_s",
-                     "value": latency, "unit": "s"}]
-    for i, c in enumerate(cands + latency_cand):
-        for n in (1, 2, 3):  # synthetic trajectory from this very run
-            (out / f"BENCH_fo{i}_r{n:02d}.json").write_text(
-                json.dumps({
-                    "n": n, "cmd": "smoke", "rc": 0, "tail": "",
-                    "parsed": {"metric": c["metric"],
-                               "value": c["value"] * (1 + 0.02 * n),
-                               "unit": c.get("unit", "per_sec")}}))
-    traj = perf_regress.load_trajectories(str(out / "BENCH_fo*.json"))
-    gate = (perf_regress.evaluate(cands, traj, tolerance=0.5)
-            + perf_regress.evaluate(latency_cand, traj, tolerance=0.5,
-                                    lower_is_better=True))
-    assert all(r["status"] == "pass" for r in gate), gate
     return {"rounds": rounds, "commits": commits, "epoch": epoch,
             "failovers": int(t.history["ps_failovers"][-1]),
             "worker_retries": sum(map(len, t.history.get(
                 "worker_round_retries", []))),
-            "promotion_latency_s": latency, "gate": gate}
+            "promotion_latency_s": latency,
+            "commits_per_s": commits / seconds}
 
 
 def elastic_migration_round(rows: int, out_dir: str) -> dict:
@@ -199,10 +172,9 @@ def elastic_migration_round(rows: int, out_dir: str) -> dict:
     ``shard_migrate_cutover`` flight event), then (c) starts a second
     migration and KILLS the receiving server mid-stream: the cutover
     must abort cleanly (``MigrationAborted``), the old owner must
-    un-fence, and the run must finish with ZERO lost commits.  Commit
-    throughput is gated via ``perf_regress.from_registry`` and the
-    successful migration's latency as a lower-is-better candidate."""
-    import json
+    un-fence, and the run must finish with ZERO lost commits.  The
+    commit rate and the successful migration's latency are reported,
+    CPU readings."""
     import threading
     import time
 
@@ -210,7 +182,7 @@ def elastic_migration_round(rows: int, out_dir: str) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from distkeras_tpu import flight_recorder, telemetry
+    from distkeras_tpu import flight_recorder
     from distkeras_tpu.data import datasets
     from distkeras_tpu.models import ModelSpec, model_config
     from distkeras_tpu.parallel.elastic_ps import (ElasticPSGroup,
@@ -296,32 +268,10 @@ def elastic_migration_round(rows: int, out_dir: str) -> dict:
         f"{len(cutovers)} cutovers, {len(aborts)} aborts")
     latency = float(cutovers[0]["latency_s"])
 
-    # ---- perf_regress hookup: shard-commit throughput from the live
-    # registry, migration latency lower-is-better
-    snap_path = out / "registry.json"
-    snap_path.write_text(json.dumps(telemetry.metrics().snapshot(),
-                                    default=repr))
-    cands = perf_regress.from_registry(
-        str(snap_path), "elastic_commits_per_sec",
-        "ps_shard_commits_total", seconds)
-    latency_cand = [{"metric": "elastic_migration_latency_s",
-                     "value": latency, "unit": "s"}]
-    for i, c in enumerate(cands + latency_cand):
-        for n in (1, 2, 3):  # synthetic trajectory from this very run
-            (out / f"BENCH_el{i}_r{n:02d}.json").write_text(
-                json.dumps({
-                    "n": n, "cmd": "smoke", "rc": 0, "tail": "",
-                    "parsed": {"metric": c["metric"],
-                               "value": c["value"] * (1 + 0.02 * n),
-                               "unit": c.get("unit", "per_sec")}}))
-    traj = perf_regress.load_trajectories(str(out / "BENCH_el*.json"))
-    gate = (perf_regress.evaluate(cands, traj, tolerance=0.5)
-            + perf_regress.evaluate(latency_cand, traj, tolerance=0.5,
-                                    lower_is_better=True))
-    assert all(r["status"] == "pass" for r in gate), gate
     return {"rounds": rounds, "commits": commits, "shards": shards,
             "migration_latency_s": latency,
-            "aborts": len(aborts), "gate": gate}
+            "commits_per_s": commits / seconds,
+            "aborts": len(aborts)}
 
 
 def engine_overload_and_drain(seed: int) -> dict:
@@ -434,9 +384,6 @@ def main():
     from distkeras_tpu import telemetry
 
     tel = telemetry.enable()
-    # failover first: its perf_regress rate candidate reads the
-    # registry while only scenario 3's commits are in it (scenario 4's
-    # gate counts ps_shard_commits_total, which nothing else touches)
     fail = failover_round(args.rows, args.out_dir or tempfile.mkdtemp(
         prefix="dkt_chaos_fo_"))
     elastic = elastic_migration_round(
@@ -473,7 +420,9 @@ def main():
         f"  rounds retried         {fail['worker_retries']}",
         f"  promotion latency      "
         f"{fail['promotion_latency_s'] * 1e3:.1f}ms "
-        "(kill -> ps_promote, perf_regress gated)",
+        "(kill -> ps_promote; a CPU reading)",
+        f"  commit rate            {fail['commits_per_s']:.1f}/s "
+        "(a CPU reading)",
         "== scenario 4: elastic reshard + receiver kill mid-move ==",
         f"  rounds completed       {elastic['rounds']}",
         f"  commits on group       {elastic['commits']} "
@@ -481,7 +430,9 @@ def main():
         f"  final shard count      {elastic['shards']}",
         f"  migration latency      "
         f"{elastic['migration_latency_s'] * 1e3:.1f}ms "
-        "(fence -> cutover, perf_regress gated)",
+        "(fence -> cutover; a CPU reading)",
+        f"  commit rate            {elastic['commits_per_s']:.1f}/s "
+        "(a CPU reading)",
         f"  aborted moves          {elastic['aborts']} "
         "(receiver killed mid-stream; old owner un-fenced)",
     ]

@@ -18,9 +18,7 @@ nothing:
 
 ``modelcheck_states_explored_total`` / ``modelcheck_violations_total
 {invariant=...}`` are emitted through the telemetry registry;
-``--metrics-out`` writes the snapshot so ``perf_regress.py
---from-registry`` can gate on exploration throughput like any other
-counter.
+``--metrics-out`` writes the snapshot as JSON.
 """
 
 import argparse

@@ -15,8 +15,7 @@ Suppressions that no longer match any finding are themselves reported
 
 Finding counts are emitted as ``lint_findings_total{rule=...}`` through
 the telemetry registry; ``--metrics-out`` writes the registry snapshot
-so ``perf_regress.py --from-registry`` can gate on finding-count
-regressions exactly like any other counter.
+as JSON.
 """
 
 import argparse

@@ -5,9 +5,7 @@ Two modes:
 
 * ``--metrics m.jsonl --trace t.json`` — summarize artifacts an
   earlier run wrote (``MetricsRegistry.write_jsonl`` /
-  ``Tracer.write_chrome_trace``, e.g. from
-  ``scripts/perf_serving.py --metrics ... --trace ...``).  Either flag
-  alone works.
+  ``Tracer.write_chrome_trace``).  Either flag alone works.
 * ``--smoke`` — self-contained end-to-end proof at tiny CPU shapes
   (the tier-1 registration, via test_examples.py's scripts-coverage
   check): enables telemetry, runs (1) a mixed-length ``DecodeEngine``

@@ -17,6 +17,7 @@ suite must be race-clean, not just pass."""
 
 import importlib.util
 import threading
+import time
 from pathlib import Path
 
 import jax
@@ -26,7 +27,7 @@ import pytest
 from distkeras_tpu import flight_recorder, telemetry
 from distkeras_tpu.analysis import racecheck
 from distkeras_tpu.data import datasets
-from distkeras_tpu.gateway import ServingGateway
+from distkeras_tpu.gateway import EngineReplica, ServingGateway
 from distkeras_tpu.models import ModelSpec, model_config
 from distkeras_tpu.parallel.elastic_ps import (
     ElasticPSClient,
@@ -47,6 +48,7 @@ from distkeras_tpu.parallel.update_rules import (
     DynSGDRule,
     ElasticRule,
 )
+from distkeras_tpu.serving import DecodeEngine
 from distkeras_tpu.trainers import AEASGD, DOWNPOUR
 
 MLP = model_config("mlp", (8,), num_classes=4, hidden=(16,))
@@ -581,6 +583,105 @@ def test_autoscaler_gateway_domain_and_verb_error(tmp_path):
         assert len(ev) == 1 and ev[0]["reason"].startswith("error:")
     finally:
         flight_recorder.stop()
+        telemetry.disable()
+
+
+def test_autoscaler_split_reshards_a_live_group_exactly_once():
+    """Breach -> split, executed on a LIVE ``ElasticPSGroup``: the
+    decision's verb is the group's own ``split``, commits issued
+    before and after it all land once, the center stays byte-equal to
+    the unsharded reference, and at ``max_shards`` the next breach is
+    recorded but suppressed."""
+    tel = telemetry.enable()
+    center = _params(0)
+    ref = HostParameterServer(DownpourRule(), center)
+    grp = ElasticPSGroup(DownpourRule(), center, num_shards=1,
+                         num_servers=1)
+    try:
+        sc = _scaler(tel, split_shard=lambda: grp.split(_widest(grp)),
+                     shard_count=lambda: grp.num_shards, max_shards=2,
+                     cooldown_s=0.0)
+        (client,) = _elastic_clients(grp, center, 1)
+        ref.pull(0)
+        client.pull()
+        sched = _schedule(n_workers=1, n_commits=6)
+        for i, (_, d) in enumerate(sched):
+            if i == 3:
+                dec, = sc.step(_breach("ps_lock_wait"), now_s=0.0)
+                assert (dec["action"], dec["executed"]) == \
+                    ("split", True)
+                assert grp.num_shards == 2
+            ref.commit(0, d, seq=i)
+            client.commit(d)
+        dec, = sc.step(_breach("ps_lock_wait"), now_s=1.0)
+        assert not dec["executed"] and dec["reason"] == "bounds"
+        assert grp.num_shards == 2
+        assert grp.num_commits == len(sched)
+        assert pack_params(ref.center) == pack_params(grp.center)
+        client.close()
+    finally:
+        grp.stop()
+        telemetry.disable()
+
+
+def test_autoscaler_spawns_a_serving_replica_and_queue_depth_clears():
+    """The gateway domain's closed loop on real engines: a backlog on
+    a one-replica gateway breaches ``queue_depth``; the decision's
+    verb admits a second ``EngineReplica`` through
+    ``gateway.add_replica``; the newcomer serves requests; and once
+    the backlog is drained the breach is gone."""
+    spec = model_config("transformer_lm", (32,), input_dtype="int32",
+                        vocab_size=37, num_layers=1, d_model=32,
+                        num_heads=2, max_len=32, dtype="float32")
+    model = ModelSpec.from_config(spec).build()
+    variables = model.init(jax.random.key(0),
+                           np.zeros((2, 32), np.int32))
+
+    def replica(name):
+        return EngineReplica(
+            DecodeEngine(model, variables, slots=2, prefill_align=4,
+                         max_new_tokens=24), name=name)
+
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 37, (6,)).astype(np.int32)
+               for _ in range(28)]
+    tel = telemetry.enable()
+    try:
+        gw = ServingGateway([replica("g0")], policy="least_loaded")
+        sc = telemetry.Autoscaler(
+            telemetry.SLOWatchdog(
+                tel.metrics, thresholds={"queue_depth": (3.0, 1e9)}),
+            spawn_replica=lambda: gw.add_replica(replica("auto0")),
+            replica_count=gw.alive_replicas, min_replicas=1,
+            max_replicas=2, cooldown_s=0.0, idle_sustain_s=1e9,
+            gateway_scale_signals=("queue_depth",))
+        with gw:
+            rids = [gw.submit(p) for p in prompts[:16]]
+            # the replica's driver thread moves the submissions into
+            # the engine's queue on its own schedule: wait (bounded)
+            # for the backlog to show on the gauge
+            for _ in range(30_000):
+                verdict = sc.watchdog.evaluate()
+                if "queue_depth" in verdict["breaches"]:
+                    break
+                time.sleep(0.001)
+            assert "queue_depth" in verdict["breaches"], verdict
+            dec, = sc.step(verdict)
+            assert (dec["domain"], dec["action"], dec["executed"]) \
+                == ("gateway", "spawn", True)
+            assert gw.alive_replicas() == 2
+            rids += [gw.submit(p) for p in prompts[16:]]
+            results = [gw.result(r, timeout=120) for r in rids]
+            cleared = sc.watchdog.evaluate()
+        assert [r.get("error") for r in results] == \
+            [None] * len(prompts)
+        assert "queue_depth" not in cleared["breaches"], cleared
+        served_by_newcomer = sum(
+            v for k, v in tel.metrics.snapshot()["counters"].items()
+            if k.startswith("gateway_requests_total")
+            and 'replica="auto0"' in k)
+        assert served_by_newcomer > 0
+    finally:
         telemetry.disable()
 
 

@@ -21,33 +21,15 @@ REPO = Path(__file__).resolve().parent.parent
 EXAMPLES = REPO / "examples"
 SCRIPTS = REPO / "scripts"
 
-# perf/measurement scripts that advertise a --smoke mode run it here
-# at tiny CPU shapes — the same no-silent-rot contract as CASES.
+# scripts that advertise a --smoke mode run it here at tiny CPU
+# shapes — the same no-silent-rot contract as CASES.
 SMOKE_SCRIPTS = {
     "chaos_report.py": ["--smoke"],
     "check_protocol.py": ["--smoke"],
     "lint_static.py": ["--smoke"],
     "obs_report.py": ["--smoke"],
-    "perf_attrib.py": ["--smoke"],
-    "perf_capacity.py": ["--smoke"],
-    "perf_elastic.py": ["--smoke"],
-    "perf_gateway.py": ["--smoke"],
-    "perf_hier.py": ["--smoke"],
-    "perf_host_ps.py": ["--smoke"],
-    "perf_mesh_comm.py": ["--smoke"],
-    "perf_paging.py": ["--smoke"],
-    "perf_prefill_decode.py": ["--smoke"],
-    "perf_prefix.py": ["--smoke"],
-    "perf_ps_flagship.py": ["--smoke"],
-    "perf_regress.py": ["--smoke"],
-    "perf_roofline.py": ["--smoke"],
-    "perf_serving.py": ["--smoke"],
-    "perf_spec.py": ["--smoke"],
     "postmortem.py": ["--smoke"],
 }
-# registered but out of tier-1: the roofline smoke sweeps many op
-# shapes and runs minutes-long on the CI CPU (run with -m slow)
-SLOW_SMOKE = {"perf_roofline.py"}
 
 # script -> tiny-shape args (every script also gets --devices 4).
 # Sizes respect each script's internal assertions: convergence checks
@@ -96,10 +78,7 @@ def test_every_smoke_script_is_covered():
         f"stale={set(SMOKE_SCRIPTS) - smoke}")
 
 
-@pytest.mark.parametrize("script", [
-    pytest.param(s, marks=([pytest.mark.slow] if s in SLOW_SMOKE
-                           else []))
-    for s in sorted(SMOKE_SCRIPTS)])
+@pytest.mark.parametrize("script", sorted(SMOKE_SCRIPTS))
 def test_smoke_script_runs(script):
     env = dict(os.environ, PYTHONPATH=str(REPO), JAX_PLATFORMS="cpu")
     proc = subprocess.run(

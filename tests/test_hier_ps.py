@@ -161,6 +161,82 @@ def test_flat_and_hier_centers_are_byte_identical():
     assert len(ps.staleness_log) == W * R
 
 
+class _CountingRoot:
+    """Root PS proxy that counts what crosses the root hop: one
+    message per commit call and the bytes of the tree it carries (a
+    worker's delta on the flat topology, a leader's fold on the
+    tree)."""
+
+    def __init__(self, ps):
+        self._ps = ps
+        self._lock = threading.Lock()
+        self.msgs = 0
+        self.nbytes = 0
+
+    def __getattr__(self, name):
+        return getattr(self._ps, name)
+
+    def _count(self, tree):
+        with self._lock:
+            self.msgs += 1
+            self.nbytes += sum(np.asarray(v).nbytes
+                               for v in tree.values())
+
+    def commit(self, worker_id, payload, local=None, seq=None):
+        self._count(payload)
+        return self._ps.commit(worker_id, payload, local, seq=seq)
+
+    def commit_packed(self, worker_id, payload, local=None, seq=None):
+        self._count(payload)
+        return self._ps.commit_packed(worker_id, payload, local,
+                                      seq=seq)
+
+    def commit_group(self, leader_id, fold, staleness, workers,
+                     seq=None):
+        self._count(fold)
+        return self._ps.commit_group(leader_id, fold, staleness,
+                                     workers, seq=seq)
+
+
+@pytest.mark.parametrize("W,G", [(16, 4), (8, 4), (6, 2)])
+def test_root_fan_in_is_exactly_the_group_size(W, G):
+    """The root-hop arithmetic of the tree: the root applies every
+    logical commit on both topologies, but sees ``W*R/g`` messages on
+    the tree against ``W*R`` flat, and — the fold being one delta
+    wide — exactly ``g`` times fewer bytes."""
+    center = _dyadic_center()
+    R, g = 2, W // G
+    total = W * R
+
+    flat_ps = HostParameterServer(DownpourRule(), center)
+    flat_root = _CountingRoot(flat_ps)
+    flat_srv = PSServer(flat_root, center).start()
+    _run_workers(center, lambda w: flat_srv.address, W, R)
+    flat_srv.stop()
+
+    ps = HostParameterServer(DownpourRule(), center)
+    hier_root = _CountingRoot(ps)
+    root = HierPSServer(hier_root, center).start()
+    leaders = [GroupLeader(DownpourRule(), center, root.address,
+                           group_id=gi, aggregate_window=g).start()
+               for gi in range(G)]
+    _run_workers(center, lambda w: leaders[w // g].address, W, R)
+    for lead in leaders:
+        lead.drain()
+        lead.stop()
+    root.stop()
+
+    assert flat_ps.num_commits == ps.num_commits == total
+    assert flat_root.msgs == total
+    assert (hier_root.msgs == sum(l.num_upstream for l in leaders)
+            == total // g)
+    assert flat_root.msgs / hier_root.msgs == g
+    assert flat_root.nbytes == g * hier_root.nbytes
+    for k in center:
+        assert (np.asarray(ps.center[k]).tobytes()
+                == np.asarray(flat_ps.center[k]).tobytes()), k
+
+
 def test_dynsgd_fold_carries_staleness_vector_byte_exactly():
     """DynSGD scales each payload by 1/(staleness+1) at commit time;
     the leader must apply that scaling per CONSTITUENT with its own

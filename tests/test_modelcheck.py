@@ -6,6 +6,7 @@ models: every scenario explores clean at smoke bounds and every seeded
 unsafe mutant yields a minimized, replayable counterexample breaking
 exactly the invariant the mutant table predicts."""
 
+import json
 import subprocess
 import sys
 from pathlib import Path
@@ -297,11 +298,9 @@ def test_elect_is_the_production_function():
     assert protomodel.mint_epoch is replicated_ps.mint_epoch
 
 
-def test_metrics_snapshot_feeds_perf_regress(tmp_path):
-    """``--metrics-out`` writes a registry snapshot that
-    ``perf_regress.from_registry`` can gate on, exactly like
-    ``lint_static.py``'s finding counters."""
-    import importlib.util
+def test_metrics_snapshot_holds_states_explored(tmp_path):
+    """``--metrics-out`` writes a registry snapshot whose
+    ``modelcheck_states_explored_total`` counts the exploration."""
     snap = tmp_path / "mc.json"
     proc = subprocess.run(
         [sys.executable, str(REPO / "scripts" / "check_protocol.py"),
@@ -309,14 +308,8 @@ def test_metrics_snapshot_feeds_perf_regress(tmp_path):
          "--metrics-out", str(snap)],
         capture_output=True, text=True, timeout=120, cwd=str(REPO))
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    spec = importlib.util.spec_from_file_location(
-        "perf_regress", REPO / "scripts" / "perf_regress.py")
-    pr = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pr)
-    cands = pr.from_registry(str(snap), "mc_states_per_sec",
-                             "modelcheck_states_explored_total", 10.0)
-    assert len(cands) == 1
-    assert cands[0]["value"] > 0  # states explored flowed through
+    counters = json.loads(snap.read_text())["counters"]
+    assert counters["modelcheck_states_explored_total"] > 0
 
 
 def test_check_protocol_replay_cli():

@@ -386,3 +386,54 @@ def test_paged_compile_guard_steady_state():
         assert after == before
     finally:
         telemetry.disable()
+
+
+# ---------------------------------------------------------------------
+# capacity: what per-page billing buys at one KV budget
+# ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("budget_slots", [2, 3])
+def test_paged_holds_more_live_slots_in_the_same_kv_positions(
+        budget_slots):
+    """One KV budget, counted in cached token positions: the envelope
+    arm spends it as ``budget_slots`` whole envelopes, the paged arm
+    as that many positions in pages.  Under a mostly-short workload
+    the envelope arm peaks at exactly its slot count and the paged arm
+    strictly above it — with the same tokens."""
+    tel = telemetry.enable()
+    try:
+        model, variables = _model()
+        page = 4
+        kv_pages = budget_slots * MAXLEN // page
+        lengths = [22, 4, 3, 5, 4, 3, 5, 4, 3, 5]
+        reqs = [{"prompt": p, "max_new_tokens": 3, "i": i}
+                for i, p in enumerate(_prompts(lengths, seed=5))]
+        occ = tel.metrics.gauge("serving_slot_occupancy",
+                                bucket=MAXLEN)
+
+        def drive(**kw):
+            eng = DecodeEngine(model, variables, buckets=[MAXLEN],
+                               prefill_align=page, **kw)
+            for r in reqs:
+                eng.submit(r["prompt"], max_new_tokens=3,
+                           meta={"i": r["i"]})
+            peak, out = 0, {}
+            while eng.has_work():
+                for r in eng.step():
+                    assert r.get("error") is None, r
+                    out[r["i"]] = r["tokens"]
+                peak = max(peak, int(occ.value))
+            eng.close()
+            return peak, out
+
+        env_peak, env_tok = drive(slots=budget_slots)
+        pag_peak, pag_tok = drive(slots=8, kv_pages=kv_pages,
+                                  page_size=page)
+        assert kv_pages * page == budget_slots * MAXLEN
+        assert env_peak == budget_slots
+        assert pag_peak > env_peak
+        for i in env_tok:
+            np.testing.assert_array_equal(pag_tok[i], env_tok[i])
+    finally:
+        telemetry.disable()

@@ -217,6 +217,31 @@ def test_router_end_to_end_parity_and_counters(mv, tmp_path):
         telemetry.disable()
 
 
+def test_monolithic_gateway_never_touches_the_handoff_path(mv):
+    """The same prompts through a plain ``ServingGateway`` over
+    engines with the same prefix stores: right tokens, and not one
+    page shipped nor a handoff requeued — the counters are the
+    router's alone."""
+    tel = telemetry.enable()
+    try:
+        work = [(p, 3 + i % 3) for i, p in enumerate(
+            _prompts([3, 12, 7, 12], seed=11))]
+        with ServingGateway(
+                [EngineReplica(_engine(mv), name=f"m{i}")
+                 for i in range(2)], policy="least_loaded") as gw:
+            rids = [gw.submit(p, max_new_tokens=n) for p, n in work]
+            results = [gw.result(r, timeout=120) for r in rids]
+        for (p, n), r in zip(work, results):
+            assert r.get("error") is None, r
+            np.testing.assert_array_equal(r["tokens"],
+                                          _want(mv, p, n))
+        counters = tel.metrics.snapshot()["counters"]
+        assert counters.get("serving_kv_pages_shipped_total", 0) == 0
+        assert counters.get("serving_handoff_requeue_total", 0) == 0
+    finally:
+        telemetry.disable()
+
+
 def test_router_survives_dead_prefill_pool(mv):
     """A dead prefill pool degrades to decode-side recompute — same
     tokens, no lost request."""

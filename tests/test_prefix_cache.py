@@ -370,6 +370,57 @@ def test_prefix_counters_and_hit_rate_gauge():
         telemetry.disable()
 
 
+@pytest.mark.parametrize("chunk", [None, 4],
+                         ids=["prefix", "prefix+chunk"])
+def test_steady_state_wave_skips_most_of_its_prefill(chunk):
+    """Tokens NOT prefilled, as a count: once the store holds the
+    shared head, a wave of the same system-prompt traffic skips at
+    least half of all its prompt tokens — with chunked prefill on or
+    off."""
+    model, variables = _model()
+    prompts = _shared_prompts()
+    kw = {} if chunk is None else {"prefill_chunk": chunk}
+    with _engine(model, variables, prefix_cache_bytes=1 << 24,
+                 **kw) as eng:
+        _drain(eng, prompts, tag="warm")
+        before = eng.prefix_stats()["tokens_saved"]
+        _drain(eng, prompts, tag="steady")
+        saved = eng.prefix_stats()["tokens_saved"] - before
+    assert saved / sum(len(p) for p in prompts) >= 0.5
+
+
+@pytest.mark.parametrize("chunk,want_steps", [(None, 1), (8, 4)],
+                         ids=["unchunked", "chunked"])
+def test_long_prefill_spans_one_step_or_one_per_chunk(chunk,
+                                                      want_steps):
+    """Engine steps between a long prompt's submit and its first
+    token, beside a live decoding slot: one when the prefill is
+    monolithic, one per chunk (32 padded / 8) when it is chunked."""
+    model, variables = _model()
+    rng = np.random.default_rng(3)
+    short = rng.integers(0, VOCAB, (5,)).astype(np.int32)
+    long = rng.integers(0, VOCAB, (30,)).astype(np.int32)
+    kw = {} if chunk is None else {"prefill_chunk": chunk}
+    with _engine(model, variables, slots=2, **kw) as eng:
+        eng.submit(short, max_new_tokens=12, request_id="short")
+        eng.step()  # short prefills; it decodes from here on
+        eng.submit(long, max_new_tokens=1, request_id="long")
+        steps, out = 0, {}
+        while "long" not in out:
+            for r in eng.step():
+                assert "error" not in r, r
+                out[r["request_id"]] = np.asarray(r["tokens"])
+            steps += 1
+        while eng.has_work():
+            for r in eng.step():
+                out[r["request_id"]] = np.asarray(r["tokens"])
+    assert steps == want_steps
+    np.testing.assert_array_equal(out["long"],
+                                  _want(model, variables, long, 1))
+    np.testing.assert_array_equal(out["short"],
+                                  _want(model, variables, short, 12))
+
+
 # ---- knob validation --------------------------------------------------
 
 

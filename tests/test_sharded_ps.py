@@ -280,6 +280,59 @@ def test_version_delta_pull_skips_unchanged_shards():
         server.stop()
 
 
+@pytest.mark.parametrize("k,polls", [(2, 3), (4, 4)])
+def test_stale_polling_reader_pays_one_pull_per_commit(k, polls):
+    """A reader that polls ``polls`` times per commit is shipped the
+    tree once per commit and a header the other times: the bytes saved
+    are exactly ``(polls - 1) / polls`` of the naive wire, shard by
+    shard, and the assembled tree is still the center."""
+    center = _params(0)
+    full = leaf_nbytes(jax.tree_util.tree_leaves(center))
+    ps = ShardedParameterServer(DownpourRule(), center, k)
+    server = PSServer(ps, center).start()
+    host, port = server.address
+    rounds = 2
+    try:
+        writer = ShardedPSClient(host, port, 0, center, num_shards=k)
+        stats = {}
+        reader = ShardedPSClient(host, port, 1, center, num_shards=k,
+                                 stats=stats)
+        writer.pull()
+        reader.pull()  # the first pull is always full
+        delta = jax.tree_util.tree_map(
+            lambda x: 1e-3 * np.ones_like(x), center)
+        for s in range(rounds):
+            writer.commit(delta, seq=s)
+            for _ in range(polls):
+                tree = reader.pull()
+        writer.close()
+        reader.close()
+    finally:
+        server.stop()
+    assert stats["pull_bytes_saved"] == rounds * (polls - 1) * full
+    assert stats["pull_shards_skipped"] == rounds * (polls - 1) * k
+    assert stats["pull_bytes_saved"] / (rounds * polls * full) > 0.5
+    for name in center:
+        np.testing.assert_array_equal(tree[name],
+                                      np.asarray(ps.center[name]))
+
+
+def test_psserver_restarts_sharded_from_a_sharded_snapshot():
+    """``PSServer.restart_from`` on a K-sharded snapshot comes back
+    K-sharded with the same bytes."""
+    center = _params(0)
+    sha = ShardedParameterServer(DownpourRule(), center, 2)
+    for w, d, seq in _schedule():
+        sha.commit(w, d, seq=seq)
+    srv = PSServer.restart_from(sha.snapshot(), DownpourRule(), center)
+    try:
+        assert srv.ps.num_shards == 2
+        assert srv.ps.num_commits == sha.num_commits
+        assert pack_params(srv.ps.center) == pack_params(sha.center)
+    finally:
+        srv.stop()
+
+
 def test_sharded_wire_commit_dedupes_per_shard():
     """A retried logical commit (same seq) is deduped shard by shard —
     the reply is byte-identical and nothing applies twice."""

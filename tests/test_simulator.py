@@ -4,12 +4,17 @@ exactly-once against a gateway, the stepped-rate search finds the knee
 of a known queue, and the capacity model's fit/required() arithmetic
 holds.
 
-Everything here runs against FAKE gateways (a deterministic FIFO
-queue), so the suite tests the simulator's own contracts in
-milliseconds-to-seconds — the full-stack closed-loop drill lives in
-``scripts/perf_capacity.py --smoke`` (test_examples.py runs it)."""
+All but the last test run against FAKE gateways (a deterministic
+FIFO queue), so the suite tests the simulator's own contracts in
+milliseconds-to-seconds; the last one is the full-stack closed-loop
+drill — real engines behind a real gateway, the autoscaler, a replica
+kill and a fault window on a training tenant's wire — held to its
+ledger of counts."""
 
 import dataclasses
+import importlib.util
+import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -339,3 +344,157 @@ def test_run_drill_reports_an_unhealed_deficit_as_unconverged():
                     max_replicas=4, drain_timeout_s=5.0)
     assert not out["converged"]
     assert [e["closed"] for e in out["episodes"]] == [False]
+
+
+# ---- the full-stack drill: its ledger of counts ------------------------
+
+
+def test_full_stack_drill_ledger(tmp_path):
+    """A burst over two real ``EngineReplica``s behind a
+    ``ServingGateway``, one of them killed inside the burst, the
+    ``Autoscaler`` refilling from a ``ReplicaPool``, and a socket-PS
+    training tenant whose wire sits in a reset/delay fault window the
+    whole time.  What the run must count, whatever it took: one kill;
+    faults injected; every arrival answered once, without error, by
+    the tokens a solo engine yields; two replicas alive and
+    the SLO state ``ok`` at the end; the trainer's commits equal to
+    its rounds; and a flight ring the postmortem can read the story
+    from.  The burst offers several times what the replicas can serve,
+    so the queue-depth breach that refills the fleet does not depend
+    on the machine's speed."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu import flight_recorder
+    from distkeras_tpu.data import datasets
+    from distkeras_tpu.gateway import EngineReplica, ServingGateway
+    from distkeras_tpu.models import ModelSpec, model_config
+    from distkeras_tpu.serving import DecodeEngine
+    from distkeras_tpu.trainers import DOWNPOUR
+
+    spec = model_config("transformer_lm", (64,), input_dtype="int32",
+                        vocab_size=61, num_layers=2, d_model=64,
+                        num_heads=2, max_len=64, dtype="float32")
+    model = ModelSpec.from_config(spec).build()
+    variables = model.init(jax.random.key(0),
+                           jnp.zeros((2, 8), jnp.int32))
+
+    def replica(name):
+        eng = DecodeEngine(model, variables, slots=1, prefill_align=8,
+                           max_new_tokens=16)
+        list(eng.run([{"prompt": np.zeros((8,), np.int32),
+                       "max_new_tokens": 2}]))
+        return EngineReplica(eng, name=name)
+
+    mlp = model_config("mlp", (8,), num_classes=4, hidden=(16,))
+    data = datasets.synthetic_classification(160, (8,), 4, seed=0)
+    train = {"runs": 0, "errors": []}
+    stop = threading.Event()
+
+    def train_once():
+        t = DOWNPOUR(mlp, fidelity="host", transport="socket",
+                     num_workers=2, communication_window=2,
+                     batch_size=16, num_epoch=1, learning_rate=0.01,
+                     worker_optimizer="adam", worker_retries=14)
+        t.train(data)
+        rounds = len(t.history["round_loss"])
+        commits = t.parameter_server_state.num_commits
+        train["runs"] += 1
+        if commits != rounds or "worker_failures" in t.history:
+            train["errors"].append((commits, rounds, dict(t.history)))
+
+    def tenant():
+        while not stop.is_set():
+            try:
+                train_once()
+            except Exception as e:  # surfaced by the assertion below
+                train["errors"].append(repr(e))
+                return
+            stop.wait(0.2)
+
+    train_once()  # compile the tenant's step outside the fault window
+    trace_spec = _spec(
+        duration_s=1.0, mean_qps=8.0, seed=3, prompt_median=6.0,
+        prompt_sigma=0.3, prompt_min=4, prompt_max=8,
+        output_alpha=1.6, output_min=8, output_max=16, vocab=61,
+        sessions=6, flash_crowds=((0.3, 0.7, 25.0),),
+        tenants=(("free", 0.7, 0), ("paid", 0.3, 2)))
+    trace = generate_trace(trace_spec)
+    cap = CapacityModel([CapacityPoint({"replicas": 1}, 50.0, 1.0, 0.1)])
+    schedule = ChaosSchedule(
+        windows=((0.0, 600.0, ("reset", "delay")),),
+        kills=((0.45, "r0"),))
+    r0, r1, spare = replica("r0"), replica("r1"), replica("s0")
+    schedule.register_kill("r0", r0.kill)
+
+    tel = telemetry.enable()
+    flight_recorder.start(tmp_path / "fdr")
+    try:
+        thresholds = {
+            k: ((-1.0, -2.0)
+                if k in telemetry.LOWER_IS_WORSE_SLO_SIGNALS
+                else (1e9, 2e9))
+            for k in telemetry.DEFAULT_SLO_THRESHOLDS}
+        thresholds["queue_depth"] = (3.0, 1e9)
+        watchdog = telemetry.SLOWatchdog(tel.metrics,
+                                         thresholds=thresholds)
+        with ServingGateway([r0, r1], policy="least_loaded",
+                            retries=8, backoff_base=0.01) as gw:
+            pool = ReplicaPool(gw, [spare])
+            scaler = telemetry.Autoscaler(
+                watchdog, spawn_replica=pool.spawn_replica,
+                drain_replica=pool.drain_replica,
+                replica_count=pool.replica_count, min_replicas=1,
+                max_replicas=2, cooldown_s=0.0, idle_sustain_s=3600.0,
+                gateway_scale_signals=("queue_depth",), busy=gw.busy)
+            with schedule.chaos_transport(
+                    seed=13, delay_s=0.005, window_rate=0.35,
+                    max_injections=10) as ct:
+                worker = threading.Thread(target=tenant, daemon=True)
+                worker.start()
+                drill = run_drill(
+                    trace, gw, scaler, cap,
+                    schedule=schedule, tick_interval_s=0.05,
+                    max_replicas=2, drain_timeout_s=120.0)
+                stop.set()
+                worker.join(60)
+                assert not worker.is_alive()
+            final = watchdog.evaluate()
+            end_replicas = gw.alive_replicas()
+        counters = tel.metrics.snapshot()["counters"]
+        events = flight_recorder.active().read_events()
+    finally:
+        flight_recorder.stop()
+        telemetry.disable()
+
+    def csum(name):
+        return sum(v for k, v in counters.items()
+                   if k == name or k.startswith(name + "{"))
+
+    rep = drill["replay"]
+    assert csum("sim_kills_total") == 1
+    assert csum("chaos_window_injected_total") > 0, dict(ct.counts)
+    assert rep["completed"] == rep["arrivals"] == len(trace.arrivals)
+    assert rep["errors"] == rep["duplicates"] == rep["undrained"] == 0
+    rids = [r["request_id"] for r in rep["results"]]
+    assert len(set(rids)) == len(rids) == rep["arrivals"]
+    assert drill["converged"], drill["episodes"]
+    assert end_replicas == 2
+    assert final["state"] == "ok", final
+    assert train["runs"] >= 2 and not train["errors"], train
+    served = sorted(rep["results"], key=lambda r: r["sim_t"])[:10]
+    solo = DecodeEngine(model, variables, slots=1, prefill_align=8,
+                        max_new_tokens=16)
+    for r, ref in zip(served, solo.run(
+            [{"prompt": np.asarray(r["prompt"], np.int32),
+              "max_new_tokens": len(r["tokens"])} for r in served])):
+        np.testing.assert_array_equal(np.asarray(r["tokens"]),
+                                      np.asarray(ref["tokens"]))
+    solo.close()
+    pm_spec = importlib.util.spec_from_file_location(
+        "postmortem", Path(__file__).resolve().parent.parent
+        / "scripts" / "postmortem.py")
+    postmortem = importlib.util.module_from_spec(pm_spec)
+    pm_spec.loader.exec_module(postmortem)
+    kinds = {s["kind"] for s in postmortem.drill_story(events)}
+    assert {"sim_phase", "sim_kill", "slo_state"} <= kinds, kinds

@@ -187,6 +187,78 @@ def test_paged_parity_and_page_accounting():
         eng.close()
 
 
+def _count_steps(eng, reqs):
+    """Drive ``reqs`` to completion by hand; returns (engine steps
+    taken, tokens by request index)."""
+    for r in reqs:
+        eng.submit(r["prompt"], max_new_tokens=r["max_new_tokens"],
+                   meta={"i": r["i"]})
+    steps, out = 0, {}
+    while eng.has_work():
+        for r in eng.step():
+            assert r.get("error") is None, r
+            out[r["i"]] = np.asarray(r["tokens"])
+        steps += 1
+    return steps, out
+
+
+@pytest.mark.parametrize("kv_pages", [None, 24],
+                         ids=["envelope", "paged"])
+def test_self_draft_takes_strictly_fewer_steps_than_baseline(kv_pages):
+    """What the program guarantees about step counts: a draft that
+    shares the target's weights is always accepted, every verify
+    commits ``k + 1`` tokens, so the same requests finish in strictly
+    fewer engine steps than without speculation — same tokens."""
+    model, variables = _model()
+    reqs = [{"prompt": p, "max_new_tokens": 12, "i": i}
+            for i, p in enumerate(_prompts([5, 9, 3]))]
+    kw = dict(slots=3, buckets=[MAXLEN], prefill_align=4)
+    if kv_pages is not None:
+        kw["kv_pages"] = kv_pages
+    with DecodeEngine(model, variables, **kw) as eng:
+        base_steps, base = _count_steps(eng, reqs)
+    with DecodeEngine(model, variables,
+                      speculative=_self_draft(model, variables),
+                      **kw) as eng:
+        spec_steps, got = _count_steps(eng, reqs)
+        assert eng.spec_stats()["accept_rate"] == 1.0
+    assert spec_steps < base_steps
+    for i in base:
+        np.testing.assert_array_equal(got[i], base[i])
+
+
+def test_ngram_earns_acceptance_on_tiled_prompts_at_no_extra_steps():
+    """Prompt-lookup drafting on a context that repeats itself: some
+    proposals are accepted, so the run takes no more steps than the
+    baseline (an accepted token is a step saved; a rejected window
+    costs none), the tokens are the baseline's, and the acceptance
+    gauge is published."""
+    model, variables = _model()
+    rng = np.random.default_rng(7)
+    reqs = [{"prompt": np.tile(
+        rng.integers(0, VOCAB, (4,)).astype(np.int32), 3)[:10],
+        "max_new_tokens": 20, "i": i} for i in range(6)]
+    kw = dict(slots=3, buckets=[MAXLEN], prefill_align=4)
+    with DecodeEngine(model, variables, **kw) as eng:
+        base_steps, base = _count_steps(eng, reqs)
+    tel = telemetry.enable()
+    try:
+        with DecodeEngine(model, variables,
+                          speculative={"proposer": "ngram", "k": 3},
+                          **kw) as eng:
+            spec_steps, got = _count_steps(eng, reqs)
+            st = eng.spec_stats()
+        gauges = tel.metrics.snapshot()["gauges"]
+    finally:
+        telemetry.disable()
+    assert st["proposed"] > 0 and st["accept_rate"] > 0.02, st
+    assert spec_steps <= base_steps
+    assert gauges["serving_spec_accept_rate"] == \
+        pytest.approx(st["accept_rate"])
+    for i in base:
+        np.testing.assert_array_equal(got[i], base[i])
+
+
 def test_eos_inside_accepted_window_stops_mid_window():
     model, variables = _model()
     p = _prompts([9], seed=7)[0]
