@@ -22,9 +22,11 @@ checked):
 * ``serve/lm``      — ``DecodeEngine`` over the trained variables,
   envelope pools and the paged pool: greedy tokens equal
   ``models.generate()`` per request, byte for byte.
-* ``serve/pools``   — a two-layer engine with heads of 128, the
-  benchmark configuration's head size: its compiled step and prefill
-  programs, handed their pool, hold no copy of a whole pool leaf.
+* ``serve/pools``   — a two-layer engine with the benchmark
+  configuration's 16 heads of 128: its compiled step and prefill
+  programs, handed their pool, hold no copy of a whole pool leaf, and
+  its step programs read the cache through the decode kernel (their
+  ``decode_step`` spans carry ``attended_rows > 0``).
 * ``four chips/…``  — only where ``len(jax.devices()) >= 4``:
   ``DOWNPOUR(fidelity="mesh")`` one worker per chip, then the LM
   through ``SyncTrainer(num_workers=4)`` (blockwise attention: Mosaic
@@ -58,7 +60,9 @@ from distkeras_tpu import attrib, native, profiling, telemetry
 from distkeras_tpu.data import datasets
 from distkeras_tpu.models import ModelSpec, generate, model_config
 from distkeras_tpu.models.transformer import dense_causal_attention
-from distkeras_tpu.ops.attention import decode_attention, flash_attention
+from distkeras_tpu.ops.attention import (decode_attention,
+                                         decode_attention_applies,
+                                         flash_attention)
 from distkeras_tpu.serving import DecodeEngine
 from distkeras_tpu.trainers import (ADAG, DOWNPOUR, SingleTrainer,
                                     SyncTrainer)
@@ -90,8 +94,9 @@ DECODE_KERNEL = dict(
 # compile count stays at one prefill + one step program per bucket
 SERVE = dict(buckets=(512, 1024, 2048), align=128, slots=4, kv_pages=64,
              requests=((128, 16), (512, 32), (1024, 64)) * 3)
-# heads of 128 as in the benchmark's configuration; one prompt a bucket
-POOLS = dict(layers=2, d_model=1024, heads=8, vocab=4096, seq=1024,
+# 16 heads of 128 as in the benchmark's configuration (what the decode
+# kernel's rule takes in bfloat16); one prompt a bucket
+POOLS = dict(layers=2, d_model=2048, heads=16, vocab=4096, seq=1024,
              buckets=(512, 1024), align=128, slots=4,
              requests=((100, 4), (600, 4)))
 # four chips: batches are per chip
@@ -434,7 +439,13 @@ def pools_in_place(*, layers, d_model, heads, vocab, seq, buckets, align,
     printed with it.  Heads of 128: at this script's LM's 64 the TPU
     lays a ``[.., KVH, 64]`` leaf out another way by default and the
     step re-lays it out, as it did before PR 27 (48 whole-leaf copies
-    in its 12-layer step program either way; PERF.md section 7)."""
+    in its 12-layer step program either way; PERF.md section 7).
+
+    Beside the copies, whether the step programs read the cache through
+    ``ops.attention.decode_attention``: a pool's ``decode_step`` spans
+    carry ``attended_rows > 0`` where ``decode_attention_applies`` takes
+    the pool's leaves (on the chip, 16 heads of 128 in bfloat16) and 0
+    where it does not (any CPU run, 8 heads)."""
     cfg = lm_config(layers=layers, d_model=d_model, heads=heads,
                     vocab=vocab, seq=seq)
     model = ModelSpec.from_config(cfg).build()
@@ -443,14 +454,30 @@ def pools_in_place(*, layers, d_model, heads, vocab, seq, buckets, align,
     engine = DecodeEngine(cfg, variables, slots=slots,
                           buckets=list(buckets), prefill_align=align)
     rng = np.random.default_rng(0)
-    for res in engine.run(
-            [{"prompt": rng.integers(0, vocab, (t,)).astype(np.int32),
-              "max_new_tokens": n} for t, n in requests]):
-        if "error" in res:
-            raise AssertionError(f"pools: {res['error']}")
+    with fresh_telemetry() as tel:
+        for res in engine.run(
+                [{"prompt": rng.integers(0, vocab, (t,)).astype(np.int32),
+                  "max_new_tokens": n} for t, n in requests]):
+            if "error" in res:
+                raise AssertionError(f"pools: {res['error']}")
+        steps = [e["args"] for e in tel.tracer.events()
+                 if e["name"] == "decode_step"]
     report = engine.pool_report()
     engine.close()
+    sds = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
     for pool in report:
+        for rows in ("attended_rows", "envelope_rows"):
+            pool[rows] = sum(a[rows] for a in steps
+                             if a["bucket"] == pool["bucket"])
+        leaf = sds((slots, pool["bucket"], heads, d_model // heads))
+        kernel = decode_attention_applies(
+            sds((slots, heads, 1, d_model // heads)), leaf, leaf,
+            jax.ShapeDtypeStruct((slots,), jnp.int32))
+        if (pool["attended_rows"] > 0) != kernel:
+            raise AssertionError(
+                f"pool {pool['bucket']}: the decode kernel's rule says "
+                f"{kernel} and its steps attended "
+                f"{pool['attended_rows']} of {pool['envelope_rows']} rows")
         held = {name: n["relayouts"]
                 for name, n in pool["programs"].items() if n["relayouts"]}
         if pool["donated"] and (held or not pool["programs"]):

@@ -47,9 +47,10 @@ from distkeras_tpu.models.core import ModelSpec
 #:   (optional, ``[B]`` int32, with ``slot_pos`` only) says how many
 #:   cache positions of each row the step has to attend over:
 #:   ``slot_pos + 1`` for a row whose token is wanted, 0 for a row whose
-#:   output nobody reads.  A model may use it to read no further
-#:   (``ops.attention.decode_attention``) or ignore it; a row of length
-#:   0 may come back as anything finite;
+#:   output nobody reads.  A model may use it to read no further (both
+#:   do where ``ops.attention.decode_attention_applies`` takes their
+#:   cache) or ignore it; a row of length 0 may come back as anything
+#:   finite;
 #: * optionally an ``"expert_load"`` collection: one ``[E]`` count of
 #:   routed tokens an expert layer, read where it is made mutable.
 DECODE_CONTRACT = ("decode_clone", "dense_prefill_clone", "max_len",
@@ -130,9 +131,10 @@ def decode_step(dec, params: Mapping, cache, tok, *, slot_pos=None,
         positions each row attends over, ``slot_pos + 1``, or 0 for a
         row whose token is thrown away (a finished slot), which then
         reads no cache at all.  A model whose step reads its cache
-        through ``ops.attention.decode_attention`` (``LatentMoELM`` on
-        a TPU) stops at these lengths; elsewhere the step attends as
-        ``slot_pos`` alone says.
+        through ``ops.attention.decode_attention`` (``LatentMoELM`` and
+        ``TransformerLM`` on a TPU, where ``decode_attention_applies``
+        takes their cache) stops at these lengths; elsewhere the step
+        attends as ``slot_pos`` alone says.
       rng: key for sampling (``temperature > 0``).
 
     Returns ``(new_cache, next_tok, load)`` with ``next_tok`` ``[B]``

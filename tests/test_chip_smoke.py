@@ -104,6 +104,9 @@ def test_pools_in_place_toy(smoke):
     assert [p["bucket"] for p in facts["pools"]] == [16, 32]
     assert all(set(p["programs"]) == {"step", f"prefill_{t}"}
                for p, t in zip(facts["pools"], (4, 12)))
+    # off the chip the decode kernel's rule refuses, and the steps say so
+    assert all(p["attended_rows"] == 0 < p["envelope_rows"]
+               for p in facts["pools"])
 
 
 def test_four_devices_toy(smoke, devices):

@@ -589,10 +589,9 @@ def _computations_over(text, leaf):
 def test_v5e_kernel_reads_a_cgpt_pool_in_place(one_chip,
                                                traced_for_the_chip):
     """The kernel at the ``cgpt`` cells' 512 pool (32 slots x 512 x 16
-    heads of 128; ``SelfAttention`` does not call it yet, PERF.md section
-    7): after the step's per-row scatter into the donated K and V the
-    custom call takes both leaves as they lie, ``[L, 16, 128]`` folded to
-    ``[L * 16, 128]`` without a copy."""
+    heads of 128): after the step's per-row scatter into the donated K
+    and V the custom call takes both leaves as they lie, ``[L, 16, 128]``
+    folded to ``[L * 16, 128]`` without a copy."""
     from distkeras_tpu.ops import attention
 
     def step(ck, cv, q, k, v, pos, lengths):
@@ -611,6 +610,25 @@ def test_v5e_kernel_reads_a_cgpt_pool_in_place(one_chip,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert layouts.whole_leaf_copies(text, [pool, pool]) == 0
     over = _computations_over(text, pool)
+    assert [r for _, r in over] == ["bf16[32,512,16,128]"] * 2, over
+    assert "input_output_alias" in text
+
+
+def test_v5e_step_reads_its_cache_through_the_kernel(v5e_pool,
+                                                     traced_for_the_chip):
+    """The real ``cgpt`` step, handed its rows' lengths as ``step_core``
+    hands them: one kernel call a layer under ``attn_decode``, no
+    contraction under that scope or over a ``[B, L, 16, 128]`` leaf
+    outside the kernel (the scatter's two fusions alone take one), no
+    copy, the pool donated in place."""
+    dec, params, cache, on_chip = v5e_pool
+    text = _kernel_step(dec, params, cache, on_chip, 32)
+    scoped = _under_scope(text, "attn_decode")
+    calls = [tail for line, tail in scoped if "custom-call(" in line]
+    assert len(calls) == 1, calls
+    assert not any("dot_general" in tail for _, tail in scoped)
+    assert layouts.whole_leaf_copies(text, cache) == 0
+    over = _computations_over(text, _kv_leaves(cache)[0])
     assert [r for _, r in over] == ["bf16[32,512,16,128]"] * 2, over
     assert "input_output_alias" in text
 
@@ -645,14 +663,27 @@ KERNEL_TOY = dict(
     experts_per_token=2, expert_width=32, hc_sinkhorn_iters=4)
 
 
+def _gpt2_toy(**kw):
+    """A ``TransformerLM`` of two layers, by default one the kernel's rule
+    takes: float32 (a sublane tile of 8), 8 heads of 128."""
+    kw = {"d_model": 1024, "num_heads": 8, "dtype": "float32", **kw}
+    spec = model_config("transformer_lm", (256,), input_dtype="int32",
+                        vocab_size=VOCAB, num_layers=2, max_len=256, **kw)
+    model = ModelSpec.from_config(spec).build()
+    return model, model.init(jax.random.key(0),
+                             jnp.zeros((1, 8), jnp.int32))
+
+
 @pytest.fixture(scope="module")
-def kernel_toy():
+def kernel_toys():
+    """One toy of each served family that the kernel's rule takes."""
     spec = model_config("latent_moe_lm", (256,), input_dtype="int32",
                         vocab_size=VOCAB, max_len=256, dtype="float32",
                         **KERNEL_TOY)
     model = ModelSpec.from_config(spec).build()
-    return model, model.init(jax.random.key(1),
-                             jnp.zeros((1, 8), jnp.int32))
+    return {"latent": (model, model.init(jax.random.key(1),
+                                         jnp.zeros((1, 8), jnp.int32))),
+            "gpt2": _gpt2_toy()}
 
 
 def _served_by_the_kernel(monkeypatch, model, variables, reqs, **kw):
@@ -675,24 +706,30 @@ def _served_by_the_kernel(monkeypatch, model, variables, reqs, **kw):
     return out, steps
 
 
-@pytest.mark.parametrize("steps_per_sync", [1, 3])
-def test_the_kernel_serves_generates_tokens(kernel_toy, monkeypatch,
-                                            steps_per_sync):
-    """The latent family at toy size, budgets that leave finished slots'
-    dead rows beside live ones in most steps (and a request of the larger
-    pool): the tokens are ``generate()``'s, the steps' spans count the
-    rows the kernel's grid read."""
-    model, variables = kernel_toy
-    reqs = _requests(lengths=(5, 9, 3, 120, 7, 5, 11),
-                     budgets=(4, 9, 2, 12, 6, 3, 8))
-    out, steps = _served_by_the_kernel(
-        monkeypatch, model, variables, reqs, steps_per_sync=steps_per_sync)
+def _assert_generates_tokens(model, variables, reqs, out):
     for r in reqs:
         want = np.asarray(generate(
             model, variables, r["prompt"][None],
             max_new_tokens=r["max_new_tokens"]))[0, len(r["prompt"]):]
         np.testing.assert_array_equal(out[r["i"]], want)
-    assert steps
+
+
+@pytest.mark.parametrize("steps_per_sync", [1, 3])
+@pytest.mark.parametrize("family", ["latent", "gpt2"])
+def test_the_kernel_serves_generates_tokens(kernel_toys, monkeypatch,
+                                            family, steps_per_sync):
+    """Both families at toy size (``LatentAttention`` and
+    ``SelfAttention`` call the one kernel), budgets that leave finished
+    slots' dead rows beside live ones in most steps (and a request of the
+    larger pool): the tokens are ``generate()``'s, the steps' spans count
+    the rows the kernel's grid read."""
+    model, variables = kernel_toys[family]
+    reqs = _requests(lengths=(5, 9, 3, 120, 7, 5, 11),
+                     budgets=(4, 9, 2, 12, 6, 3, 8))
+    out, steps = _served_by_the_kernel(
+        monkeypatch, model, variables, reqs, steps_per_sync=steps_per_sync)
+    _assert_generates_tokens(model, variables, reqs, out)
+    assert {a["bucket"] for a in steps} == {128, 256}
     for args in steps:
         # whole blocks of 128 positions, at most the live slots' envelopes
         assert args["envelope_rows"] == 3 * args["bucket"] * steps_per_sync
@@ -701,12 +738,13 @@ def test_the_kernel_serves_generates_tokens(kernel_toy, monkeypatch,
         assert args["attended_rows"] % 128 == 0
 
 
-def test_a_done_slots_row_changes_no_live_slots_tokens(kernel_toy,
-                                                       monkeypatch):
+@pytest.mark.parametrize("family", ["latent", "gpt2"])
+def test_a_done_slots_row_changes_no_live_slots_tokens(kernel_toys,
+                                                       monkeypatch, family):
     """One request served alone, then beside two that finish long before
     it (their slots' rows are then attended over no position): the same
     tokens."""
-    model, variables = kernel_toy
+    model, variables = kernel_toys[family]
     long, *short = _requests(lengths=(9, 5, 7), budgets=(14, 2, 3))
     alone, _ = _served_by_the_kernel(monkeypatch, model, variables, [long])
     beside, _ = _served_by_the_kernel(monkeypatch, model, variables,
@@ -714,30 +752,54 @@ def test_a_done_slots_row_changes_no_live_slots_tokens(kernel_toy,
     np.testing.assert_array_equal(alone[long["i"]], beside[long["i"]])
 
 
-def test_self_attention_keeps_the_xla_read(monkeypatch):
-    """``TransformerLM`` takes the contract's ``lengths`` and does not
-    use it (PERF.md section 7): traced as on a TPU, with heads the
-    kernel's rule would take, its step programs hold no kernel and its
-    tokens are ``generate()``'s."""
-    spec = model_config("transformer_lm", (256,), input_dtype="int32",
-                        vocab_size=VOCAB, num_layers=1, d_model=1024,
-                        num_heads=8, max_len=256, dtype="float32")
-    model = ModelSpec.from_config(spec).build()
-    variables = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+def test_the_kernels_call_is_traced_once_a_program(monkeypatch):
+    """A trace of the kernel's call costs the host a tenth of a second
+    (0.1 s x 24 layers x 3 programs were 14 % of a serving cell's
+    set-up): the layers of a step program share one, and a program
+    traced later (another pool, another engine) gets its own, so that
+    each is recorded (``attended_rows > 0`` in both)."""
+    from distkeras_tpu.ops import attention
+
+    calls = []
+    real = attention.decode_attention
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(attention, "decode_attention", counted)
+    model, variables = _gpt2_toy()                  # two layers
+    for reqs in (_requests(lengths=(5,), budgets=(3,)),
+                 _requests(lengths=(5, 120), budgets=(3, 12))):
+        before = len(calls)
+        _, steps = _served_by_the_kernel(monkeypatch, model, variables,
+                                         reqs)
+        pools = {a["bucket"] for a in steps}
+        assert len(calls) - before == len(pools) == len(reqs)
+        assert all(a["attended_rows"] > 0 for a in steps)
+
+
+@pytest.mark.parametrize("refused", [
+    {"kv_cache_dtype": "int8"},                  # no floating cache
+    {"dtype": "bfloat16"},     # 8 cached heads: half a tile of sublanes
+    {"d_model": 512},                            # heads of 64
+], ids=["int8", "bf16_8_heads", "heads_of_64"])
+def test_self_attention_keeps_the_xla_read_where_the_rule_refuses(
+        monkeypatch, refused):
+    """What ``decode_attention_applies`` refuses stays XLA's read inside
+    the model too: traced as on a TPU, the step programs hold no kernel
+    and the tokens are ``generate()``'s."""
+    model, variables = _gpt2_toy(**refused)
     reqs = _requests(lengths=(5, 9), budgets=(3, 5))
     out, steps = _served_by_the_kernel(monkeypatch, model, variables, reqs)
-    for r in reqs:
-        want = np.asarray(generate(
-            model, variables, r["prompt"][None],
-            max_new_tokens=r["max_new_tokens"]))[0, len(r["prompt"]):]
-        np.testing.assert_array_equal(out[r["i"]], want)
+    _assert_generates_tokens(model, variables, reqs, out)
     assert steps and all(a["attended_rows"] == 0 for a in steps)
 
 
-def test_off_the_chip_the_xla_read_stays(kernel_toy):
+def test_off_the_chip_the_xla_read_stays(kernel_toys):
     """Nothing steered: the CPU's step programs hold no kernel and their
     spans say so."""
-    model, variables = kernel_toy
+    model, variables = kernel_toys["latent"]
     tel = telemetry.enable()
     try:
         eng = DecodeEngine(model, variables, slots=2, buckets=[128],
