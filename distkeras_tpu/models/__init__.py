@@ -23,6 +23,7 @@ from distkeras_tpu.models.lstm import BiLSTMClassifier  # noqa: F401
 from distkeras_tpu.models.widedeep import WideAndDeep  # noqa: F401
 from distkeras_tpu.models.transformer import TransformerLM  # noqa: F401
 from distkeras_tpu.models.latent_moe import LatentMoELM  # noqa: F401
+from distkeras_tpu.models.hybrid_moe import HybridMoELM  # noqa: F401
 from distkeras_tpu.models.generate import (  # noqa: F401
     beam_search,
     generate,

@@ -37,18 +37,27 @@ class RMSNorm(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    """``(silu(x W_gate) * (x W_up)) W_down``, no biases."""
+    """``(silu(x W_gate) * (x W_up)) W_down``, no biases.  A ``limit``
+    above 0 clamps as gpt-oss does: ``x W_gate`` at most ``limit``,
+    ``x W_up`` within ``+-limit``."""
 
     width: int
     dtype: jnp.dtype
+    limit: float = 0.0
 
     @nn.compact
     def __call__(self, x):
         d = x.shape[-1]
         dense = lambda n, name: nn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name)
-        h = nn.silu(dense(self.width, "gate")(x)) \
-            * dense(self.width, "up")(x)
+        if not self.limit:
+            h = nn.silu(dense(self.width, "gate")(x)) \
+                * dense(self.width, "up")(x)
+        else:
+            gate = jnp.minimum(dense(self.width, "gate")(x), self.limit)
+            up = jnp.clip(dense(self.width, "up")(x), -self.limit,
+                          self.limit)
+            h = nn.silu(gate) * up
         return dense(d, "down")(h)
 
 

@@ -261,8 +261,8 @@ def grouped_matmul(rows, weights, sizes, out_dtype):
 
 
 def sigmoid_topk(x, router, bias, top_k: int, *, normalize: bool = True,
-                 scale: float = 1.0):
-    """Bias-corrected sigmoid routing ("noaux_tc", one group).
+                 scale: float = 1.0, n_group: int = 1, topk_group: int = 1):
+    """Bias-corrected sigmoid routing ("noaux_tc").
 
     ``x`` ``[T, d]``, ``router`` ``[d, E]`` and ``bias`` ``[E]`` are
     taken in float32 and the product runs at full float32 precision,
@@ -270,11 +270,30 @@ def sigmoid_topk(x, router, bias, top_k: int, *, normalize: bool = True,
     ``top_k`` experts are chosen by ``sigmoid(x W) + bias``; a chosen
     expert's weight is its score WITHOUT the bias, divided by the sum
     of the chosen scores (``normalize``) and multiplied by ``scale``.
+    With ``n_group > 1`` the experts are ``n_group`` contiguous groups
+    (DeepSeek-V3's selection): a group scores by the sum of its two best
+    biased scores, the best ``topk_group`` groups are kept and the
+    ``top_k`` are chosen among their experts alone.
     Returns ``(idx [T, k] int32, weights [T, k] float32)``."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = choice.shape
+        groups = choice.reshape(t, n_group, e // n_group)
+        # the two best of each group by two reductions: a top_k of 2
+        # lowers to a sort of the whole group on a TPU
+        first = jnp.argmax(groups, axis=-1)[..., None]
+        second = jnp.where(jnp.arange(e // n_group) == first, -jnp.inf,
+                           groups).max(axis=-1)
+        group_score = groups.max(axis=-1) + second             # [T, G]
+        _, kept = lax.top_k(group_score, topk_group)
+        keep = jnp.zeros((t, n_group), bool).at[
+            jnp.arange(t)[:, None], kept].set(True)
+        choice = jnp.where(jnp.repeat(keep, e // n_group, axis=1), choice,
+                           -jnp.inf)
+    _, idx = lax.top_k(choice, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
         w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
@@ -286,9 +305,11 @@ def expert_load(idx, num_experts: int):
     return jnp.zeros((num_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
 
 
-def dropless_experts(x, idx, weights, w_in, w_out, *, first: int = 0):
+def dropless_experts(x, idx, weights, w_in, w_out, *, first: int = 0,
+                     limit: float = 0.0):
     """The held experts' part of a routed SwiGLU layer, every
-    assignment computed.
+    assignment computed.  A ``limit`` above 0 clamps as gpt-oss does:
+    the gate at most ``limit``, the up projection within ``+-limit``.
 
     ``x`` ``[T, d]``; ``idx``/``weights`` ``[T, k]`` from the router
     (over all experts); ``w_in`` ``[E_held, d, 2 h]`` (gate then up) and
@@ -307,7 +328,10 @@ def dropless_experts(x, idx, weights, w_in, w_out, *, first: int = 0):
         rows = jnp.take(x, order // k, axis=0)      # [T k, d]
     with jax.named_scope("moe_experts"):
         gu = grouped_matmul(rows, w_in, sizes, x.dtype)
-        act = (jax.nn.silu(gu[:, :h]) * gu[:, h:]).astype(x.dtype)
+        gate, up = gu[:, :h], gu[:, h:]
+        if limit:
+            gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+        act = (jax.nn.silu(gate) * up).astype(x.dtype)
         y = grouped_matmul(act, w_out, sizes, jnp.float32)
     with jax.named_scope("moe_combine"):
         # rows past the last group belong to no expert held here: what

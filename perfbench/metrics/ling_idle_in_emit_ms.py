@@ -1,0 +1,3 @@
+"""Reader of ``ling_idle_in_emit_ms``: see ``perfbench/layers_moe.py``."""
+
+from perfbench.layers_moe import idle_in_emit_ms as read  # noqa: F401

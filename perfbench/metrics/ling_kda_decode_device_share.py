@@ -1,0 +1,3 @@
+"""Reader of ``ling_kda_decode_device_share``: see ``perfbench/layers_kda.py``."""
+
+from perfbench.layers_kda import kda_decode_device_share as read  # noqa: F401

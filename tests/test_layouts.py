@@ -812,3 +812,72 @@ def test_off_the_chip_the_xla_read_stays(kernel_toys):
     assert steps and all(
         a["attended_rows"] == 0 and a["envelope_rows"] == 2 * 128
         for a in steps)
+
+
+# ---- the latent cell's programs under the options a later model added ---
+
+def _old_sigmoid_topk(x, router, bias, top_k, *, normalize=True, scale=1.0,
+                      n_group=1, topk_group=1):
+    """``parallel.moe.sigmoid_topk`` before grouped selection (which one
+    group asks for nothing of)."""
+    assert n_group == topk_group == 1
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+def _instructions(text):
+    """The HLO's instructions without their metadata (source lines and
+    stack frames, which name the functions that traced them) and without
+    the serialized bodies of the Mosaic kernels (which carry their own
+    source locations: two compiles of one program differ there)."""
+    return [re.sub(r', metadata=\{[^}]*\}|"body":"[^"]*"', "", line)
+            for line in text.splitlines() if " = " in line]
+
+
+def test_v5e_xing_step_is_the_program_it_was(one_chip, traced_for_the_chip,
+                                             monkeypatch):
+    """``xing-serve-backlog``'s step program at three of its layers,
+    compiled for the described chip, is the same HLO with one-group
+    selection as it was before grouped selection, the query's low rank,
+    the head gate and the SwiGLU clamp came in beside it."""
+    import json
+
+    from distkeras_tpu.parallel import moe
+    from perfbench.adapters import mla_moe_hc
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "perfbench",
+                           "configs", "xing4.0-29b-a4b-l6.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": 3}
+    dec = _decode_model(mla_moe_hc.program_model(cfg, 1024))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: SDS(x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip({"params": jax.eval_shape(
+        lambda: dec.clone(decode=False).init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))["params"]})
+    cache = on_chip(jax.eval_shape(
+        lambda v: dec.apply(v, jnp.zeros((16, 1), jnp.int32),
+                            mutable=["cache"]), params)[1]["cache"])
+    tok = on_chip(SDS((16,), jnp.int32))
+
+    def compiled():
+        def step(params, cache, tok, pos):
+            return decode_step(dec, params, cache, tok, slot_pos=pos,
+                               lengths=pos + 1, temperature=0.0,
+                               top_k=None, top_p=None, rng=None)
+
+        return _instructions(jax.jit(step, donate_argnums=1).lower(
+            params, cache, tok, tok).compile().as_text())
+
+    now = compiled()
+    assert len(now) > 1000
+    monkeypatch.setattr(moe, "sigmoid_topk", _old_sigmoid_topk)
+    assert now == compiled()
