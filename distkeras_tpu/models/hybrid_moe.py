@@ -97,9 +97,11 @@ class KimiDeltaAttention(nn.Module):
                 g = jnp.where(live[None, :, None, None], g, 0.0)
                 beta = jnp.where(live[None, :, None], beta, 0.0)
         if t == 1:
+            step = la.kda_step_kernel if la.kda_step_kernel_applies(
+                state0) else la.kda_step
             with jax.named_scope("kda_decode"):
-                state, o = la.kda_step(state0, q[:, 0], k[:, 0], v[:, 0],
-                                       g[:, 0], beta[:, 0])
+                state, o = step(state0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0])
                 o = o[:, None]
         else:
             with jax.named_scope("kda_prefill"):

@@ -10,8 +10,13 @@ Per head, with a state ``S`` ``[d_k, d_v]`` kept in float32::
 (0, 1).  Written as ``S_t = D_t S_{t-1} + k_t u_t^T`` with
 ``u_t = beta_t (v_t - (D_t S_{t-1})^T k_t)``.
 
-* ``kda_step``: one token (``T = 1``), the state read and written once.
-  What a decode step runs, under the scope ``kda_decode`` of the model.
+* ``kda_step``: one token (``T = 1``).  What a decode step runs off a
+  TPU, under the scope ``kda_decode`` of the model; XLA reads the state
+  twice and writes it once (the reduction ``k^T S`` cannot share a
+  fusion with the update that broadcasts it back over ``S``).
+* ``kda_step_kernel``: the same step as one Pallas TPU kernel that reads
+  and writes each row's state once, in place.  What a decode step runs
+  where ``kda_step_kernel_applies`` takes the state.
 * ``kda_chunked``: a prompt, in chunks of ``CHUNK`` tokens.  Inside a
   chunk the ``u`` of every token come from one unit-lower-triangular
   solve (the WY / UT form), in plain matmuls; the state is carried from
@@ -27,6 +32,8 @@ that is how a prompt's padding is kept out of it.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +104,106 @@ def kda_step(state, q, k, v, g, beta):
     kv = jnp.einsum("bhk,bhkv->bhv", k, s, precision=_HI)
     s = s + k[..., None] * (beta[..., None] * (v - kv))[..., None, :]
     return s, jnp.einsum("bhk,bhkv->bhv", q, s, precision=_HI)
+
+
+#: State a grid step of ``kda_step_kernel`` takes in at least: whole
+#: rows (every head of a row) until a block holds this much, so that the
+#: grid's fixed cost a step is paid a few hundred times a layer, not
+#: thousands.
+_STEP_BLOCK_BYTES = 1 << 20
+
+
+def kda_step_kernel_applies(state) -> bool:
+    """Whether a decode step should run ``kda_step_kernel`` on ``state``
+    ``[B, H, d_k, d_v]`` in place of ``kda_step``: on a TPU, a float32
+    state whose ``d_v`` fills whole rows of 128 lanes and whose ``d_k``
+    whole tiles of 8 sublanes."""
+    from distkeras_tpu.ops import attention
+
+    dk, dv = state.shape[-2:]
+    return (attention._on_tpu() and state.dtype == jnp.float32
+            and dv % 128 == 0 and dk % 8 == 0)
+
+
+def _step_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, s_out, o_ref,
+                 *, rows: int, heads: int):
+    """One block of ``rows`` rows x ``heads`` heads.  A head's state
+    ``[d_k, d_v]`` lies with ``d_k`` on the sublanes, so ``q``, ``k`` and
+    the decay act on it as columns: a row's ``[heads, d_k]`` of each is
+    turned into ``[d_k, heads]`` once, and both contractions are sums
+    over sublanes on the vector unit, in float32."""
+
+    def row(r, carry):
+        decay = jnp.exp(g_ref[r]).T             # [d_k, heads]
+        kc, qc = k_ref[r].T, q_ref[r].T
+        v, beta = v_ref[r], b_ref[r]            # [heads, d_v], [heads, 1]
+        o = []
+        for h in range(heads):
+            s = s_ref[r, h] * decay[:, h:h + 1]
+            kv = jnp.sum(kc[:, h:h + 1] * s, axis=0, keepdims=True)
+            s = s + kc[:, h:h + 1] * (beta[h:h + 1] * (v[h:h + 1] - kv))
+            s_out[r, h] = s
+            o.append(jnp.sum(qc[:, h:h + 1] * s, axis=0, keepdims=True))
+        o_ref[r] = jnp.concatenate(o, axis=0)
+        return carry
+
+    lax.fori_loop(0, rows, row, 0)
+
+
+def _step_block(b: int, h: int, dk: int, dv: int) -> tuple[int, int]:
+    """``(rows, heads)`` of a block of ``kda_step_kernel``: every head,
+    and whole rows up to ``_STEP_BLOCK_BYTES``."""
+    row = h * dk * dv * 4
+    return max(1, min(b, _STEP_BLOCK_BYTES // row)), h
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "heads", "interpret"))
+def _step_call(state, q, k, v, g, beta, *, rows, heads, interpret):
+    # one jit for every call of one shape: the kernel is traced and
+    # lowered once a process, not once a layer of every program
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, dk, dv = state.shape
+    vec = lambda w: pl.BlockSpec(  # noqa: E731
+        (rows, heads, w), lambda i, j: (i, j, 0))
+    whole = pl.BlockSpec((rows, heads, dk, dv), lambda i, j: (i, j, 0, 0))
+    block = rows * heads * dk * dv * 4
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        # the state in and out, two buffers each, and room for the body
+        vmem_limit_bytes=4 * block + (16 << 20))
+    return pl.pallas_call(
+        functools.partial(_step_kernel, rows=rows, heads=heads),
+        grid=(pl.cdiv(b, rows), h // heads),
+        in_specs=[vec(dk), vec(dk), vec(dv), vec(dk), vec(1), whole],
+        out_specs=[whole, vec(dv)],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((b, h, dv), jnp.float32)],
+        input_output_aliases={5: 0},
+        compiler_params=params,
+        interpret=interpret,
+        name="kda_step",
+    )(q, k, v, g, beta[..., None], state)
+
+
+def kda_step_kernel(state, q, k, v, g, beta, *, rows: int | None = None,
+                    heads: int | None = None,
+                    interpret: bool | None = None):
+    """``kda_step`` as one Pallas TPU kernel: the same arguments (all
+    float32) and results, each row's state read once and written once,
+    into its own buffer (a donated state is updated in place).  A grid
+    over blocks of ``rows`` rows x ``heads`` heads (``heads`` divides
+    ``H``; by default ``_step_block``'s); the decay, both contractions
+    and the update are float32 on the vector unit.  ``interpret``: the
+    Pallas interpreter, by default off a TPU."""
+    b, h, dk, dv = state.shape
+    if rows is None:
+        rows, heads = _step_block(b, h, dk, dv)
+    if interpret is None:
+        interpret = jax.devices()[0].platform != "tpu"
+    return _step_call(state, q, k, v, g, beta, rows=rows,
+                      heads=heads or h, interpret=interpret)
 
 
 def kda_chunked(q, k, v, g, beta, state):

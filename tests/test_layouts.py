@@ -881,3 +881,46 @@ def test_v5e_xing_step_is_the_program_it_was(one_chip, traced_for_the_chip,
     assert len(now) > 1000
     monkeypatch.setattr(moe, "sigmoid_topk", _old_sigmoid_topk)
     assert now == compiled()
+
+
+# ---- the KDA step kernel in the hybrid model's step ---------------------
+
+def test_v5e_kda_step_updates_each_state_in_place_through_the_kernel(
+        one_chip, traced_for_the_chip):
+    """Two KDA layers of ``ling-serve-backlog``'s configuration (32 heads of
+    128) and a pool of 16 slots, compiled for the described chip: one
+    Mosaic call a layer under ``kda_decode``, the scope the benchmark
+    finds its time by; no fusion that reads a ``[B, H, 128, 128]`` state
+    (XLA's step had two a layer) and no copy of a state leaf: the kernel
+    writes each donated state where it lies."""
+    import json
+
+    from distkeras_tpu.models.generate import recurrent_leaves
+    from perfbench.adapters import kda_mla_moe
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "perfbench",
+                           "configs", "ling-3.0-flash-vl-l7-e64.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": 2}
+    dec = _decode_model(kda_mla_moe.program_model(cfg, 1024))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: SDS(x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip({"params": jax.eval_shape(
+        lambda: dec.clone(decode=False).init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))["params"]})
+    cache = on_chip(jax.eval_shape(
+        lambda v: dec.apply(v, jnp.zeros((16, 1), jnp.int32),
+                            mutable=["cache"]), params)[1]["cache"])
+    states = [leaf for path, leaf in recurrent_leaves(cache).items()
+              if path.endswith("recurrent_state")]
+    assert [s.shape for s in states] == [(16, 32, 128, 128)] * 2
+    text = _kernel_step(dec, params, cache, on_chip, 16)
+    calls = [tail for line, tail in _under_scope(text, "kda_decode")
+             if "custom-call(" in line]
+    assert len(calls) == 2, calls
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert _computations_over(text, states[0]) == []
+    assert layouts.whole_leaf_copies(text, states) == 0
+    assert layouts.whole_leaf_copies(text, cache) == 0

@@ -609,9 +609,11 @@ def test_one_traced_step_yields_the_span_tree(tmp_path):
     (decode,) = prof.named("decode_step")
     assert prof.parent(decode) is root
     # the CPU's step holds XLA's read of the whole envelope: no row
-    # counted as the decode kernel's, 3 slots x 16 x 2 sub-steps held
+    # counted as the decode kernel's, 3 slots x 16 x 2 sub-steps held;
+    # no KDA layer, so no state row updated by the KDA step kernel
     assert decode["stats"] == {"bucket": 16, "steps": 2, "live": 3,
-                               "attended_rows": 0, "envelope_rows": 96}
+                               "attended_rows": 0, "envelope_rows": 96,
+                               "kda_kernel_rows": 0}
     (dispatch,) = prof.named("decode_dispatch")
     (fetch,) = prof.named("decode_fetch")
     assert prof.parent(dispatch) is decode and prof.parent(fetch) is decode
