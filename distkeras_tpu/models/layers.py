@@ -1,6 +1,7 @@
 """Pieces of the modern decoder block that more than one model family
 can use: RMSNorm, the rotary position table (plain or YaRN-scaled) with
-its interleaved-pair rotation, and the SwiGLU feed-forward.
+its rotation of interleaved pairs or of the two halves, and the SwiGLU
+feed-forward.
 
 ``TransformerLM`` (GPT-2 block: LayerNorm, learned positions, GELU) uses
 none of them; ``latent_moe.LatentMoELM`` uses all three.
@@ -109,3 +110,15 @@ def apply_rotary_interleaved(x, cos, sin):
     out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
                     axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def apply_rotary_half(x, cos, sin):
+    """Rotate the channel pairs ``(x[i], x[i + D / 2])`` of the last axis
+    by the angle of pair ``i`` (the "rotate half" convention).  ``cos``/
+    ``sin`` broadcast against ``x[..., :D / 2]``.  Float32 inside,
+    ``x.dtype`` out."""
+    xf = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)

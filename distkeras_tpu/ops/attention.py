@@ -37,6 +37,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -46,6 +47,9 @@ _NEG = -1e30
 # Measured v5e optimum of the round-4 sweep at T=2048 (PERF.md §17).
 _DEFAULT_BLOCK_Q = 512
 _DEFAULT_BLOCK_K = 1024
+# A windowed forward's blocks: a band of 128 keys then spans two key
+# blocks a q block, half of them live
+_WINDOW_BLOCK = 128
 
 # 3 parallel grid dims (batch, head, q block) + 1 sequential reduction
 # dim (k or q block stream) that the VMEM accumulators persist across.
@@ -106,6 +110,72 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             preferred_element_type=jnp.float32)            # [bq, D]
 
     @pl.when(j == _j_last(i, bq, bk, n_k, causal))
+    def _():
+        l = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_scr[:] + jnp.log(l)).astype(jnp.float32)
+
+
+def _band(i, bq, bk, n_k, window):
+    """``(first, last)`` key block that the i-th q block needs under a
+    causal band of ``window`` keys (query i sees keys ``i - window < j <=
+    i``); ``i`` an int, an int array or a grid index."""
+    xp = np if isinstance(i, np.ndarray) else jnp   # shapes, or the grid
+    lo = xp.maximum(i * bq - window + 1, 0) // bk
+    hi = xp.minimum((i * bq + bq - 1) // bk, n_k - 1)
+    return lo.astype(xp.int32), hi.astype(xp.int32)
+
+
+def band_blocks(t: int, bq: int, bk: int, window: int) -> int:
+    """Key blocks the band holds over every q block of a length-``t``
+    sequence, for one (row, head): the blocks the windowed forward
+    kernel computes."""
+    lo, hi = _band(np.arange(t // bq), bq, bk, t // bk, window)
+    return int(np.sum(hi - lo + 1))
+
+
+def band_width(t: int, bq: int, bk: int, window: int) -> int:
+    """The most key blocks the band holds for one q block: the windowed
+    grid's last axis."""
+    lo, hi = _band(np.arange(t // bq), bq, bk, t // bk, window)
+    return int(np.max(hi - lo + 1))
+
+
+def _band_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                     acc_scr, *, scale, window, n_k, n_band):
+    """``_fwd_kernel`` over the band alone: grid step ``j`` of q block
+    ``i`` is key block ``first + j`` of the band (``_band``), the steps
+    past its last block compute nothing, and the band's edges are masked.
+    A row whose keys all lie in a later block of the band takes the
+    masked logits of an earlier one first; the correction ``exp(m_prev -
+    m)`` of its first true key wipes them."""
+    i, j = pl.program_id(2), pl.program_id(3)
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    lo, hi = _band(i, bq, bk, n_k, window)
+    kb = lo + j
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[:] = jnp.full_like(m_scr, _NEG)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kb <= hi)
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [bq, bk]
+        rows = i * bq + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = kb * bk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(jnp.logical_and(rows >= cols, rows - cols < window),
+                      s, _NEG)
+        m, l, alpha, p = _softmax_step(s, m_scr[:], l_scr[:], 1)
+        acc_scr[:] = alpha * acc_scr[:] + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bq, Dv]
+        m_scr[:], l_scr[:] = m, l
+
+    @pl.when(j == n_band - 1)
     def _():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -244,9 +314,14 @@ def _qblk(bq, d):
                         memory_space=pltpu.VMEM)
 
 
-def _kblk(bk, d):
-    """BlockSpec for a per-(b, h, j) k-shaped operand on [B, H, T, D]."""
-    return pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0),
+def _kblk(bk, d, g=1):
+    """BlockSpec for a per-(b, h, j) k-shaped operand on [B, H, T, D];
+    ``g`` > 1: the operand has H / g heads, query head h reading head
+    h // g (grouped-query attention)."""
+    if g == 1:
+        return pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0),
+                            memory_space=pltpu.VMEM)
+    return pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h // g, j, 0),
                         memory_space=pltpu.VMEM)
 
 
@@ -256,16 +331,37 @@ def _rowblk(bq):
                         memory_space=pltpu.VMEM)
 
 
-def _fwd_call(q, k, v, scale, causal, bq, bk, interpret):
+def _fwd_call(q, k, v, scale, causal, bq, bk, interpret, window=None):
     b, h, t, d = q.shape
     dv = v.shape[-1]    # values (and the output) may be narrower than q/k
+    g = h // k.shape[1]     # query heads a key head
     n_q, n_k = t // bq, t // bk
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               n_k=n_k)
+    if window is None:
+        kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                                   n_k=n_k)
+        grid = (b, h, n_q, n_k)
+        kv_specs = [_kblk(bk, d, g), _kblk(bk, dv, g)]
+    else:
+        n_band = band_width(t, bq, bk, window)
+        kernel = functools.partial(_band_fwd_kernel, scale=scale,
+                                   window=window, n_k=n_k, n_band=n_band)
+        grid = (b, h, n_q, n_band)
+
+        def band_blk(width):
+            # past the band's last block a step names that block again,
+            # which is in VMEM already: no copy is issued for it
+            def index(b_, h_, i, j):
+                lo, hi = _band(i, bq, bk, n_k, window)
+                return (b_, h_ // g, jnp.minimum(lo + j, hi), 0)
+
+            return pl.BlockSpec((1, 1, bk, width), index,
+                                memory_space=pltpu.VMEM)
+
+        kv_specs = [band_blk(d), band_blk(dv)]
     return pl.pallas_call(
         kernel,
-        grid=(b, h, n_q, n_k),
-        in_specs=[_qblk(bq, d), _kblk(bk, d), _kblk(bk, dv)],
+        grid=grid,
+        in_specs=[_qblk(bq, d)] + kv_specs,
         out_specs=[_qblk(bq, dv), _rowblk(bq)],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
@@ -348,13 +444,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     scale: float | None = None, causal: bool = True,
                     block_q: int | None = None,
                     block_k: int | None = None,
-                    interpret: bool | None = None) -> jax.Array:
+                    interpret: bool | None = None,
+                    window: int | None = None) -> jax.Array:
     """Pallas-kernel attention: ``[B, T, H, D] -> [B, T, H, D]``.
     ``v`` may have a head size of its own (``[B, T, H, Dv]``, as the
     expanded form of latent attention has: 192 for q/k, 128 for v); the
     result is then ``[B, T, H, Dv]`` and comes from the forward kernel
     alone, which computes nothing it does not need: that spelling
-    serves (prefill) and has no backward pass.
+    serves (prefill) and has no backward pass.  So do two more: ``k``
+    and ``v`` with fewer heads than ``q`` (``KVH`` dividing ``H``: query
+    head h reads key head ``h // (H / KVH)``, grouped-query attention,
+    with no repeated copy), and a ``window`` (causal only): query i
+    attends to keys ``i - window < j <= i``, and the grid walks only the
+    key blocks of that band, so that blocks wholly outside it are
+    neither computed nor copied in; the band's edge blocks are masked.
+    Its blocks default to 128 positions (a divisor of T no larger).
 
     Same contract as ``models.transformer.dense_causal_attention`` and
     ``parallel.ring_attention.blockwise_attention``; differentiable via
@@ -370,17 +474,36 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         interpret = jax.devices()[0].platform != "tpu"
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    bq, bk = _blocks(q.shape[1], block_q, block_k)
+    t, h, kvh = q.shape[1], q.shape[2], k.shape[2]
+    if h % kvh or v.shape[2] != kvh:
+        raise ValueError(f"{h} query heads cannot share {kvh} key heads "
+                         f"({v.shape[2]} value heads)")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window} needs causal attention "
+                             "and at least one key")
+        block_q = _auto_block(t, _WINDOW_BLOCK) if block_q is None \
+            else block_q
+        block_k = _auto_block(t, _WINDOW_BLOCK) if block_k is None \
+            else block_k
+    bq, bk = _blocks(t, block_q, block_k)
     if not interpret:
-        _check_mosaic_alignment(bq, bk, q.shape[1], q.shape[1])
+        _check_mosaic_alignment(bq, bk, t, t)
     # [B, T, H, D] -> [B, H, T, D]: one transpose each way per pass —
     # negligible (O(T)) next to attention's O(T^2), and it gives the
     # kernels their natural (rows = time, lanes = head_dim) layout.
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    call = _flash_bhtd if v.shape[-1] == q.shape[-1] else \
-        (lambda *a: _fwd_call(*a)[0])
-    out = call(qt, kt, vt, float(scale), bool(causal), bq, bk,
-               bool(interpret))
+    if window is None and kvh == h and v.shape[-1] == q.shape[-1]:
+        out = _flash_bhtd(qt, kt, vt, float(scale), bool(causal), bq, bk,
+                          bool(interpret))
+    else:
+        if window is not None:
+            recorded = getattr(_recording, "band", None)
+            if recorded is not None:
+                recorded.append(q.shape[0] * h
+                                * band_blocks(t, bq, bk, window))
+        out = _fwd_call(qt, kt, vt, float(scale), bool(causal), bq, bk,
+                        bool(interpret), window)[0]
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -685,17 +808,29 @@ _recording = threading.local()
 
 
 @contextlib.contextmanager
+def _recording_into(name):
+    prev = getattr(_recording, name, None)
+    setattr(_recording, name, [])
+    try:
+        yield getattr(_recording, name)
+    finally:
+        setattr(_recording, name, prev)
+
+
 def recording_decode_blocks():
     """Collects, while a program is traced inside it (this thread), the
     cache block of every ``decode_attention`` call: the host's way to
     know whether the kernel is in a program it traced, and in what
     blocks it reads."""
-    prev = getattr(_recording, "blocks", None)
-    _recording.blocks = blocks = []
-    try:
-        yield blocks
-    finally:
-        _recording.blocks = prev
+    return _recording_into("blocks")
+
+
+def recording_band_blocks():
+    """Collects, while a program is traced inside it (this thread), the
+    key blocks that each windowed ``flash_attention`` call computes
+    (rows x heads x ``band_blocks``): the host's count of the band's
+    work in a program, from shapes alone."""
+    return _recording_into("band")
 
 
 def decode_block(cache_len: int, row_bytes: int) -> int | None:
@@ -723,8 +858,19 @@ def decode_attention_applies(q, k, v, lengths, *, v_width=None) -> bool:
     leaf) a multiple of 128 wide, whose default layout is row-major, an
     envelope of whole 128-row blocks, and cached heads that fill whole
     tiles of sublanes (16 in bfloat16) or are one shared leaf, so that
-    ``[L, KVH, D]`` is ``[L * KVH, D]`` without a byte moved."""
+    ``[L, KVH, D]`` is ``[L * KVH, D]`` without a byte moved.  A cache
+    whose heads lie side by side on the lanes (``[B, L, KVH * D]``, keys
+    and values) is taken at any ``KVH``: the kernel reads each head's
+    columns where they lie."""
     kvh = q.shape[1]
+    if v is not None and k.ndim == 3:      # the lane-dense grouped leaf
+        return (lengths is not None
+                and _on_tpu()
+                and k.dtype == q.dtype == v.dtype
+                and jnp.issubdtype(k.dtype, jnp.floating)
+                and q.shape[-1] % 128 == 0
+                and k.shape[-1] == v.shape[-1] == kvh * q.shape[-1]
+                and k.shape[1] % 128 == 0)
     dv = v_width if v is None else v.shape[-1]
     tile = 32 // k.dtype.itemsize
     return (lengths is not None
@@ -816,6 +962,60 @@ def _decode_kernel(len_ref, row_ref, blk_ref, q_ref, k_ref, *rest,
             l > 0.0, l, 1.0), 0.0).astype(o_ref.dtype)
 
 
+def _decode_lane_kernel(len_ref, row_ref, blk_ref, q_ref, k_ref, v_ref,
+                        o_ref, m_scr, l_scr, acc_scr, *, scale, bk, kvh, d):
+    """``_decode_kernel`` for a cache whose heads lie side by side on the
+    lanes: the block is ``[bk, KVH * D]`` of keys and of values, cached
+    head ``c`` their columns ``c D .. (c + 1) D`` (a slice of whole lane
+    tiles); the row's queries ``[KVH, G, D]``, group ``c`` against head
+    ``c`` alone, so that no product is wasted."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    n_keys = len_ref[b]                      # live positions of the row
+    first = j * bk                           # this block's first position
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def block(partial):
+        k, v = k_ref[0], v_ref[0]               # [bk, KVH * D]
+        if partial:
+            # past the row's length: masked out of the logits, zeroed in
+            # the values (a probability of 0 times a NaN is a NaN)
+            live = first + lax.broadcasted_iota(jnp.int32, (bk, 1), 0) \
+                < n_keys
+            v = jnp.where(live, v, jnp.zeros_like(v))
+        heads = [slice(c * d, (c + 1) * d) for c in range(kvh)]
+        s = jnp.concatenate([
+            lax.dot_general(q_ref[0, c], k[:, cols],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+            for c, cols in enumerate(heads)], axis=0) * scale  # [H, bk]
+        if partial:
+            s = jnp.where(first + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                          < n_keys, s, _NEG)
+        m, l, alpha, p = _softmax_step(s, m_scr[...], l_scr[...], 1)
+        g = p.shape[0] // kvh
+        acc_scr[...] = alpha * acc_scr[...] + jnp.concatenate([
+            jnp.dot(p[c * g:(c + 1) * g].astype(v.dtype), v[:, cols],
+                    preferred_element_type=jnp.float32)
+            for c, cols in enumerate(heads)], axis=0)        # [H, D]
+        m_scr[...], l_scr[...] = m, l
+
+    last = first + bk
+    pl.when(last <= n_keys)(lambda: block(False))
+    pl.when(jnp.logical_and(first < n_keys,
+                            last > n_keys))(lambda: block(True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_scr[...]
+        o_ref[0] = jnp.where(l > 0.0, acc_scr[...] / jnp.where(
+            l > 0.0, l, 1.0), 0.0).astype(o_ref.dtype)
+
+
 def _fetch_schedule(lengths, n_blocks: int, bk: int):
     """For every grid step (row-major over rows x blocks) the (row,
     block) whose cache block it wants in VMEM: its own where the block
@@ -841,6 +1041,8 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array | None,
     -> ``[B, KVH, G, Dv]``.  ``v=None``: the values are the keys' first
     ``v_width`` columns (a latent leaf ``[c_kv | k_rope | pad]`` whose
     value is ``c_kv``), and the leaf is read once for both products.
+    ``k`` and ``v`` may also be lane-dense, ``[B, L, KVH * D]``, cached
+    head ``c`` in columns ``c D .. (c + 1) D`` (``_decode_lane_kernel``).
 
     A Pallas TPU kernel over (row, cache block of ``block`` positions,
     by default ``decode_block``'s).  Blocks at or beyond a row's length
@@ -852,7 +1054,8 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array | None,
     ``interpret`` as in ``flash_attention``."""
     bsz, kvh, g, dk = q.shape
     length = k.shape[1]
-    dv = v_width if v is None else v.shape[-1]
+    lane_dense = v is not None and k.ndim == 3
+    dv = v_width if v is None else v.shape[-1] // (kvh if lane_dense else 1)
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
     row_bytes = (dk + (0 if v is None else dv)) * kvh * k.dtype.itemsize
@@ -869,26 +1072,34 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array | None,
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, length)
     rows, blks = _fetch_schedule(lengths, n_blocks, bk)
 
-    def cached(width):
+    def cached(positions, width):
         # the block a step wants, by the schedule
         return pl.BlockSpec(
-            (1, bk * kvh, width),
+            (1, positions, width),
             lambda b, j, ln, r, c: (r[b * n_blocks + j],
                                     c[b * n_blocks + j], 0))
 
     def per_row(width):
         return pl.BlockSpec((1, h, width), lambda b, j, ln, r, c: (b, 0, 0))
 
-    # the cache as keys of one width, position-major: the last two axes
-    # of [L, KVH, D] folded, which moves no byte where KVH fills whole
-    # tiles of sublanes (or is 1: a latent leaf)
-    operands = [q.reshape(bsz, h, dk), k.reshape(bsz, length * kvh, dk)]
-    in_specs = [per_row(dk), cached(dk)]
-    if v is not None:
-        operands.append(v.reshape(bsz, length * kvh, dv))
-        in_specs.append(cached(dv))
-    kernel = functools.partial(_decode_kernel, scale=scale, bk=bk, kvh=kvh,
-                               g=g, dv=dv)
+    if lane_dense:
+        operands = [q, k, v]
+        in_specs = [pl.BlockSpec((1, kvh, g, dk),
+                                 lambda b, j, ln, r, c: (b, 0, 0, 0)),
+                    cached(bk, kvh * dk), cached(bk, kvh * dv)]
+        kernel = functools.partial(_decode_lane_kernel, scale=scale, bk=bk,
+                                   kvh=kvh, d=dk)
+    else:
+        # the cache as keys of one width, position-major: the last two
+        # axes of [L, KVH, D] folded, which moves no byte where KVH fills
+        # whole tiles of sublanes (or is 1: a latent leaf)
+        operands = [q.reshape(bsz, h, dk), k.reshape(bsz, length * kvh, dk)]
+        in_specs = [per_row(dk), cached(bk * kvh, dk)]
+        if v is not None:
+            operands.append(v.reshape(bsz, length * kvh, dv))
+            in_specs.append(cached(bk * kvh, dv))
+        kernel = functools.partial(_decode_kernel, scale=scale, bk=bk,
+                                   kvh=kvh, g=g, dv=dv)
     params = None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"),
         # two buffers a cached operand, and room for the body's own

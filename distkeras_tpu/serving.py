@@ -131,7 +131,8 @@ from distkeras_tpu.analysis import racecheck
 from distkeras_tpu.models.generate import (RECURRENT_PREFIX,
                                            _decode_model, _select,
                                            decode_step, recurrent_leaves,
-                                           stacked_expert_load)
+                                           stacked_expert_load,
+                                           window_leaves)
 from distkeras_tpu.ops import linear_attention
 from distkeras_tpu.parallel import transport
 
@@ -412,7 +413,8 @@ class _Pool:
     __slots__ = ("env", "n_slots", "dec", "cache", "state", "reqs",
                  "step_fn", "prefill_fn", "queue", "chunk_fn",
                  "copy_fn", "extract_fn", "prefilling", "cache_tmpl",
-                 "table", "table_np", "spec", "read_block", "kda_rows")
+                 "table", "table_np", "spec", "read_block", "kda_rows",
+                 "window", "band_blocks")
 
     def __init__(self, env, n_slots, dec):
         self.env = env
@@ -426,6 +428,12 @@ class _Pool:
         # through ops.linear_attention.kda_step_kernel (every row, dead
         # ones too); 0: the jnp step, or no such layer
         self.kda_rows = 0
+        # (ring rows W, window layers) of the cache's window leaves;
+        # (0, 0): no window layer
+        self.window = (0, 0)
+        # padded prompt length -> key blocks that the windowed prefill
+        # kernel computes in that prefill program (known once traced)
+        self.band_blocks = {}
         self.reqs: list[Optional[_Request]] = [None] * n_slots
         self.queue: collections.deque[_Request] = collections.deque()
         # slot -> pending chunk-prefill plan (insertion order = the
@@ -757,7 +765,12 @@ class DecodeEngine:
             {"params": self.variables["params"]})[1]["cache"]
         pool.cache_tmpl = shapes
         leaves = recurrent_leaves(shapes)
-        recurrent = list(leaves)
+        windows = window_leaves(shapes)
+        rings = [leaf for path, leaf in windows.items()
+                 if path.endswith("key")]
+        pool.window = (max((r.shape[1] for r in rings), default=0),
+                       len(rings))
+        positionless = list(leaves) + list(windows)
         pool.kda_rows = sum(
             leaf.shape[0] for path, leaf in leaves.items()
             if path.endswith(RECURRENT_PREFIX + "state")
@@ -767,12 +780,13 @@ class DecodeEngine:
             ("prefix_cache_bytes", self._prefix is not None),
             ("prefill_chunk", self.prefill_chunk is not None),
             ("speculative", self._spec is not None)) if on]
-        if recurrent and arms:
+        if positionless and arms:
             raise ValueError(
                 f"{', '.join(arms)} cannot serve this model: its cache "
-                f"leaves {recurrent} are recurrent states with no "
-                "position axis, which cannot be paged, rewound or shared "
-                "a block at a time (models.generate.DECODE_CONTRACT)")
+                f"leaves {positionless} are recurrent states or rings of a "
+                "window's last positions, with no position axis, which "
+                "cannot be paged, rewound or shared a block at a time "
+                "(models.generate.DECODE_CONTRACT)")
         if self._paged:
             # no per-bucket envelope pool: slots read/write the shared
             # page pool through their table rows (all entries start at
@@ -917,10 +931,16 @@ class DecodeEngine:
 
         def prefill_core(variables, cache, state, prompt, slot,
                          last_idx, n_left0, eos_id, rng):
+            from distkeras_tpu.ops import attention
+
             params = {"params": variables["params"]}
-            logits, st = dec.apply(params, prompt,
-                                   mutable=["cache", "expert_load"],
-                                   last_index=last_idx)
+            with attention.recording_band_blocks() as band:
+                logits, st = dec.apply(params, prompt,
+                                       mutable=["cache", "expert_load"],
+                                       last_index=last_idx)
+            # trace time: the windowed kernel's blocks in this program,
+            # for the host's count on its span (_prefill_whole)
+            pool.band_blocks[prompt.shape[1]] = sum(band)
             tok0 = _select(logits[:, -1].astype(jnp.float32), temp,
                            top_k, top_p, rng)[0]
 
@@ -1928,6 +1948,9 @@ class DecodeEngine:
                     tok0, load = jax.device_get((tok0, load))
                     tok0 = int(tok0)
                 self._note_expert_load(sp, load)
+                if pool.window[1]:
+                    sp.set_metadata(
+                        band_blocks=pool.band_blocks.get(t_pad, 0))
                 req.tokens.append(tok0)
                 req.t_tokens.append(telemetry.now())
         except Exception as e:
@@ -2900,8 +2923,12 @@ class DecodeEngine:
         host's mirror of the device's positions, nothing fetched.  And
         ``kda_kernel_rows``: rows x KDA layers x sub-steps whose state
         ``kda_step_kernel`` updated (0 where the ``jnp`` step ran), from
-        the pool's state leaves and the kernel's rule."""
-        attended, block = 0, pool.read_block
+        the pool's state leaves and the kernel's rule; for a model with
+        window layers ``window_rows``: the ring positions they read
+        through the kernel, each live slot's ``min(length, W)`` at each
+        sub-step, times the window layers (0 where XLA's read ran)."""
+        attended = ring = 0
+        block, (w, rings) = pool.read_block, pool.window
         if block:
             for slot, req in enumerate(pool.reqs):
                 if req is None or slot in pool.prefilling:
@@ -2911,10 +2938,13 @@ class DecodeEngine:
                                    req.max_new - len(req.tokens))):
                     attended += -(-min(pool.env, pos + k + 1)
                                   // block) * block
+                    ring += min(w, pos + k + 1)
         span.set_metadata(
             attended_rows=attended,
             envelope_rows=pool.n_slots * pool.env * self.steps_per_sync,
             kda_kernel_rows=pool.kda_rows * self.steps_per_sync)
+        if rings:
+            span.set_metadata(window_rows=ring * rings)
 
     def expert_load(self) -> Optional[np.ndarray]:
         """``[expert layers, experts held]``: the rows that the one-shot
@@ -2939,7 +2969,8 @@ class DecodeEngine:
         """One record an envelope pool, for an operator or a smoke
         check: its envelope, the layout its K/V leaves live in, the
         bytes of its recurrent-state leaves (``recurrent_bytes``; 0 for
-        a model whose cache is all positions), and for
+        a model whose cache is all positions) and of its window rings
+        (``window_bytes``; 0 for a model without window layers), and for
         its step program and each one-shot prefill program traced so
         far how many operations of the compiled HLO copy a whole leaf
         of the pool: ``relayouts`` (a layout change or a second
@@ -2979,5 +3010,8 @@ class DecodeEngine:
                            "recurrent_bytes": sum(
                                int(leaf.nbytes) for leaf in
                                recurrent_leaves(pool.cache).values()),
+                           "window_bytes": sum(
+                               int(leaf.nbytes) for leaf in
+                               window_leaves(pool.cache).values()),
                            "programs": programs})
         return report

@@ -924,3 +924,188 @@ def test_v5e_kda_step_updates_each_state_in_place_through_the_kernel(
     assert _computations_over(text, states[0]) == []
     assert layouts.whole_leaf_copies(text, states) == 0
     assert layouts.whole_leaf_copies(text, cache) == 0
+
+
+# ---- the window model's programs, and the programs it left alone ----------
+
+def _on_chip_shapes(dec, one_chip, slots):
+    """``(params, cache, on_chip)`` of a decode model as shapes on the
+    described chip, its cache a pool of ``slots``."""
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: SDS(x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip({"params": jax.eval_shape(
+        lambda: dec.clone(decode=False, cache_envelope=None).init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))["params"]})
+    cache = on_chip(jax.eval_shape(
+        lambda v: dec.apply(v, jnp.zeros((slots, 1), jnp.int32),
+                            mutable=["cache"]), params)[1]["cache"])
+    return params, cache, on_chip
+
+
+def _prefill_text(dec, params, cache, on_chip, t):
+    """The engine's one-shot prefill of a ``t``-token prompt into slot
+    ``slot`` of the pool (``serving.DecodeEngine._make_prefill``), compiled
+    for the described chip."""
+    def prefill(params, cache, prompt, slot, last):
+        logits, st = dec.apply(params, prompt, mutable=["cache"],
+                               last_index=last)
+        return logits, jax.tree_util.tree_map(
+            lambda c, n: c if not n.shape else
+            jax.lax.dynamic_update_slice(
+                c, n, (slot,) + (0,) * (n.ndim - 1)),
+            cache, st["cache"])
+
+    scalar = on_chip(SDS((), jnp.int32))
+    return jax.jit(prefill, donate_argnums=1).lower(
+        params, cache, on_chip(SDS((1, t), jnp.int32)), scalar,
+        scalar).compile().as_text()
+
+
+def _config(name, **kw):
+    import json
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "perfbench",
+                           "configs", f"{name}.json")) as f:
+        return {**json.load(f), **kw}
+
+
+@pytest.fixture(scope="module")
+def v5e_window_pool(one_chip):
+    """Layers 2 and 3 of ``exa-serve-backlog``'s configuration (a window
+    layer and the full layer, 64 heads sharing 8 of 128, each over 8 of
+    128 experts) and a pool of the cell's 288 slots, over 1024 positions,
+    as shapes on the described chip: ``(dec, params, cache, on_chip)``."""
+    from perfbench.adapters import gqa_swa_moe
+
+    cfg = _config("k-exaone-236b-a23b-l5-e8")
+    cfg = {**cfg, "num_hidden_layers": 2,
+           **{k: cfg[k][2:4] for k in ("layer_types", "sliding_windows",
+                                       "mlp_layer_types")}}
+    dec = _decode_model(gqa_swa_moe.program_model(cfg, 1024)).clone(
+        cache_envelope=1024)
+    return (dec,) + _on_chip_shapes(dec, one_chip, 288)
+
+
+def test_v5e_window_cache_holds_the_algorithms_bytes(v5e_window_pool):
+    """The full layer's keys and values lane-dense, ``[B, L, 8 x 128]``,
+    the window layer's a ring of 128: as ``[B, L, 8, 128]`` a v5e pads
+    eight bfloat16 heads to sixteen sublanes, twice the bytes."""
+    from distkeras_tpu.models.generate import window_leaves
+
+    _, _, cache, _ = v5e_window_pool
+    rings = window_leaves(cache)
+    assert {r.shape for r in rings.values()} == {(288, 128, 1024)}
+    full = cache["Layer_1_attn"]
+    assert full["cached_key"].shape == full["cached_value"].shape == \
+        (288, 1024, 1024)
+    nbytes = sum(int(np.prod(l.shape)) * l.dtype.itemsize
+                 for l in _kv_leaves(cache))
+    assert nbytes == 288 * 2 * 2 * (1024 + 128) * 8 * 128
+
+
+def test_v5e_window_step_reads_both_caches_through_the_kernel(
+        v5e_window_pool, traced_for_the_chip):
+    """The slot-mode step as ``step_core`` calls it: one kernel call
+    under ``swa_decode`` (the ring) and one under ``gqa_decode`` (the full
+    layer), the scopes the benchmark finds their time by; no contraction
+    over a cache leaf outside the kernels and no copy of any leaf: each
+    donated leaf is written where it lies."""
+    dec, params, cache, on_chip = v5e_window_pool
+    text = _kernel_step(dec, params, cache, on_chip, 288)
+    for scope in ("swa_decode", "gqa_decode"):
+        scoped = _under_scope(text, scope)
+        assert len([t for line, t in scoped if "custom-call(" in line]) == 1
+        assert not any("dot_general" in t for _, t in scoped)
+    assert layouts.whole_leaf_copies(text, cache) == 0
+    assert layouts.whole_leaf_copies(text, cache, moves=True) == 0
+    assert "input_output_alias" in text
+
+
+def test_v5e_window_prefill_installs_one_slot_in_place(
+        v5e_window_pool, traced_for_the_chip):
+    """A 512-token prompt: the windowed and the grouped flash kernels,
+    and the slot's rows of the pool written where they lie."""
+    dec, params, cache, on_chip = v5e_window_pool
+    text = _prefill_text(dec, params, cache, on_chip, 512)
+    for scope in ("swa_prefill", "gqa_prefill"):
+        assert any("custom-call(" in line
+                   for line, _ in _under_scope(text, scope)), scope
+    assert layouts.whole_leaf_copies(text, cache) == 0
+
+
+def _program_digest(name, kind, one_chip):
+    """sha256 of a cell's program compiled for the described chip: its
+    instructions without metadata or Mosaic bodies (``_instructions``).
+    ``name``: ``cgpt`` (one layer of ``cerebras-gpt-1.3b``, the 512 pool of
+    32 slots), ``xing`` / ``ling`` (two layers of theirs, a dense and an
+    expert one; ``ling``'s second latent), pools of 16 x 1024, or
+    ``train`` (the flash kernels' forward and backward at
+    ``cgpt-train-t2048``'s shape); ``kind``: ``step``, ``prefill`` (128
+    tokens, the flash kernels' shortest) or ``grad``."""
+    import hashlib
+
+    from distkeras_tpu.ops import attention
+    from perfbench.adapters import gpt2, kda_mla_moe, mla_moe_hc
+
+    if name == "train":
+        x = SDS((4, 2048, 16, 128), jnp.bfloat16, sharding=one_chip)
+        text = jax.jit(jax.grad(lambda q, k, v: attention.flash_attention(
+            q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2))).lower(
+                x, x, x).compile().as_text()
+    else:
+        if name == "cgpt":
+            cfg = _config("cerebras-gpt-1.3b", n_layer=1)
+            dec = _decode_model(gpt2.program_model(cfg, 2048)).clone(
+                cache_envelope=512)
+            slots = 32
+        elif name == "xing":
+            cfg = _config("xing4.0-29b-a4b-l6", num_hidden_layers=2)
+            dec = _decode_model(mla_moe_hc.program_model(cfg, 1024))
+            slots = 16
+        else:
+            cfg = _config("ling-3.0-flash-vl-l7-e64", num_hidden_layers=2,
+                          layer_group_size=2)
+            dec = _decode_model(kda_mla_moe.program_model(cfg, 1024))
+            slots = 16
+        params, cache, on_chip = _on_chip_shapes(dec, one_chip, slots)
+        text = _kernel_step(dec, params, cache, on_chip, slots) \
+            if kind == "step" else \
+            _prefill_text(dec, params, cache, on_chip, 128)
+    return hashlib.sha256(
+        "\n".join(_instructions(text)).encode()).hexdigest()
+
+
+# the digests of these programs as they compiled before the window model
+# came in beside them (jax 0.9.0 and its libtpu)
+PROGRAMS_BEFORE_THE_WINDOW_MODEL = {
+    ("cgpt", "step"):
+        "44b198f4d8396977d237401dd537755999688484ed9ccb5705c49be190fcf05a",
+    ("cgpt", "prefill"):
+        "16a0fcdd3e81ba089f154b1a1d97598cdc04ad3d0bc0530bdf7b0f414a208cec",
+    ("xing", "step"):
+        "e0a946883adcd655bb5bcca5c913323abb306f53f44d813edeee9acb3d8b95fa",
+    ("xing", "prefill"):
+        "a8dadb7ba46448a40e43f784b314c9da8a6fc2f5be926da04e713a557cef2fe2",
+    ("ling", "step"):
+        "90a82ba2681f58b009d87ffad98bada5a3ebf2a74337291ed5174b2635a4cb48",
+    ("ling", "prefill"):
+        "80b60d7ad279c5b83cec1760ae8d28ceaeb2a605f5e13a04be3d158f55b0f92e",
+    ("train", "grad"):
+        "15b1b831c39d513fd2a904aff3298780b03f91b1d11b83a5b226888c3ebd8ab9",
+}
+
+
+@pytest.mark.parametrize("name,kind", [
+    ("cgpt", "step"), ("cgpt", "prefill"), ("xing", "step"),
+    ("xing", "prefill"), ("ling", "step"), ("ling", "prefill"),
+    ("train", "grad")])
+def test_v5e_the_other_cells_programs_are_what_they_were(
+        one_chip, traced_for_the_chip, name, kind):
+    """The lane-dense read, the window and the grouped heads came in
+    beside ``decode_attention`` and ``flash_attention``; the programs of
+    the cells that were there compile, for the described chip, to the
+    instructions they compiled to before."""
+    assert _program_digest(name, kind, one_chip) == \
+        PROGRAMS_BEFORE_THE_WINDOW_MODEL[name, kind]
